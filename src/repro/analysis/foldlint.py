@@ -91,7 +91,8 @@ def _check_layers(net, params, input_shape: Tuple[int, ...],
                 epi = requant_epilogue(epi)
             plan = sched.plan.clamped(cv.nf, cv.c, cv.p)
             layer_rep = check_plan(cv, plan, where=where,
-                                   precision=sched.key.precision)
+                                   precision=sched.key.precision,
+                                   dataflow=sched.dataflow, epilogue=epi)
             if layer_rep.ok:
                 try:
                     spec = fold_kernel_spec(

@@ -11,8 +11,8 @@ decomposition assumes:
                       of its block (a partial edge tile would clamp)
   index.oob           a grid point addresses a block beyond the (padded)
                       array bounds
-  index.rows-window   the in-kernel ``dynamic_slice`` row window of the
-                      last P fold runs past the padded input rows
+  index.rows-window   the in-kernel row window of the last P fold runs
+                      past the padded input rows
   index.group-offset  a WS/OS input or weight block is not addressed by
                       the group of the current filter fold
   index.dw-offset     a depthwise input/weight block is not addressed by
@@ -87,7 +87,7 @@ def check_kernel_spec(spec: FoldKernelSpec, where: str = "kernel") -> Report:
                         f"{a} exactly — an edge tile would clamp and "
                         f"break the fold geometry")
 
-    # the in-kernel dynamic_slice of the last P fold must stay inside the
+    # the in-kernel row reads of the last P fold must stay inside the
     # padded rows: row0 + (p_block-1)*stride + R <= x_rows
     g_p = spec.grid[axes["p"]]
     rows_top = ((g_p - 1) * spec.p_block * spec.stride
@@ -141,10 +141,10 @@ def check_kernel_spec(spec: FoldKernelSpec, where: str = "kernel") -> Report:
                     add_once("index.dw-offset", op.role,
                              f"grid {pt}: depthwise input reads channel "
                              f"fold {idx[1]}, not the grid's fold {cc}")
-                if op.role == "w" and idx[0] != cc:
+                if op.role == "w" and idx[1] != cc:      # (R*S, C, 1)
                     add_once("index.dw-offset", op.role,
                              f"grid {pt}: depthwise weights read filter "
-                             f"fold {idx[0]}, not the grid's fold {cc}")
+                             f"fold {idx[1]}, not the grid's fold {cc}")
             else:
                 f, cc = pt[axes["nf"]], pt[axes["c"]]
                 if op.role == "x":
@@ -155,9 +155,10 @@ def check_kernel_spec(spec: FoldKernelSpec, where: str = "kernel") -> Report:
                                  f"{idx[1]} but filter fold {f} lives in "
                                  f"group {f // spec.nfg_folds} (want "
                                  f"fold {want})")
-                if op.role == "w" and idx[:2] != (f, cc):
+                # tap-major weights (R*S, N_F, C/G): every tap rides along
+                if op.role == "w" and idx[1:] != (f, cc):
                     add_once("index.group-offset", op.role,
-                             f"grid {pt}: weight block {idx[:2]} != the "
+                             f"grid {pt}: weight block {idx[1:]} != the "
                              f"grid's (filter, depth) folds ({f}, {cc})")
         out_idx = _eval_map(spec.output, pt)
         first = writers.setdefault(out_idx, pt)

@@ -6,9 +6,9 @@ this with ad-hoc ``str(jaxpr).count("pallas_call")`` scraping;
 ``audit_compiled`` promotes that into a structured API:
 
 * ``pallas_calls``  — recursive count of pallas_call equations.
-* ``top_counts``    — top-level primitive histogram, with ``pjit``
+* ``top_counts``    — top-level primitive histogram, with ``jit``
                       equations resolved to their traced-function name
-                      (``jnp.clip`` traces as a pjit named ``"clip"``).
+                      (``jnp.clip`` traces as a jit named ``"clip"``).
 * ``ops4d``         — the same histogram restricted to equations touching
                       a 4-D tensor: rank-1 BN-statistic folds and the 2-D
                       fc head don't count, escaped epilogue tensor math
@@ -66,7 +66,7 @@ def _count_recursive(jaxpr, name: str) -> int:
 
 def _resolved_name(eqn) -> str:
     name = eqn.primitive.name
-    if name == "pjit":
+    if name == "jit":
         return eqn.params.get("name", name)
     return name
 
@@ -114,9 +114,9 @@ def audit_compiled(net, params, input_shape: Tuple[int, ...]
     x0 = jnp.zeros(tuple(input_shape), jnp.float32)
     closed = jax.make_jaxpr(net.apply)(params, x0)
     jaxpr = closed.jaxpr
-    # a jitted forward is one opaque pjit equation: audit what it wraps
+    # a jitted forward is one opaque jit equation: audit what it wraps
     while (len(jaxpr.eqns) == 1
-           and jaxpr.eqns[0].primitive.name == "pjit"):
+           and jaxpr.eqns[0].primitive.name == "jit"):
         jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr
 
     pallas_calls = _count_recursive(jaxpr, "pallas_call")

@@ -22,7 +22,9 @@ reaches a kernel:
                         extent exactly once (under- or over-coverage)
   plan.not-clamped      ``clamped()`` is not idempotent at the nest's own
                         dims — the plan does not describe this layer
-  plan.vmem-overflow    ``conv_working_set`` exceeds the VMEM limit
+  plan.vmem-overflow    the kernel's real blocks (``conv_working_set``:
+                        double-buffered full-height input, weight fold,
+                        output, accumulator) exceed the VMEM limit
   plan.vmem-pressure    (warning) working set exceeds the planner's
                         half-capacity target, eating the double-buffer
   quant.acc-overflow    (int8 only) the worst-case per-output reduction
@@ -32,14 +34,16 @@ reaches a kernel:
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 from repro.analysis.report import Report, WARNING
 from repro.core.loopnest import ConvLoopNest
-from repro.core.mapping import ConvBlockPlan, conv_working_set
+from repro.core.mapping import (VMEM_LIMIT_BYTES, ConvBlockPlan,
+                                conv_working_set)
 
 __all__ = ["check_plan", "DEFAULT_VMEM_LIMIT"]
 
-DEFAULT_VMEM_LIMIT = 64 * 1024 * 1024      # matches plan_conv_blocks
+DEFAULT_VMEM_LIMIT = VMEM_LIMIT_BYTES      # matches plan_conv_blocks
 
 
 def _covers_exactly(grid: int, block: int, extent: int) -> bool:
@@ -50,8 +54,14 @@ def _covers_exactly(grid: int, block: int, extent: int) -> bool:
 
 def check_plan(conv: ConvLoopNest, plan: ConvBlockPlan,
                vmem_limit: int = DEFAULT_VMEM_LIMIT,
-               where: str = "plan", precision: str = "fp32") -> Report:
+               where: str = "plan", precision: str = "fp32",
+               dataflow: Optional[str] = None,
+               epilogue=None) -> Report:
     """Prove ``plan`` is a legal fold geometry for ``conv``.
+
+    ``dataflow``/``epilogue`` name the launch the plan will drive, so the
+    VMEM check prices exactly the blocks that kernel holds; without them
+    it prices the larger of the dataflows the nest can run.
 
     With ``precision="int8"`` the int32 accumulator is additionally
     proven safe: the per-output reduction depth (C_g * R * S) at the
@@ -156,7 +166,9 @@ def check_plan(conv: ConvLoopNest, plan: ConvBlockPlan,
     # VMEM residency — recompute the working set from the (possibly
     # clamped) blocks; plan.vmem_bytes is the *solve-time* estimate and is
     # deliberately not trusted here
-    ws = conv_working_set(conv, nf_b, c_b, p_b)
+    ws = conv_working_set(conv, nf_b, c_b, p_b,
+                          1 if precision == "int8" else 4,
+                          dataflow=dataflow, epilogue=epilogue)
     if ws > vmem_limit:
         rep.add("plan.vmem-overflow", where,
                 f"working set {ws / 2**20:.1f} MiB exceeds the "

@@ -52,8 +52,9 @@ from repro.core.epilogue import (Epilogue, epilogue_out_hw, maxpool2x2)
 from repro.core.graph import (DEPTHWISE, GraphError, StreamGraph, as_graph,
                               bn_scale_shift, fuse_graph)
 from repro.core.loopnest import ConvLoopNest
-from repro.core.mapping import (WS_ACC_BYTES_LIMIT, ConvBlockPlan,
-                                conv_working_set, plan_conv_blocks)
+from repro.core.mapping import (VMEM_LIMIT_BYTES, WS_ACC_BYTES_LIMIT,
+                                ConvBlockPlan, conv_working_set,
+                                plan_conv_blocks, serving_conv_plan)
 from repro.core.perfmodel import MavecConfig
 
 __all__ = [
@@ -331,7 +332,7 @@ def plan_and_dataflow(cv: ConvLoopNest,
 
 def tuning_candidates(cv: ConvLoopNest,
                       base_plan: Optional[ConvBlockPlan] = None,
-                      vmem_limit: int = 64 * 1024 * 1024
+                      vmem_limit: int = VMEM_LIMIT_BYTES
                       ) -> List[Tuple[str, ConvBlockPlan, str]]:
     """The candidate set ``autotune_schedule`` races: the analytical plan
     plus nearby block-shape variants — every blocked axis of the fold
@@ -477,7 +478,7 @@ def measure_schedule_ms(cv: ConvLoopNest, plan: ConvBlockPlan, dataflow: str,
 
 
 def autotune_schedule(cv: ConvLoopNest, cfg: Optional[MavecConfig] = None,
-                      *, vmem_limit: int = 64 * 1024 * 1024,
+                      *, vmem_limit: int = VMEM_LIMIT_BYTES,
                       interpret: Optional[bool] = None,
                       reps: int = 3, warmup: int = 1,
                       epilogue: Optional[Epilogue] = None,
@@ -587,7 +588,7 @@ class ScheduleCache:
     """
 
     def __init__(self, cfg: Optional[MavecConfig] = None,
-                 vmem_limit: int = 64 * 1024 * 1024):
+                 vmem_limit: int = VMEM_LIMIT_BYTES):
         self.cfg = cfg or MavecConfig()
         self.vmem_limit = vmem_limit
         self.stats = CacheStats()
@@ -907,7 +908,8 @@ def _verify_schedule(name: str, cv: ConvLoopNest, sched: "ConvSchedule",
     from repro.analysis.plan_check import check_plan
     from repro.analysis.report import FoldLintError
     from repro.kernels.conv2d_ws import fold_kernel_spec
-    rep = check_plan(cv, plan, where=name, precision=sched.key.precision)
+    rep = check_plan(cv, plan, where=name, precision=sched.key.precision,
+                     dataflow=sched.dataflow, epilogue=epi)
     if rep.ok:
         spec = fold_kernel_spec(
             (cv.n, cv.c, cv.padded_x, cv.padded_y),
@@ -918,6 +920,55 @@ def _verify_schedule(name: str, cv: ConvLoopNest, sched: "ConvSchedule",
     if not rep.ok:
         raise FoldLintError(rep.errors)
     _VERIFIED_SCHEDULES[key] = True
+
+
+def _dense(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The classifier's dense layer, (N, K) @ (K, D) + b, contracted in
+    fp32 on every backend (XLA's TPU default would round the operands to
+    bf16).  The rows are zero-padded to a multiple of 8 for the dot: XLA
+    compiles a one-row dot into a differently rounded program, so a
+    one-image bucket — or a mesh's one-row batch slice per device — would
+    otherwise round an image's logits differently from a wider batch."""
+    n = x.shape[0]
+    if n % 8:
+        x = jnp.pad(x, ((0, -n % 8), (0, 0)))
+    y = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+    return y[:n] + b
+
+
+def _conv_step(x, w, b, scale, shift, res, *, sched: "ConvSchedule",
+               epi: Optional[Epilogue], stride: int, pad: int, groups: int,
+               mode: str, interpret: bool, precision: str, x_scale):
+    """One conv node of a compiled forward: ``b``/``scale``/``shift``/
+    ``res`` are None unless the node's epilogue uses them."""
+    from repro.kernels.ops import conv2d, conv2d_fused, conv2d_int8
+    if precision == "int8":
+        # quantized stream: weights quantize per-channel at trace time,
+        # activations with the calibrated static scale; bias/BN/dequant
+        # fold into one flush affine
+        return conv2d_int8(
+            x, w, b, x_scale=x_scale, stride=stride, pad=pad, epilogue=epi,
+            impl="direct" if mode == "reference" else sched.impl(),
+            plan=sched.plan, interpret=interpret, residual=res,
+            scale=scale, shift=shift, groups=groups)
+    if epi is not None:
+        # an epilogue on a conv node is graph semantics and is honored in
+        # every mode; in pallas mode it flushes in-kernel, in reference
+        # mode (a caller-supplied pre-fused graph — this compile never
+        # fuses there) it lowers through the XLA conv + reference epilogue
+        if mode == "reference":
+            return conv2d_fused(x, w, b, stride=stride, pad=pad,
+                                epilogue=epi, impl="direct", residual=res,
+                                scale=scale, shift=shift, groups=groups)
+        return conv2d_fused(x, w, b, stride=stride, pad=pad, epilogue=epi,
+                            impl=sched.impl(), plan=sched.plan,
+                            interpret=interpret, residual=res, scale=scale,
+                            shift=shift, groups=groups)
+    if mode == "reference":
+        return conv2d(x, w, stride=stride, pad=pad, impl="direct",
+                      groups=groups)
+    return conv2d(x, w, stride=stride, pad=pad, impl=sched.impl(),
+                  plan=sched.plan, interpret=interpret, groups=groups)
 
 
 def compile_network(params: Dict[str, Any],
@@ -936,7 +987,9 @@ def compile_network(params: Dict[str, Any],
                     verify: bool = True,
                     tracer=None,
                     precision: str = "fp32",
-                    quant=None
+                    quant=None,
+                    mesh=None,
+                    mesh_plan=None
                     ) -> CompiledNetwork:
     """Lower a streaming graph into a static fold schedule + jitted forward.
 
@@ -994,6 +1047,14 @@ def compile_network(params: Dict[str, Any],
     ``ScheduleKey``s (the traffic model prices the 1-byte streams, which
     can flip the WS/OS choice), and verification proves the int32
     accumulator bound on top of the usual invariants.
+
+    ``mesh`` (with ``mesh_plan``, a ``core/mapping.py:serving_conv_plan``
+    naming its batch and filter axes) runs every fold kernel under
+    ``shard_map``: GSPMD cannot partition a Mosaic kernel, so each device
+    runs it on its own batch (and, where the weights split, filter)
+    shard — ``distributed/sharding.py:fold_conv_shards``.  Schedules are
+    planned and verified for that per-device nest.  Reference mode needs
+    no such wrapper.
     """
     from repro.core.quant import check_precision
     check_precision(precision)
@@ -1050,8 +1111,22 @@ def compile_network(params: Dict[str, Any],
                 raise GraphError(
                     f"{nd.name}: groups={groups} must divide the filter "
                     f"count {nf}")
-            cv = ConvLoopNest(n=n_, nf=nf, c=chan, r=r, s=s, x=h, y=w_,
-                              stride=nd.stride, pad=nd.pad, groups=groups)
+            shards = None
+            if mesh is not None and mode == "pallas":
+                from repro.distributed.sharding import fold_conv_shards
+                if mesh_plan is None:
+                    mesh_plan = serving_conv_plan(n_, nf)
+                shards = fold_conv_shards(mesh, mesh_plan, n=n_, nf=nf,
+                                          c=chan, groups=groups)
+                # the nest each device's kernel runs
+                cv = ConvLoopNest(n=shards.n, nf=shards.nf, c=shards.c,
+                                  r=r, s=s, x=h, y=w_, stride=nd.stride,
+                                  pad=nd.pad, groups=shards.groups)
+                groups = shards.groups
+            else:
+                cv = ConvLoopNest(n=n_, nf=nf, c=chan, r=r, s=s, x=h, y=w_,
+                                  stride=nd.stride, pad=nd.pad,
+                                  groups=groups)
             epi, demoted_pool = nd.epilogue, False
             if epi is not None and epi.pool and (cv.p < 2 or cv.q < 2):
                 # output too small to pool in-kernel: demote to a
@@ -1107,7 +1182,7 @@ def compile_network(params: Dict[str, Any],
             plan_steps.append(("conv", nd.name, nd.all_inputs(),
                                (sched, epi, nd.stride, nd.pad, nd.param,
                                 demoted_pool, groups, nd.bn_param,
-                                x_scale)))
+                                x_scale, shards)))
         elif nd.op == "bias":
             _need4d(nd, s_in)
             shapes[nd.name] = s_in
@@ -1157,66 +1232,27 @@ def compile_network(params: Dict[str, Any],
     def forward(p: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
         # Schedules are baked in: tracing binds the cached kernels and
         # never re-plans (no cache lookups on the hot path).
-        from repro.kernels.ops import conv2d, conv2d_fused, conv2d_int8
         env: Dict[str, jnp.ndarray] = {g.input: x}
         for op, out, ins, info in steps:
             if op == "conv":
                 (sched, epi, stride, pad, pname, demoted_pool, groups,
-                 bn_param, x_scale) = info
-                xin, w = env[ins[0]], p[pname]["w"]
-                if precision == "int8":
-                    # quantized stream: weights quantize per-channel at
-                    # trace time, activations with the calibrated static
-                    # scale; bias/BN/dequant fold into one flush affine
-                    b = (p[pname]["b"]
-                         if epi is not None and epi.bias else None)
-                    scale = shift = None
-                    if epi is not None and epi.scale:
-                        scale, shift = bn_scale_shift(p[bn_param])
-                    res = (env[ins[1]]
-                           if epi is not None and epi.residual else None)
-                    y = conv2d_int8(
-                        xin, w, b, x_scale=x_scale, stride=stride,
-                        pad=pad, epilogue=epi,
-                        impl=("direct" if mode == "reference"
-                              else sched.impl()),
-                        plan=sched.plan, interpret=interpret,
-                        residual=res, scale=scale, shift=shift,
-                        groups=groups)
-                    env[out] = maxpool2x2(y) if demoted_pool else y
-                    continue
-                if epi is not None:
-                    # an epilogue on a conv node is graph semantics and is
-                    # honored in every mode; in pallas mode it flushes
-                    # in-kernel, in reference mode (a caller-supplied
-                    # pre-fused graph — this compile never fuses there) it
-                    # lowers through the XLA conv + reference epilogue
-                    b = p[pname]["b"] if epi.bias else None
-                    scale = shift = None
-                    if epi.scale:
-                        # fold the BN statistics to the flush-time affine
-                        # at trace time (compile-time constants per call)
-                        scale, shift = bn_scale_shift(p[bn_param])
-                    res = env[ins[1]] if epi.residual else None
-                    if mode == "reference":
-                        y = conv2d_fused(xin, w, b, stride=stride, pad=pad,
-                                         epilogue=epi, impl="direct",
-                                         residual=res, scale=scale,
-                                         shift=shift, groups=groups)
-                    else:
-                        y = conv2d_fused(xin, w, b, stride=stride, pad=pad,
-                                         epilogue=epi, impl=sched.impl(),
-                                         plan=sched.plan,
-                                         interpret=interpret, residual=res,
-                                         scale=scale, shift=shift,
-                                         groups=groups)
-                elif mode == "reference":
-                    y = conv2d(xin, w, stride=stride, pad=pad, impl="direct",
-                               groups=groups)
-                else:
-                    y = conv2d(xin, w, stride=stride, pad=pad,
-                               impl=sched.impl(), plan=sched.plan,
-                               interpret=interpret, groups=groups)
+                 bn_param, x_scale, shards) = info
+                w = p[pname]["w"]
+                b = p[pname]["b"] if epi is not None and epi.bias else None
+                scale = shift = None
+                if epi is not None and epi.scale:
+                    # fold the BN statistics to the flush-time affine at
+                    # trace time (compile-time constants per call)
+                    scale, shift = bn_scale_shift(p[bn_param])
+                res = (env[ins[1]] if epi is not None and epi.residual
+                       else None)
+                conv = functools.partial(
+                    _conv_step, sched=sched, epi=epi, stride=stride,
+                    pad=pad, groups=groups, mode=mode, interpret=interpret,
+                    precision=precision, x_scale=x_scale)
+                if shards is not None:
+                    conv = shards.wrap(conv, mesh)
+                y = conv(env[ins[0]], w, b, scale, shift, res)
                 env[out] = maxpool2x2(y) if demoted_pool else y
             elif op == "bias":
                 env[out] = (env[ins[0]]
@@ -1239,7 +1275,7 @@ def compile_network(params: Dict[str, Any],
                 v = env[ins[0]]
                 env[out] = v.reshape(v.shape[0], -1)
             else:                                 # dense
-                env[out] = env[ins[0]] @ p[info]["w"] + p[info]["b"]
+                env[out] = _dense(env[ins[0]], p[info]["w"], p[info]["b"])
         y = env[out_name]
         return head(p, y) if head is not None else y
 
@@ -1285,6 +1321,9 @@ class BucketCompiler:
     is per-bucket.  With ``tuning_path`` the measured winners round-trip
     through one JSON shared by all buckets (and by later sessions).
 
+    ``mesh``/``mesh_plan`` run the fold kernels per shard
+    (``compile_network``).
+
     ``precision="int8"``: one ``QuantRecipe`` is calibrated eagerly here
     (or supplied via ``quant``) and shared by every bucket, so all bucket
     widths bake in bitwise-identical scales — a request's logits cannot
@@ -1300,7 +1339,8 @@ class BucketCompiler:
                  autotune_reps: int = 3,
                  autotune_timer: Optional[Callable] = None,
                  verify: bool = True, tracer=None,
-                 precision: str = "fp32", quant=None):
+                 precision: str = "fp32", quant=None, mesh=None,
+                 mesh_plan=None):
         from repro.core.quant import (check_precision, default_calib_batch,
                                       quantize_graph)
         check_precision(precision)
@@ -1325,6 +1365,7 @@ class BucketCompiler:
         self.autotune_timer = autotune_timer
         self.verify = verify
         self.tracer = tracer          # duck-typed obs tracer (or None)
+        self.mesh, self.mesh_plan = mesh, mesh_plan
         self._nets: Dict[int, CompiledNetwork] = {}
 
     @property
@@ -1352,7 +1393,7 @@ class BucketCompiler:
                 autotune_reps=self.autotune_reps,
                 autotune_timer=self.autotune_timer, verify=self.verify,
                 tracer=self.tracer, precision=self.precision,
-                quant=self.quant)
+                quant=self.quant, mesh=self.mesh, mesh_plan=self.mesh_plan)
             self._nets[batch] = net
         return net
 

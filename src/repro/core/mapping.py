@@ -8,7 +8,7 @@ The paper expresses its dataflow with two data-centric directives:
 
 On TPU these become, respectively:
 
-  * across chips  : a mesh axis in a ``PartitionSpec`` (GSPMD/pjit)
+  * across chips  : a mesh axis in a ``PartitionSpec`` (GSPMD/jit)
   * within a chip : a Pallas grid dimension with a ``BlockSpec`` index-map
     (spatial over the MXU lanes, temporal over the grid's streaming dims)
 
@@ -43,6 +43,9 @@ __all__ = [
     "largest_divisor_le",
     "plan_conv_blocks",
     "serving_conv_plan",
+    "tiled_bytes",
+    "vmem_request_bytes",
+    "VMEM_LIMIT_BYTES",
     "WS_ACC_BYTES_LIMIT",
 ]
 
@@ -52,6 +55,42 @@ __all__ = [
 # is fused) instead of allocating an uncompilable scratch, and the engine's
 # cost model prices the same fallback (engine.dataflow_traffic_bytes).
 WS_ACC_BYTES_LIMIT = 16 * 1024 * 1024
+
+# The most VMEM one fold kernel may request (``vmem_limit_bytes``): TPU
+# v5e and v6e cores have 128 MiB of VMEM; the rest is left to Mosaic's
+# internal scratch.  The planner sizes folds against half of it, and
+# foldlint's ``plan.vmem-overflow`` holds every kernel's real blocks
+# (``FoldKernelSpec.vmem_bytes``) to all of it.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+# Mosaic's default scoped-VMEM limit on v5e (what a kernel gets without
+# ``vmem_limit_bytes``), and the headroom requested on top of the blocks
+# for in-kernel values (row accumulators, tap windows, pool selectors).
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+_VMEM_HEADROOM = 4 * 1024 * 1024
+
+_SUBLANES = {4: 8, 2: 16, 1: 32}         # sublane tile per element width
+
+
+def tiled_bytes(shape: Sequence[int], itemsize: int = 4) -> int:
+    """VMEM bytes of a block once Mosaic pads its last two dims to the
+    (sublane, 128-lane) tile of its element width."""
+    dims = [int(d) for d in shape]
+    if len(dims) == 1:
+        dims = [1] + dims
+    sub = _SUBLANES[itemsize]
+    lead = math.prod(dims[:-2])
+    return (lead * _round_up(dims[-2], sub) * _round_up(dims[-1], 128)
+            * itemsize)
+
+
+def vmem_request_bytes(working_set: int) -> int:
+    """The ``vmem_limit_bytes`` a fold kernel asks for: its blocks plus
+    headroom, never below Mosaic's default scoped limit.  Working sets
+    above ``VMEM_LIMIT_BYTES`` are requested as they are, and the chip's
+    compiler refuses them — foldlint reports them first."""
+    return max(_DEFAULT_SCOPED_VMEM,
+               _round_up(working_set + _VMEM_HEADROOM, 1024 * 1024))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,19 +217,61 @@ def _round_up(x: int, m: int) -> int:
 
 
 def conv_working_set(conv: ConvLoopNest, nf_block: int, c_block: int,
-                     p_block: int, bytes_per_elem: int = 4) -> int:
-    """VMEM bytes of one grid step's working set: weight fold + streamed
-    image rows + block accumulator (shared by the block solver and the
-    autotuner's candidate variants).  For a depthwise nest the weight fold
-    and accumulator ride the channel block (one filter per channel)."""
-    if conv.depthwise:
-        w = c_block * conv.r * conv.s
-        acc = c_block * p_block * conv.q
+                     p_block: int, bytes_per_elem: int = 4, *,
+                     dataflow: Optional[str] = None,
+                     epilogue=None) -> int:
+    """VMEM bytes the fold kernel really holds for these blocks: the
+    full-height input block, the weight fold, the per-filter vectors, the
+    output block and any residual block — each double-buffered by the
+    Pallas pipeline — plus the accumulator scratch (full-height for
+    weight-stationary), all padded to the TPU's tiles.  Computed from the
+    launch geometry itself (``kernels/conv2d_ws.py:FoldKernelSpec``), so
+    the planner, the autotuner's candidates, foldlint and the kernel's
+    ``vmem_limit_bytes`` share one figure.
+
+    ``dataflow=None`` prices the larger of the dataflows the nest can run
+    (the planner sizes folds before the dataflow is chosen);
+    ``bytes_per_elem`` is the streamed x/w element width."""
+    # lazy: the kernel module imports this one
+    from repro.kernels.conv2d_ws import fold_kernel_spec
+    plan = ConvBlockPlan(nf_block=nf_block, c_block=c_block,
+                         p_block=p_block, grid=(1, 1, 1), vmem_bytes=0,
+                         groups=conv.groups)
+    if dataflow is not None:
+        flows: Tuple[str, ...] = (dataflow,)
+    elif conv.depthwise:
+        flows = ("depthwise",)
     else:
-        w = nf_block * c_block * conv.r * conv.s
-        acc = nf_block * p_block * conv.q
-    img = c_block * (p_block * conv.stride + conv.r) * conv.padded_y
-    return (w + img + acc) * bytes_per_elem
+        flows = ("weight_stationary", "output_stationary")
+    return max(fold_kernel_spec(
+        (conv.n, conv.c, conv.padded_x, conv.padded_y),
+        (conv.nf, conv.cg, conv.r, conv.s), stride=conv.stride, plan=plan,
+        dataflow=df, epilogue=epilogue, groups=conv.groups
+    ).vmem_bytes(bytes_per_elem) for df in flows)
+
+
+def _p_block(conv: ConvLoopNest) -> int:
+    """Output rows per image fold: about 512 output positions, and — when
+    that leaves more than one fold — a multiple of 16, so the per-fold
+    output block (halved by a fused 2x2 pool) keeps the TPU's 8-row
+    sublane tiling.  A single fold spans the whole height (the block is
+    then the full extent, which is always legal)."""
+    want = max(1, 512 // max(conv.q, 1))
+    if want >= conv.p:
+        return conv.p
+    return min(conv.p, _round_up(want, 16))
+
+
+def _dw_channel_block(c: int) -> int:
+    """Channels per depthwise fold: all of them up to 128, else the
+    largest 8-aligned divisor of C within 128 (128 when none divides).
+    Mosaic keeps each tap's (c_b, q) window, broadcast filter column and
+    partial product live at once — some 44 (c_b, 128-lane) values for a
+    3x3 — which past 128 channels outgrows the kernel's VMEM headroom
+    (``vmem_request_bytes``)."""
+    if c <= 128:
+        return _round_up(c, 8)
+    return next((d for d in range(128, 7, -8) if c % d == 0), 128)
 
 
 def largest_divisor_le(n: int, cap: int) -> int:
@@ -205,16 +286,19 @@ def largest_divisor_le(n: int, cap: int) -> int:
 
 
 def plan_conv_blocks(conv: ConvLoopNest,
-                     vmem_limit: int = 64 * 1024 * 1024,
+                     vmem_limit: int = VMEM_LIMIT_BYTES,
                      mxu: int = 128,
                      bytes_per_elem: int = 4) -> ConvBlockPlan:
     """Solve eqs (1)-(2) under TPU constraints.
 
     R_P -> nf_block: min(N_F, 2*mxu) rounded to the MXU lane width so the
            filter dim fills the systolic array.
-    C_P -> c_block:  largest channel count whose weight fold + streamed
-           image tile + accumulator fit in ~half of VMEM (the other half is
-           the Pallas double-buffer).
+    C_P -> c_block:  largest channel count whose real kernel blocks
+           (``conv_working_set``: double-buffered full-height input,
+           weight fold, output, accumulator) fit in half of
+           ``vmem_limit``.  A block smaller than C stays a multiple of 128
+           (the weight fold's lane dim), so only multiples of 256 halve.
+    P   -> p_block:  ~512 output positions, 16-row aligned (``_p_block``).
 
     Grouped nests (``conv.groups > 1``) solve the same equations *within
     one group*: ``nf_block`` divides N_F/G and ``c_block`` divides C/G
@@ -223,17 +307,17 @@ def plan_conv_blocks(conv: ConvLoopNest,
     has no depth folds at all — the channel block doubles as the filter
     block and the grid's c axis walks the channels.
     """
-    p_block = min(conv.p, max(1, 512 // max(conv.q, 1)))  # ~512 out positions
+    p_block = _p_block(conv)
 
     def working_set(nf_b: int, c_b: int) -> int:
         return conv_working_set(conv, nf_b, c_b, p_block, bytes_per_elem)
 
     if conv.depthwise:
         # one filter per channel: block the channel axis only (channels are
-        # independent, so any block size is legal — lane-align when we can)
-        c_block = min(_round_up(conv.c, 8), 512)
-        while c_block > 1 and working_set(c_block, c_block) > vmem_limit // 2:
-            c_block //= 2
+        # independent, so any 8-aligned block is legal)
+        c_block = _dw_channel_block(conv.c)
+        while c_block > 8 and working_set(c_block, c_block) > vmem_limit // 2:
+            c_block = _round_up(c_block // 2, 8)
         grid = (1, math.ceil(conv.c / c_block), math.ceil(conv.p / p_block))
         return ConvBlockPlan(nf_block=c_block, c_block=c_block,
                              p_block=p_block, grid=grid,
@@ -257,7 +341,8 @@ def plan_conv_blocks(conv: ConvLoopNest,
 
     nf_block = min(_round_up(conv.nf, 8), 2 * mxu)
     c_block = min(conv.c, 512)
-    while c_block > 1 and working_set(nf_block, c_block) > vmem_limit // 2:
+    while (c_block % 256 == 0
+           and working_set(nf_block, c_block) > vmem_limit // 2):
         c_block //= 2
     grid = (math.ceil(conv.nf / nf_block),
             math.ceil(conv.c / c_block),

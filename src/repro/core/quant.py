@@ -138,7 +138,7 @@ def quantize_act(x: jnp.ndarray, scale) -> jnp.ndarray:
 
 
 # jit-wrapped entry points for use inside a traced forward: each call is
-# one opaque ``pjit`` equation named after the function, so the jaxpr
+# one opaque ``jit`` equation named after the function, so the jaxpr
 # auditor (``analysis/jaxpr_audit.py``) sees a deliberate quantize step —
 # not a leaked 4-D clip/mul that would trip ``audit.unfused-op``.
 quantize_act_jit = jax.jit(quantize_act)

@@ -24,7 +24,6 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["gpipe_schedule", "pipeline_apply", "split_stages"]
@@ -134,7 +133,7 @@ def make_pipelined_stack(cfg, layer_fn: Callable, *, n_stages: int,
     def run_mesh(stacked_params, x_micro):
         staged = split_stages(stacked_params, n_stages)
         pspecs = jax.tree.map(lambda _: P(axis_name), staged)
-        fn = shard_map(spmd, mesh=mesh, in_specs=(pspecs, P()),
-                       out_specs=P(), check_rep=False)
+        fn = jax.shard_map(spmd, mesh=mesh, in_specs=(pspecs, P()),
+                           out_specs=P(), check_vma=False)
         return fn(staged, x_micro)
     return run_mesh

@@ -27,7 +27,8 @@ from repro.models.common import Axes
 
 __all__ = ["ShardingRules", "make_rules", "spec_for", "tree_shardings",
            "set_context", "clear_context", "constrain", "zero1_shardings",
-           "vision_shardings", "vision_batch_sharding"]
+           "vision_shardings", "vision_batch_sharding", "FoldConvShards",
+           "fold_conv_shards"]
 
 MeshAxes = Optional[Tuple[str, ...]]
 
@@ -102,6 +103,15 @@ def vision_batch_sharding(mesh: Mesh, plan) -> NamedSharding:
     return NamedSharding(mesh, plan.partition_spec(("N", None, None, None)))
 
 
+def _mesh_axis_sizes(mesh: Mesh, plan) -> Tuple[str, int, Optional[str], int]:
+    """(data axis, its size, model axis, its size) of a serving plan."""
+    by_dim = {d.dim: d.axis for d in plan.spatial()}
+    data_axis, model_axis = by_dim.get("N"), by_dim.get("N_F")
+    data = mesh.shape.get(data_axis, 1) if data_axis else 1
+    model = mesh.shape.get(model_axis, 1) if model_axis else 1
+    return data_axis, data, model_axis, model
+
+
 def vision_shardings(params, mesh: Mesh, plan):
     """NamedShardings for a conv-trunk param tree under a serving plan.
 
@@ -112,9 +122,7 @@ def vision_shardings(params, mesh: Mesh, plan):
     replicates (same fallback discipline as ``make_rules``), as does
     everything that is not a conv layer (the fc head).
     """
-    by_dim = {d.dim: d.axis for d in plan.spatial()}
-    model_axis = by_dim.get("N_F")
-    model = mesh.shape.get(model_axis, 1) if model_axis else 1
+    model = _mesh_axis_sizes(mesh, plan)[3]
     w_spec = plan.partition_spec(("N_F", None, None, None))
     b_spec = plan.partition_spec(("N_F",))
     replicate = NamedSharding(mesh, PartitionSpec())
@@ -133,6 +141,74 @@ def vision_shardings(params, mesh: Mesh, plan):
         else:
             out[name] = jax.tree.map(lambda _: replicate, leaf)
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class FoldConvShards:
+    """One fold conv under ``shard_map`` on a serving mesh: the per-device
+    nest (batch ``n``, filters ``nf``, input channels ``c``, ``groups``)
+    and the PartitionSpecs of its operands.
+
+    A Mosaic kernel cannot be partitioned by GSPMD, so each device runs
+    the kernel on its own shard: the batch split over the data axis and,
+    where the weights are split (``vision_shardings``' rule: N_F divides
+    the model axis), the filters over the model axis.  Dense convs read
+    every input channel (the activation is gathered over the model axis
+    first); grouped convs whose group count divides the model axis read
+    only their groups' channels."""
+    n: int
+    nf: int
+    c: int
+    groups: int
+    x: PartitionSpec
+    w: PartitionSpec
+    vec: PartitionSpec
+    out: PartitionSpec
+
+    def wrap(self, conv, mesh: Mesh):
+        """``conv(x, w, b, scale, shift, residual)`` run per shard; absent
+        (None) operands stay absent.  The residual splits like the
+        output."""
+        specs = (self.x, self.w, self.vec, self.vec, self.vec, self.out)
+
+        def run(*args):
+            live = [i for i, a in enumerate(args) if a is not None]
+
+            def local(*vals):
+                full = [None] * len(args)
+                for i, v in zip(live, vals):
+                    full[i] = v
+                return conv(*full)
+            return jax.shard_map(
+                local, mesh=mesh, in_specs=tuple(specs[i] for i in live),
+                out_specs=self.out, check_vma=False
+            )(*(args[i] for i in live))
+        return run
+
+
+def fold_conv_shards(mesh: Mesh, plan, *, n: int, nf: int, c: int,
+                     groups: int) -> FoldConvShards:
+    """The per-device geometry of an (N, C) -> N_F conv with ``groups``
+    under a serving plan (``core/mapping.py:serving_conv_plan``)."""
+    _, data, _, model = _mesh_axis_sizes(mesh, plan)
+    if n % data:
+        raise ValueError(f"batch {n} does not split over a data axis of "
+                         f"{data} devices")
+    # a grouped conv splits only whole groups: each device's filters must
+    # read channels that live on that device
+    split = (model > 1 and nf % model == 0
+             and (groups == 1 or groups % model == 0))
+    m = model if split else 1
+    split_c = split and groups > 1
+    nf_axis = "N_F" if split else None
+    return FoldConvShards(
+        n=n // data, nf=nf // m, c=c // m if split_c else c,
+        groups=groups // m if split_c else groups,
+        x=plan.partition_spec(("N", nf_axis if split_c else None, None,
+                               None)),
+        w=plan.partition_spec((nf_axis, None, None, None)),
+        vec=plan.partition_spec((nf_axis,)),
+        out=plan.partition_spec(("N", nf_axis, None, None)))
 
 
 # ---------------------------------------------------------------------------

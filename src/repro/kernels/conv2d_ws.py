@@ -66,10 +66,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.epilogue import Epilogue, epilogue_out_hw, maxpool2x2
+from repro.core.epilogue import Epilogue, epilogue_out_hw
 from repro.core.loopnest import ConvLoopNest
 from repro.core.mapping import (WS_ACC_BYTES_LIMIT, ConvBlockPlan,
-                                plan_conv_blocks)
+                                plan_conv_blocks, tiled_bytes,
+                                vmem_request_bytes)
 
 __all__ = ["conv2d_folded", "default_plan", "DATAFLOWS",
            "OperandSpec", "FoldKernelSpec", "fold_kernel_spec"]
@@ -77,59 +78,134 @@ __all__ = ["conv2d_folded", "default_plan", "DATAFLOWS",
 DATAFLOWS = ("weight_stationary", "output_stationary", "depthwise")
 
 
-def _fold_partial(xv, w_ref, i_p, *, r: int, s: int, stride: int,
-                  p_block: int, q: int, acc_dtype=jnp.float32):
-    """One fold interaction (Fig 4): R*S stationary taps against a strided
-    window of the resident image rows.  Returns (nf_b, p_block, q) in
-    ``acc_dtype`` — fp32 for the fp32 path, int32 for int8 streams (the
-    MXU contracts the int8 operands directly and widens per-product; the
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _tap_lane(si: int, stride: int, wph: int) -> int:
+    """First lane of filter column ``si``'s window in the stride-phase
+    input layout (``_phase_split``): column ``si + stride*j`` of the padded
+    row lives at lane ``(si % stride) * wph + si // stride + j``, so every
+    tap reads ``q`` *contiguous* lanes — no strided lane access."""
+    return (si % stride) * wph + si // stride
+
+
+def _row_taps(x_ref, w_ref, xrow, *, r: int, s: int, stride: int, wph: int,
+              q: int, acc_dtype):
+    """One output row of one fold interaction (Fig 4): the R*S stationary
+    taps, each an (nf_b, c_b) filter-fold column, against the (c_b, q)
+    image-row window under it.  ``xrow`` is the first input row.  Returns
+    (nf_b, q) in ``acc_dtype`` — fp32 for the fp32 path (contracted at
+    ``Precision.HIGHEST``: full fp32 products, fp32 accumulation), int32
+    for int8 streams (the MXU contracts the int8 operands directly; the
     int32 depth-fold accumulation is exact, see ``core/quant.py``)."""
-    nf_b = w_ref.shape[0]
-    row0 = i_p * p_block * stride
-    rows = (p_block - 1) * stride + r
-    xwin = jax.lax.dynamic_slice(
-        xv, (0, row0, 0), (xv.shape[0], rows, xv.shape[2]))
-    acc = jnp.zeros((nf_b, p_block, q), dtype=acc_dtype)
+    fp32 = acc_dtype == jnp.float32
+    acc = None
     for ri in range(r):
+        line = x_ref[0, :, xrow + ri, :]             # (c_b, stride * wph)
         for si in range(s):
-            win = xwin[:, ri:ri + p_block * stride:stride,
-                       si:si + q * stride:stride]        # (c_b, p_b, Q)
-            tap = w_ref[:, :, ri, si]                    # (nf_b, c_b)
-            if acc_dtype == jnp.float32:
+            lane0 = _tap_lane(si, stride, wph)
+            win = line[:, lane0:lane0 + q]                      # (c_b, q)
+            tap = w_ref[ri * s + si]                            # (nf_b, c_b)
+            if fp32:
                 tap = tap.astype(jnp.float32)
                 win = win.astype(jnp.float32)
-            acc += jax.lax.dot_general(
-                tap, win.reshape(win.shape[0], -1),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype,
-            ).reshape(acc.shape)
+            part = jnp.dot(tap, win, preferred_element_type=acc_dtype,
+                           precision=_HIGHEST if fp32 else None)
+            acc = part if acc is None else acc + part
     return acc
 
 
-def _flush_value(v, b_ref, epi: Epilogue, res=None):
-    """Apply the fused epilogue to a finished fp32 fold (nf_b, p_b, q).
-
-    ``b_ref`` is the (nf_b, 3) per-filter vector block: column 0 the bias,
-    columns 1-2 the folded batch-norm scale/shift (``Epilogue.scale``) —
-    unused columns are never read."""
+def _epilogue_row(v, b_ref, epi: Epilogue, res=None):
+    """Apply the fused epilogue (bar the pool) to one finished fp32 output
+    row (nf_b, q).  ``b_ref`` is the (nf_b, 3) per-filter vector block:
+    column 0 the bias, columns 1-2 the folded batch-norm scale/shift
+    (``Epilogue.scale``) — unused columns are never read."""
     if epi.bias:
-        v = v + b_ref[:, 0].astype(jnp.float32)[:, None, None]
+        v = v + b_ref[:, 0:1].astype(jnp.float32)
     if epi.scale:                            # inference BN: y*scale + shift
-        v = (v * b_ref[:, 1].astype(jnp.float32)[:, None, None]
-             + b_ref[:, 2].astype(jnp.float32)[:, None, None])
+        v = (v * b_ref[:, 1:2].astype(jnp.float32)
+             + b_ref[:, 2:3].astype(jnp.float32))
     if epi.residual:
         v = v + res.astype(jnp.float32)      # ResNet shortcut, pre-ReLU
     if epi.relu:
         v = jnp.maximum(v, 0.0)
     if epi.relu6:
         v = jnp.clip(v, 0.0, 6.0)            # MobileNet activation
-    if epi.pool == "max2":
-        v = maxpool2x2(v)        # p_b forced even: windows stay in-fold
     return v
 
 
-def _ws_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int,
-               stride: int, p_block: int, q: int, n_c: int, epi: Epilogue,
+def _pool_pair(top, bottom):
+    """2x2/2 max-pool of two finished output rows (nf_b, q) -> (nf_b, q//2).
+
+    The vertical max is elementwise; the horizontal pairs are compacted
+    with two 0/1 selection matmuls (even / odd columns), because Mosaic
+    lowers neither a lane-strided slice nor a lane-splitting reshape.  At
+    ``Precision.HIGHEST`` a product with 1.0 plus exact zeros reproduces
+    each value bit for bit, so the pool stays exact."""
+    m = jnp.maximum(top, bottom)
+    q = m.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q // 2), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q // 2), 1)
+
+    def pick(offset):
+        sel = (rows == 2 * cols + offset).astype(jnp.float32)
+        return jnp.dot(m, sel, preferred_element_type=jnp.float32,
+                       precision=_HIGHEST)
+    return jnp.maximum(pick(0), pick(1))
+
+
+def _flush_rows(rows, k, b_ref, res_ref, out_ref, epi: Epilogue, *,
+                res_row0, out_row0):
+    """Flush one row group — two rows when the 2x2 pool is fused, else one
+    — of finished accumulator values.  ``rows`` is [(local_row, value)];
+    ``k`` indexes the group within the fold."""
+    vals = []
+    for j, v in rows:
+        res = (res_ref[0, :, res_row0 + j, :] if epi.residual else None)
+        vals.append(_epilogue_row(v.astype(jnp.float32), b_ref, epi, res))
+    if epi.pool == "max2":
+        out_ref[0, :, out_row0 + k, :] = _pool_pair(*vals).astype(
+            out_ref.dtype)
+    else:
+        out_ref[0, :, out_row0 + k, :] = vals[0].astype(out_ref.dtype)
+
+
+def _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref, *, i_c, i_p,
+               n_c: int, acc_row0, res_row0, out_row0, r: int, s: int,
+               stride: int, wph: int, p_block: int, q: int, epi: Epilogue,
+               acc_dtype):
+    """The shared fold body of the WS and OS kernels: walk the P fold's
+    output rows (in pool pairs when the pool is fused), accumulate each
+    row's depth-fold partial into ``acc_ref`` (first depth fold writes,
+    later ones add), and flush the epilogue on the last depth fold.  The
+    dataflows differ only in where the accumulator rows live
+    (``acc_row0``: full-height WS scratch vs the OS block scratch) and in
+    the grid order that decides which operand stays resident."""
+    group = 2 if epi.pool == "max2" else 1   # p_block is even when pooled
+
+    def body(k, carry):
+        rows = []
+        for u in range(group):
+            j = k * group + u                           # row in the fold
+            part = _row_taps(x_ref, w_ref, (i_p * p_block + j) * stride,
+                             r=r, s=s, stride=stride, wph=wph, q=q,
+                             acc_dtype=acc_dtype)
+            acc = jnp.where(i_c == 0, part,
+                            acc_ref[:, acc_row0 + j, :] + part)
+            acc_ref[:, acc_row0 + j, :] = acc
+            rows.append((j, acc))
+
+        @pl.when(i_c == n_c - 1)
+        def _flush():
+            _flush_rows(rows, k, b_ref, res_ref, out_ref, epi,
+                        res_row0=res_row0, out_row0=out_row0)
+        return carry
+
+    jax.lax.fori_loop(0, p_block // group, body, 0)
+
+
+def _ws_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
+               wph: int, p_block: int, q: int, n_c: int, epi: Epilogue,
                acc_dtype=jnp.float32):
     """Weight-stationary with in-kernel depth reduction.
 
@@ -145,100 +221,82 @@ def _ws_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int,
     """
     res_ref, (out_ref, acc_ref) = (refs[0] if epi.residual else None,
                                    refs[-2:])
-    i_c = pl.program_id(2)
     i_p = pl.program_id(3)
-    part = _fold_partial(x_ref[0], w_ref, i_p, r=r, s=s, stride=stride,
-                         p_block=p_block, q=q, acc_dtype=acc_dtype)
     row0 = i_p * p_block
-
-    @pl.when(i_c == 0)
-    def _init():
-        acc_ref[:, pl.ds(row0, p_block), :] = part
-
-    @pl.when(i_c > 0)
-    def _accumulate():
-        acc_ref[:, pl.ds(row0, p_block), :] += part
-
-    @pl.when(i_c == n_c - 1)
-    def _flush():
-        res = (res_ref[0, :, pl.ds(row0, p_block), :]
-               if epi.residual else None)
-        v = _flush_value(
-            acc_ref[:, pl.ds(row0, p_block), :].astype(jnp.float32),
-            b_ref, epi, res)
-        if epi.pool == "max2":
-            out_ref[0, :, pl.ds(i_p * (p_block // 2), p_block // 2), :] = (
-                v.astype(out_ref.dtype))
-        else:
-            out_ref[0, :, pl.ds(row0, p_block), :] = v.astype(out_ref.dtype)
+    _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref,
+               i_c=pl.program_id(2), i_p=i_p, n_c=n_c, acc_row0=row0,
+               res_row0=row0,
+               out_row0=i_p * (p_block // 2) if epi.pool == "max2" else row0,
+               r=r, s=s, stride=stride, wph=wph, p_block=p_block, q=q,
+               epi=epi, acc_dtype=acc_dtype)
 
 
-def _os_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int,
-               stride: int, p_block: int, q: int, n_c: int, epi: Epilogue,
+def _os_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
+               wph: int, p_block: int, q: int, n_c: int, epi: Epilogue,
                acc_dtype=jnp.float32):
-    """Output-stationary variant. Grid: (N, nf, p, c); c fastest."""
+    """Output-stationary variant. Grid: (N, nf, p, c); c fastest — the
+    block-sized accumulator and the output block stay resident across the
+    depth sweep."""
     res_ref, (out_ref, acc_ref) = (refs[0] if epi.residual else None,
                                    refs[-2:])
-    i_p = pl.program_id(2)
-    i_c = pl.program_id(3)
-    part = _fold_partial(x_ref[0], w_ref, i_p, r=r, s=s, stride=stride,
-                         p_block=p_block, q=q, acc_dtype=acc_dtype)
-
-    @pl.when(i_c == 0)
-    def _init():
-        acc_ref[...] = part
-
-    @pl.when(i_c > 0)
-    def _accumulate():
-        acc_ref[...] += part
-
-    @pl.when(i_c == n_c - 1)
-    def _flush():
-        res = res_ref[0] if epi.residual else None
-        out_ref[0] = _flush_value(acc_ref[...].astype(jnp.float32), b_ref,
-                                  epi, res).astype(out_ref.dtype)
+    _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref,
+               i_c=pl.program_id(3), i_p=pl.program_id(2), n_c=n_c,
+               acc_row0=0, res_row0=0, out_row0=0, r=r, s=s, stride=stride,
+               wph=wph, p_block=p_block, q=q, epi=epi, acc_dtype=acc_dtype)
 
 
-def _dw_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int,
-               stride: int, p_block: int, q: int, epi: Epilogue,
+def _dw_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
+               wph: int, p_block: int, q: int, epi: Epilogue,
                acc_dtype=jnp.float32):
     """Depthwise kernel: grid (N, c folds, p folds) — **no depth-fold
-    reduction exists**.  Each channel owns exactly one filter, so a grid
-    step's (c_b, p_block, q) output is finished the moment its R*S taps
-    have accumulated: the taps multiply the resident channel rows
-    elementwise on the VPU (no MXU contraction — there is no channel sum),
-    and the epilogue flushes immediately, every step.  Int8 streams widen
-    each operand to int32 *before* the elementwise product (int8 x int8
-    would wrap) and accumulate the R*S taps exactly.
+    reduction exists**.  Each channel owns exactly one filter, so an
+    output row (c_b, q) is finished the moment its R*S taps have
+    accumulated: each tap's (c_b, 1) filter column multiplies the resident
+    channel rows elementwise on the VPU (no MXU contraction — there is no
+    channel sum), and the epilogue flushes immediately.  Int8 streams
+    widen each operand to int32 *before* the elementwise product (int8 x
+    int8 would wrap) and accumulate the R*S taps exactly.
     """
     res_ref, out_ref = (refs[0] if epi.residual else None, refs[-1])
     i_p = pl.program_id(2)
-    xv = x_ref[0]                                      # (c_b, rows, y)
-    row0 = i_p * p_block * stride
-    rows = (p_block - 1) * stride + r
-    xwin = jax.lax.dynamic_slice(
-        xv, (0, row0, 0), (xv.shape[0], rows, xv.shape[2]))
-    acc = jnp.zeros((xv.shape[0], p_block, q), dtype=acc_dtype)
-    for ri in range(r):
-        for si in range(s):
-            win = xwin[:, ri:ri + p_block * stride:stride,
-                       si:si + q * stride:stride]      # (c_b, p_b, q)
-            tap = w_ref[:, 0, ri, si]                  # (c_b,)
-            acc += (win.astype(acc_dtype)
-                    * tap.astype(acc_dtype)[:, None, None])
-    res = res_ref[0] if epi.residual else None
-    out_ref[0] = _flush_value(acc.astype(jnp.float32), b_ref, epi,
-                              res).astype(out_ref.dtype)
+    group = 2 if epi.pool == "max2" else 1
+
+    def body(k, carry):
+        rows = []
+        for u in range(group):
+            j = k * group + u
+            xrow = (i_p * p_block + j) * stride
+            acc = None
+            for ri in range(r):
+                line = x_ref[0, :, xrow + ri, :]
+                for si in range(s):
+                    lane0 = _tap_lane(si, stride, wph)
+                    win = line[:, lane0:lane0 + q]           # (c_b, q)
+                    tap = w_ref[ri * s + si]                 # (c_b, 1)
+                    part = win.astype(acc_dtype) * tap.astype(acc_dtype)
+                    acc = part if acc is None else acc + part
+            rows.append((j, acc))
+        _flush_rows(rows, k, b_ref, res_ref, out_ref, epi, res_row0=0,
+                    out_row0=0)
+        return carry
+
+    jax.lax.fori_loop(0, p_block // group, body, 0)
 
 
 def _ws_psum_kernel(x_ref, w_ref, out_ref, *, r: int, s: int, stride: int,
-                    p_block: int, q: int):
+                    wph: int, p_block: int, q: int):
     """PR-1 weight-stationary formulation: each depth fold emits a
     partial-sum fold to HBM (benchmarking baseline only)."""
     i_p = pl.program_id(3)
-    acc = _fold_partial(x_ref[0], w_ref, i_p, r=r, s=s, stride=stride,
-                        p_block=p_block, q=q)
-    out_ref[0, 0] = acc.astype(out_ref.dtype)
+
+    def body(j, carry):
+        part = _row_taps(x_ref, w_ref, (i_p * p_block + j) * stride, r=r,
+                         s=s, stride=stride, wph=wph, q=q,
+                         acc_dtype=jnp.float32)
+        out_ref[0, 0, :, j, :] = part.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, p_block, body, 0)
 
 
 def default_plan(conv: ConvLoopNest, **kw) -> ConvBlockPlan:
@@ -280,8 +338,9 @@ def _ix_ws_x(b, f, cc, pp, *, nfg_folds: int, cg_folds: int):
 
 
 def _ix_ws_w(b, f, cc, pp):
-    """Weight fold: globally filter-indexed, per-group channel-indexed."""
-    return (f, cc, 0, 0)
+    """Weight fold (all R*S taps): globally filter-indexed, per-group
+    channel-indexed."""
+    return (0, f, cc)
 
 
 def _ix_ws_vec(b, f, cc, pp):
@@ -305,7 +364,7 @@ def _ix_os_x(b, f, pp, cc, *, nfg_folds: int, cg_folds: int):
 
 
 def _ix_os_w(b, f, pp, cc):
-    return (f, cc, 0, 0)
+    return (0, f, cc)
 
 
 def _ix_os_vec(b, f, pp, cc):
@@ -327,7 +386,7 @@ def _ix_dw_x(b, cc, pp):
 
 
 def _ix_dw_w(b, cc, pp):
-    return (cc, 0, 0, 0)
+    return (0, cc, 0)
 
 
 def _ix_dw_vec(b, cc, pp):
@@ -373,7 +432,14 @@ class FoldKernelSpec:
     ``reduction_axis`` is the depth-fold grid axis (the only axis allowed
     to revisit the accumulator/output block); ``inner_sliced_axes`` are
     grid axes whose output revisits are *disjoint in-block sub-slices*
-    (the WS kernel's ``pl.ds(row0, p_block)`` rows), not races.
+    (the WS kernel's per-P-fold output rows), not races.
+
+    Operand layouts (what the kernel binds, prepared by ``conv2d_folded``):
+    x is NCHW with its width split into ``stride`` phases of ``wph`` lanes
+    (``_phase_split``; the identity at stride 1); w is tap-major
+    (R*S, N_F, C/G) — (R*S, C, 1) for depthwise — so each tap is one 2-D
+    (filters x channels) tile.  ``scratch`` is the accumulator's shape
+    (None when the kernel keeps none).
     """
     dataflow: str                       # resolved (post-fallback)
     requested: str                      # dataflow as requested by caller
@@ -383,6 +449,7 @@ class FoldKernelSpec:
     inner_sliced_axes: Tuple[int, ...]
     inputs: Tuple[OperandSpec, ...]
     output: OperandSpec
+    scratch: Optional[Tuple[int, ...]]
     epilogue: Epilogue
     plan: ConvBlockPlan                 # clamped to this layer's dims
     groups: int
@@ -399,9 +466,25 @@ class FoldKernelSpec:
     c_pad: int
     p_pad: int
     x_rows: int                         # padded input rows the kernel sees
+    wph: int                            # lanes per stride phase of x
     p_block: int                        # post pool-even bump
     p_valid: int
     q_valid: int
+
+    def vmem_bytes(self, stream_bytes: int = 4) -> int:
+        """VMEM the launch really holds: every operand block twice (the
+        Pallas pipeline double-buffers inputs and the output) plus the
+        accumulator scratch, each padded to the TPU's (sublane, 128)
+        tiles.  The x and w blocks stream at ``stream_bytes`` per element
+        (1 for int8); the vector, residual, output and accumulator blocks
+        are 4 bytes wide."""
+        total = 0
+        for op in (*self.inputs, self.output):
+            itemsize = stream_bytes if op.role in ("x", "w") else 4
+            total += 2 * tiled_bytes(op.block, itemsize)
+        if self.scratch is not None:
+            total += tiled_bytes(self.scratch, 4)
+        return total
 
 
 def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
@@ -414,13 +497,15 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
     """Solve the complete launch geometry for a fold-streamed conv — block
     clamping, the pool-even P bump, padding, and the WS->psum/OS VMEM
     fallback — and return it as inspectable data.  Pure shape arithmetic:
-    no arrays are touched, so the analyzer can call it on any layer."""
+    no arrays are touched, so the analyzer can call it on any layer.
+    ``x_shape`` is the pre-padded NCHW input, ``w_shape`` OIHW."""
     n, c, xp_, yp_ = x_shape
     nf, cw, r, s = w_shape
     assert c == cw * groups, (c, cw, groups)
     assert nf % groups == 0, (nf, groups)
     p = (xp_ - r) // stride + 1
     q = (yp_ - s) // stride + 1
+    wph = -(-yp_ // stride)                 # lanes per stride phase
     epi = epilogue or Epilogue()
     if epi.pool == "max2" and (p < 2 or q < 2):
         raise ValueError(f"cannot fuse 2x2 pool into a {p}x{q} output")
@@ -452,17 +537,23 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
         g_p = -(-p // p_b)
     p_valid, q_valid = epilogue_out_hw(epi, p, q)
     q_o = q // 2 if pooled else q
+    rs = r * s
+
+    def common(**kw):
+        return FoldKernelSpec(
+            requested=requested, epilogue=epi, plan=plan, groups=groups,
+            nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride, wph=wph,
+            p_block=p_b, p_valid=p_valid, q_valid=q_valid, **kw)
 
     if dataflow == "depthwise":
         c_pad, p_pad = g_c * c_b, g_p * p_b
-        rows_needed = (p_pad - 1) * stride + r
-        x_rows = max(xp_, rows_needed)
+        x_rows = max(xp_, (p_pad - 1) * stride + r)
         p_b_o = p_b // 2 if pooled else p_b
         p_o_pad = p_pad // 2 if pooled else p_pad
         inputs = [
-            OperandSpec("x", (1, c_b, x_rows, yp_),
-                        (n, c_pad, x_rows, yp_), _ix_dw_x),
-            OperandSpec("w", (c_b, 1, r, s), (c_pad, 1, r, s), _ix_dw_w),
+            OperandSpec("x", (1, c_b, x_rows, stride * wph),
+                        (n, c_pad, x_rows, stride * wph), _ix_dw_x),
+            OperandSpec("w", (rs, c_b, 1), (rs, c_pad, 1), _ix_dw_w),
             OperandSpec("vec", (c_b, 3), (c_pad, 3), _ix_dw_vec),
         ]
         if epi.residual:
@@ -470,20 +561,17 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
                                       (n, c_pad, p_pad, q), _ix_dw_res))
         out = OperandSpec("out", (1, c_b, p_b_o, q_o),
                           (n, c_pad, p_o_pad, q_o), _ix_dw_out)
-        return FoldKernelSpec(
-            dataflow="depthwise", requested=requested,
-            grid=(n, g_c, g_p), grid_axes=("n", "c", "p"),
-            reduction_axis=None, inner_sliced_axes=(),
-            inputs=tuple(inputs), output=out, epilogue=epi, plan=plan,
-            groups=groups, nfg_folds=1, cg_folds=g_c,
-            nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride,
-            nf_pad=c_pad, c_pad=c_pad, p_pad=p_pad, x_rows=x_rows,
-            p_block=p_b, p_valid=p_valid, q_valid=q_valid)
+        return common(
+            dataflow="depthwise", grid=(n, g_c, g_p),
+            grid_axes=("n", "c", "p"), reduction_axis=None,
+            inner_sliced_axes=(), inputs=tuple(inputs), output=out,
+            scratch=None, nfg_folds=1, cg_folds=g_c, nf_pad=c_pad,
+            c_pad=c_pad, p_pad=p_pad, x_rows=x_rows)
 
     # Pad every tiled dim to an exact block multiple: zero channels/filters
     # contribute nothing to the accumulation, and extra bottom rows only
-    # produce out-of-range outputs that are sliced away.  This keeps the
-    # in-kernel dynamic_slice un-clamped (fold geometry stays exact).
+    # produce out-of-range outputs that are sliced away.  This keeps every
+    # in-kernel row window in bounds (fold geometry stays exact).
     # Aligned layers skip the pads entirely (no copy).  Grouped layers are
     # exactly tiled by construction (blocks divide the per-group extents),
     # so only the bottom-row pad can apply.
@@ -494,8 +582,7 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
         nf_pad, c_pad = g_nf * nf_b, g_c * c_b
         g_nfg = g_nf
     p_pad = g_p * p_b
-    rows_needed = (p_pad - 1) * stride + r
-    x_rows = max(xp_, rows_needed)
+    x_rows = max(xp_, (p_pad - 1) * stride + r)
 
     # a fused residual rides along full-height, resident like the
     # accumulator — it doubles the WS footprint the spill check must price
@@ -512,79 +599,59 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
                     if epi.identity and groups == 1
                     else "output_stationary")
 
+    ws_like = dataflow in ("weight_stationary", "weight_stationary_psum")
+    ix_x, ix_w, ix_vec = ((_ix_ws_x, _ix_ws_w, _ix_ws_vec) if ws_like
+                          else (_ix_os_x, _ix_os_w, _ix_os_vec))
+    inputs = [
+        OperandSpec("x", (1, c_b, x_rows, stride * wph),
+                    (n, c_pad, x_rows, stride * wph),
+                    functools.partial(ix_x, nfg_folds=g_nfg, cg_folds=g_c)),
+        OperandSpec("w", (rs, nf_b, c_b), (rs, nf_pad, c_pad // groups),
+                    ix_w),
+    ]
+    folds = dict(nfg_folds=g_nfg, cg_folds=g_c, nf_pad=nf_pad, c_pad=c_pad,
+                 p_pad=p_pad, x_rows=x_rows)
+
     if dataflow == "weight_stationary_psum":
-        inputs = [
-            OperandSpec("x", (1, c_b, x_rows, yp_), (n, c_pad, x_rows, yp_),
-                        functools.partial(_ix_ws_x, nfg_folds=g_nfg,
-                                          cg_folds=g_c)),
-            OperandSpec("w", (nf_b, c_b, r, s),
-                        (nf_pad, c_pad // groups, r, s), _ix_ws_w),
-        ]
         # out: one partial-sum fold per depth fold (paper Fig 5, staged in
         # HBM — the formulation the in-kernel reduction replaces)
         out = OperandSpec("out", (1, 1, nf_b, p_b, q),
                           (g_c, n, nf_pad, p_pad, q), _ix_psum_out)
-        return FoldKernelSpec(
-            dataflow="weight_stationary_psum", requested=requested,
-            grid=(n, g_nf, g_c, g_p), grid_axes=("n", "nf", "c", "p"),
-            reduction_axis=None, inner_sliced_axes=(),
-            inputs=tuple(inputs), output=out, epilogue=epi, plan=plan,
-            groups=groups, nfg_folds=g_nfg, cg_folds=g_c,
-            nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride,
-            nf_pad=nf_pad, c_pad=c_pad, p_pad=p_pad, x_rows=x_rows,
-            p_block=p_b, p_valid=p_valid, q_valid=q_valid)
+        return common(
+            dataflow="weight_stationary_psum", grid=(n, g_nf, g_c, g_p),
+            grid_axes=("n", "nf", "c", "p"), reduction_axis=None,
+            inner_sliced_axes=(), inputs=tuple(inputs), output=out,
+            scratch=None, **folds)
 
+    inputs.append(OperandSpec("vec", (nf_b, 3), (nf_pad, 3), ix_vec))
+    p_o_pad = p_pad // 2 if pooled else p_pad
     if dataflow == "weight_stationary":
-        p_o_pad = p_pad // 2 if pooled else p_pad
-        inputs = [
-            OperandSpec("x", (1, c_b, x_rows, yp_), (n, c_pad, x_rows, yp_),
-                        functools.partial(_ix_ws_x, nfg_folds=g_nfg,
-                                          cg_folds=g_c)),
-            OperandSpec("w", (nf_b, c_b, r, s),
-                        (nf_pad, c_pad // groups, r, s), _ix_ws_w),
-            OperandSpec("vec", (nf_b, 3), (nf_pad, 3), _ix_ws_vec),
-        ]
         if epi.residual:
             # resident like the output: constant along (c, p)
             inputs.append(OperandSpec("residual", (1, nf_b, p_pad, q),
                                       (n, nf_pad, p_pad, q), _ix_ws_res))
         out = OperandSpec("out", (1, nf_b, p_o_pad, q_o),
                           (n, nf_pad, p_o_pad, q_o), _ix_ws_out)
-        return FoldKernelSpec(
-            dataflow="weight_stationary", requested=requested,
-            grid=(n, g_nf, g_c, g_p), grid_axes=("n", "nf", "c", "p"),
-            reduction_axis=2, inner_sliced_axes=(3,),
-            inputs=tuple(inputs), output=out, epilogue=epi, plan=plan,
-            groups=groups, nfg_folds=g_nfg, cg_folds=g_c,
-            nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride,
-            nf_pad=nf_pad, c_pad=c_pad, p_pad=p_pad, x_rows=x_rows,
-            p_block=p_b, p_valid=p_valid, q_valid=q_valid)
+        # full-height accumulator: the paper's reserved-column partial
+        # sums (int32 for int8 streams — same 4 bytes/elem footprint)
+        return common(
+            dataflow="weight_stationary", grid=(n, g_nf, g_c, g_p),
+            grid_axes=("n", "nf", "c", "p"), reduction_axis=2,
+            inner_sliced_axes=(3,), inputs=tuple(inputs), output=out,
+            scratch=(nf_b, p_pad, q), **folds)
 
     # output_stationary
     p_b_o = p_b // 2 if pooled else p_b
-    p_o_pad = p_pad // 2 if pooled else p_pad
-    inputs = [
-        OperandSpec("x", (1, c_b, x_rows, yp_), (n, c_pad, x_rows, yp_),
-                    functools.partial(_ix_os_x, nfg_folds=g_nfg,
-                                      cg_folds=g_c)),
-        OperandSpec("w", (nf_b, c_b, r, s),
-                    (nf_pad, c_pad // groups, r, s), _ix_os_w),
-        OperandSpec("vec", (nf_b, 3), (nf_pad, 3), _ix_os_vec),
-    ]
     if epi.residual:
         inputs.append(OperandSpec("residual", (1, nf_b, p_b, q),
                                   (n, nf_pad, p_pad, q), _ix_os_res))
     out = OperandSpec("out", (1, nf_b, p_b_o, q_o),
                       (n, nf_pad, p_o_pad, q_o), _ix_os_out)
-    return FoldKernelSpec(
-        dataflow="output_stationary", requested=requested,
-        grid=(n, g_nf, g_p, g_c), grid_axes=("n", "nf", "p", "c"),
-        reduction_axis=3, inner_sliced_axes=(),
-        inputs=tuple(inputs), output=out, epilogue=epi, plan=plan,
-        groups=groups, nfg_folds=g_nfg, cg_folds=g_c,
-        nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride,
-        nf_pad=nf_pad, c_pad=c_pad, p_pad=p_pad, x_rows=x_rows,
-        p_block=p_b, p_valid=p_valid, q_valid=q_valid)
+    return common(
+        dataflow="output_stationary", grid=(n, g_nf, g_p, g_c),
+        grid_axes=("n", "nf", "p", "c"), reduction_axis=3,
+        inner_sliced_axes=(), inputs=tuple(inputs), output=out,
+        scratch=(nf_b, p_b, q), **folds)
 
 
 def _pad_to(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
@@ -593,6 +660,24 @@ def _pad_to(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
     if any(hi for _, hi in pads):
         return jnp.pad(arr, pads)
     return arr
+
+
+def _phase_split(x: jnp.ndarray, stride: int) -> jnp.ndarray:
+    """Reorder the (already padded to ``stride * wph``) width of an NCHW
+    input into ``stride`` contiguous phases: lane ``ph * wph + j`` holds
+    column ``ph + stride * j``.  A strided conv's taps then read
+    contiguous lanes (``_tap_lane``).  Identity at stride 1."""
+    if stride == 1:
+        return x
+    n, c, h, w = x.shape
+    return (x.reshape(n, c, h, w // stride, stride)
+            .transpose(0, 1, 2, 4, 3).reshape(n, c, h, w))
+
+
+def _tap_major(w: jnp.ndarray) -> jnp.ndarray:
+    """OIHW weights -> (R*S, O, I): one 2-D filter-fold tile per tap."""
+    o, i, r, s = w.shape
+    return w.transpose(2, 3, 0, 1).reshape(r * s, o, i)
 
 
 def conv2d_folded(x_padded: jnp.ndarray, w: jnp.ndarray, *,
@@ -633,6 +718,10 @@ def conv2d_folded(x_padded: jnp.ndarray, w: jnp.ndarray, *,
     accumulator folds through HBM with no flush hook to dequantize at, so
     it rejects int8 (unreachable from the engine anyway: the requant
     epilogue is never identity, which psum requires).
+
+    The launch requests exactly the VMEM its blocks hold
+    (``FoldKernelSpec.vmem_bytes``, the figure the planner and foldlint's
+    ``plan.vmem-overflow`` check use) plus headroom for in-kernel values.
     """
     n, c, xp_, yp_ = x_padded.shape
     nf, cw, r, s = w.shape
@@ -679,58 +768,45 @@ def conv2d_folded(x_padded: jnp.ndarray, w: jnp.ndarray, *,
         # epilogue — which an int8 stream never has (requant is an affine)
         raise ValueError("int8 weight_stationary spilled to psum staging, "
                          "which cannot dequantize; use output_stationary")
-    nf_b = spec.plan.nf_block
-    p_b, q_v = spec.p_block, spec.q_valid
 
-    arrays = {"x": x_padded, "w": w, "residual": residual}
+    arrays = {"w": _tap_major(w), "residual": residual}
     args = []
     for op in spec.inputs:
         if op.role == "vec":
-            args.append(_vector_block(nf, op.array_shape[0], epi,
-                                      bias, scale, shift))
+            args.append(_vector_block(nf, op.array_shape[0],
+                                      epi, bias, scale, shift))
+        elif op.role == "x":
+            args.append(_phase_split(_pad_to(x_padded, op.array_shape),
+                                     stride))
         else:
             args.append(_pad_to(arrays[op.role], op.array_shape))
-    in_specs = [op.block_spec() for op in spec.inputs]
-    out_shape = jax.ShapeDtypeStruct(spec.output.array_shape, out_dtype)
-
+    kw = dict(r=r, s=s, stride=stride, wph=spec.wph, p_block=spec.p_block,
+              q=q)
     if spec.dataflow == "depthwise":
-        kern = functools.partial(_dw_kernel, r=r, s=s, stride=stride,
-                                 p_block=p_b, q=q, epi=epi,
-                                 acc_dtype=acc_dtype)
-        out = pl.pallas_call(
-            kern, grid=spec.grid, in_specs=in_specs,
-            out_specs=spec.output.block_spec(), out_shape=out_shape,
-            interpret=interpret,
-        )(*args)
-        return out[:, :nf, :spec.p_valid, :q_v]
-
-    if spec.dataflow == "weight_stationary_psum":
-        kern = functools.partial(_ws_psum_kernel, r=r, s=s, stride=stride,
-                                 p_block=p_b, q=q)
-        partial_sums = pl.pallas_call(
-            kern, grid=spec.grid, in_specs=in_specs,
-            out_specs=spec.output.block_spec(), out_shape=out_shape,
-            interpret=interpret,
-        )(*args)
-        # multi-depth reduce of the partial-sum folds, paid through HBM
-        return partial_sums.sum(axis=0)[:, :nf, :p].astype(out_dtype)
-
-    if spec.dataflow == "weight_stationary":
-        kern = functools.partial(_ws_kernel, r=r, s=s, stride=stride,
-                                 p_block=p_b, q=q, n_c=spec.cg_folds,
-                                 epi=epi, acc_dtype=acc_dtype)
-        # full-height accumulator: the paper's reserved-column partial
-        # sums (int32 for int8 streams — same 4 bytes/elem footprint)
-        scratch = pltpu.VMEM((nf_b, spec.p_pad, q), acc_dtype)
-    else:  # output_stationary
-        kern = functools.partial(_os_kernel, r=r, s=s, stride=stride,
-                                 p_block=p_b, q=q, n_c=spec.cg_folds,
-                                 epi=epi, acc_dtype=acc_dtype)
-        scratch = pltpu.VMEM((nf_b, p_b, q), acc_dtype)
+        kern = functools.partial(_dw_kernel, epi=epi, acc_dtype=acc_dtype,
+                                 **kw)
+    elif spec.dataflow == "weight_stationary_psum":
+        kern = functools.partial(_ws_psum_kernel, **kw)
+    else:
+        body = (_ws_kernel if spec.dataflow == "weight_stationary"
+                else _os_kernel)
+        kern = functools.partial(body, n_c=spec.cg_folds, epi=epi,
+                                 acc_dtype=acc_dtype, **kw)
+    scratch = ([] if spec.scratch is None
+               else [pltpu.VMEM(spec.scratch, acc_dtype)])
+    stream_bytes = jnp.dtype(x_padded.dtype).itemsize
     out = pl.pallas_call(
-        kern, grid=spec.grid, in_specs=in_specs,
-        out_specs=spec.output.block_spec(), out_shape=out_shape,
-        scratch_shapes=[scratch],
+        kern, grid=spec.grid,
+        in_specs=[op.block_spec() for op in spec.inputs],
+        out_specs=spec.output.block_spec(),
+        out_shape=jax.ShapeDtypeStruct(spec.output.array_shape, out_dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_request_bytes(
+                spec.vmem_bytes(stream_bytes))),
         interpret=interpret,
     )(*args)
-    return out[:, :nf, :spec.p_valid, :q_v]
+    if spec.dataflow == "weight_stationary_psum":
+        # multi-depth reduce of the partial-sum folds, paid through HBM
+        return out.sum(axis=0)[:, :nf, :p].astype(out_dtype)
+    return out[:, :nf, :spec.p_valid, :spec.q_valid]

@@ -17,6 +17,8 @@ Dispatch policy:
                     dense-only)
       "direct"    — shifted-matmul reference (grouped via ``groups``)
       "xla"       — lax.conv_general_dilated (feature_group_count)
+    "direct" and "xla" contract at ``Precision.HIGHEST``: float32 on every
+    backend, so they stay references on TPU too.
   * ``plan`` pins a pre-solved ``ConvBlockPlan`` (the engine's schedule
     cache passes these in, so repeated geometries skip re-planning).
 
@@ -81,7 +83,8 @@ def _conv2d_fwd_impl(x, w, stride: int, pad: int, impl: str,
         return jax.lax.conv_general_dilated(
             x, w, (stride, stride), [(pad, pad), (pad, pad)],
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            feature_group_count=groups)
+            feature_group_count=groups,
+            precision=jax.lax.Precision.HIGHEST)
     if impl == "direct":
         return _ref.conv2d_direct(x, w, stride, pad, groups)
     if impl == "im2col":

@@ -9,9 +9,13 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["conv2d_direct", "conv2d_im2col", "conv1d_causal_ref"]
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _pad_nchw(x: jnp.ndarray, pad: int) -> jnp.ndarray:
@@ -30,6 +34,12 @@ def conv2d_direct(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
     accumulates partial sums, mirroring the paper's reduction order.  With
     ``groups > 1`` each filter contracts only its own group's C/G channel
     slice (the depth reduction runs per group; depthwise = groups == C).
+
+    Contractions run at ``Precision.HIGHEST`` (TPU's default would round
+    the operands to bf16).  The tap weights are broadcast over N so that
+    each image is its own matmul: an image's result does not depend on
+    how many images share its batch, which keeps it bitwise equal across
+    serving bucket widths.
     """
     n, c, _, _ = x.shape
     nf, cw, r, s = w.shape
@@ -43,8 +53,10 @@ def conv2d_direct(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
             for si in range(s):
                 win = xp[:, :, ri:ri + p * stride:stride,
                          si:si + q * stride:stride]      # (N, C, P, Q)
-                acc = acc + jnp.einsum("ncpq,fc->nfpq", win, w[:, :, ri, si],
-                                       preferred_element_type=jnp.float32)
+                wt = jnp.broadcast_to(w[:, :, ri, si], (n, nf, c))
+                acc = acc + jnp.einsum("ncpq,nfc->nfpq", win, wt,
+                                       preferred_element_type=jnp.float32,
+                                       precision=_HIGHEST)
         return acc.astype(x.dtype)
     nfg = nf // groups
     xg = xp.reshape(n, groups, cw, xp.shape[2], xp.shape[3])
@@ -54,9 +66,10 @@ def conv2d_direct(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
         for si in range(s):
             win = xg[:, :, :, ri:ri + p * stride:stride,
                      si:si + q * stride:stride]          # (N, G, Cg, P, Q)
-            acc = acc + jnp.einsum("ngcpq,gfc->ngfpq", win,
-                                   wg[:, :, :, ri, si],
-                                   preferred_element_type=jnp.float32)
+            wt = jnp.broadcast_to(wg[:, :, :, ri, si], (n, groups, nfg, cw))
+            acc = acc + jnp.einsum("ngcpq,ngfc->ngfpq", win, wt,
+                                   preferred_element_type=jnp.float32,
+                                   precision=_HIGHEST)
     return acc.reshape(n, nf, p, q).astype(x.dtype)
 
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
@@ -24,10 +25,15 @@ def make_production_mesh(*, multi_pod: bool = False,
         for s in shape:
             n *= s
         devices = jax.devices()[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many local devices exist (tests)."""
+    """A (data, model) mesh over the first ``data * model`` local devices.
+    Axes are ``Auto``: GSPMD propagates shardings from the placed params
+    and inputs, and ``with_sharding_constraint`` pins them where the code
+    asks (``jax.make_mesh`` defaults to ``Explicit`` axes)."""
     devices = jax.devices()[:data * model]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+    return jax.make_mesh((data, model), ("data", "model"), devices=devices,
+                         axis_types=(AxisType.Auto,) * 2)

@@ -279,6 +279,8 @@ def main():
                     choices=["kernel-fault", "nan", "slow-batch", "mixed"],
                     help="which fault schedule --chaos injects")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.vision:
         vision_main(args)
     else:

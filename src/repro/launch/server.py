@@ -29,13 +29,42 @@ import argparse
 import asyncio
 import dataclasses
 import json
+import os
 import threading
 import time
 from typing import List, Optional, Sequence
 
 from repro.launch.serve import VISION_POLICIES
 
-__all__ = ["ServerHandle", "start_server", "build_workers", "main"]
+__all__ = ["ServerHandle", "start_server", "build_workers", "boot_report",
+           "visible_tpu_chips", "main"]
+
+
+def visible_tpu_chips() -> int:
+    """TPU chips this host exposes to JAX, counted the way JAX itself
+    detects them (PCI ids) — without initializing a backend, which would
+    take the chips away from spawned workers.  0 when ``JAX_PLATFORMS``
+    rules the TPU out."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+    return num_available_tpu_chips_and_device_id()[0]
+
+
+def boot_report(workers) -> dict:
+    """What the in-process workers run on: the JAX device platform,
+    ``device_kind`` and count, and the execution mode the engines
+    resolved (``core/engine.py:resolve_execution``)."""
+    import jax
+
+    from repro.core.engine import resolve_execution
+    devices = jax.devices()
+    mode, interpret = resolve_execution(workers[0].worker.engine.compiler.policy)
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices), "mode": mode,
+            "interpret": interpret}
 
 
 def build_workers(model: str, n: int, *, img: int = 32,
@@ -47,7 +76,8 @@ def build_workers(model: str, n: int, *, img: int = 32,
     """N in-process replicas: one ``VisionEngine`` + ``EngineWorker``
     thread each, all compiling over ONE shared ``ScheduleCache`` (the
     second replica's planning is pure cache hits).  Warmup runs
-    sequentially on the calling thread, before any worker serves."""
+    sequentially on the calling thread, before any worker serves.  Every
+    replica runs on JAX's default device."""
     import jax
 
     from repro.core.engine import ScheduleCache
@@ -86,6 +116,7 @@ class ServerHandle:
     thread: threading.Thread
     guard: object = None
     tracer: object = None
+    boot: Optional[dict] = None   # boot_report() of in-process workers
 
     def run(self, coro, timeout: float = 120.0):
         """Run a coroutine on the server loop from sync code."""
@@ -118,13 +149,26 @@ def start_server(model: str = "vgg16", *, host: str = "127.0.0.1",
 
     ``workers`` overrides construction entirely (tests inject fakes);
     ``spawn`` builds subprocess replicas via ``spawn_worker`` instead of
-    in-process engine threads."""
+    in-process engine threads.  In-process workers print their
+    ``boot_report`` (device and execution mode) once built.
+
+    On a TPU host ``spawn`` allows one worker: each worker process opens
+    every chip the host exposes, and a chip belongs to one process, so a
+    second worker would fail or hang at start-up."""
     from repro.obs.metrics import MetricsRegistry
     from repro.serve.router import Router, spawn_worker
     from repro.serve.transport import TransportServer
 
+    boot = None
     if workers is None:
         if spawn:
+            chips = visible_tpu_chips()
+            if chips and n_workers > 1:
+                raise ValueError(
+                    f"--spawn with {n_workers} workers on a TPU host: each "
+                    f"worker process opens every chip the host exposes "
+                    f"({chips}), and a chip belongs to one process. Run "
+                    f"in-process workers (drop --spawn) or --workers 1.")
             tail = ["--model", model, "--backend-policy", policy,
                     "--img", str(img), "--width", str(width_mult),
                     "--classes", str(classes), "--precision", precision,
@@ -137,6 +181,11 @@ def start_server(model: str = "vgg16", *, host: str = "127.0.0.1",
                 model, n_workers, img=img, width_mult=width_mult,
                 classes=classes, policy=policy, buckets=buckets,
                 precision=precision, seed=seed, tracer=tracer)
+            boot = boot_report(workers)
+            print("# boot " + " ".join(f"{k}={v!r}" if isinstance(v, str)
+                                       else f"{k}={v}"
+                                       for k, v in boot.items()),
+                  flush=True)
     router = Router(workers, buckets)
     if registry is None:
         registry = MetricsRegistry(max_series=2048)
@@ -151,7 +200,7 @@ def start_server(model: str = "vgg16", *, host: str = "127.0.0.1",
         server.start(probe_interval_s), loop).result(60.0)
     return ServerHandle(host=host, port=bound, server=server,
                         router=router, workers=workers, loop=loop,
-                        thread=thread, guard=guard, tracer=tracer)
+                        thread=thread, guard=guard, tracer=tracer, boot=boot)
 
 
 def _drain_and_exit(handle: ServerHandle, args) -> None:
@@ -215,7 +264,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     args = ap.parse_args(argv)
 
     from repro.ft.fault_tolerance import PreemptionGuard
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     policy = args.backend_policy or VISION_POLICIES[args.backend]
     buckets = tuple(int(b) for b in args.buckets.split(","))
     tracer = None

@@ -185,7 +185,8 @@ class VisionEngine:
     are placed by ``vision_shardings`` (conv weights and biases on the
     N_F filter-fold axis, everything else replicated) and every staged
     batch carries the ``serving_conv_plan`` batch sharding — GSPMD then
-    runs the same jitted forwards data+model parallel.
+    runs the same jitted forwards data+model parallel, each fold kernel
+    under ``shard_map`` on its device's shard.
 
     **Degradation ladder** (DESIGN.md §10): a primary dispatch that
     raises, or whose active rows come back non-finite, is retried on the
@@ -242,7 +243,7 @@ class VisionEngine:
             head=head, fuse_epilogues=fuse_epilogues, autotune=autotune,
             tuning_path=tuning_path, autotune_timer=autotune_timer,
             tracer=self.tracer if self.tracer.enabled else None,
-            precision=precision)
+            precision=precision, mesh=mesh, mesh_plan=self.plan)
         self.metrics = ServingMetrics()
         self.chaos = chaos
         if chaos is not None and getattr(chaos, "tracer", None) in \
@@ -258,6 +259,7 @@ class VisionEngine:
         # forward; tracing alone stays behind the NULL_TRACER check.
         self.folds = FoldStreamCounters(pe=fold_pe)
         self._req_spans: Dict[int, Any] = {}   # rid -> open lifetime span
+        self.warmup_s: Dict[int, float] = {}   # bucket -> warmup seconds
 
     # -- request side ------------------------------------------------------
     def submit(self, images: np.ndarray,
@@ -541,10 +543,13 @@ class VisionEngine:
     def warmup(self) -> List[int]:
         """Compile and run every bucket width once on zeros, so serving
         latencies measure steady-state forwards, not XLA traces.  Returns
-        the widths warmed.  Chaos never wraps warmup — the injector's
-        dispatch indices count served batches only."""
+        the widths warmed; ``warmup_s`` keeps each width's seconds
+        (schedule build + trace + compile + one run).  Chaos never wraps
+        warmup — the injector's dispatch indices count served batches
+        only."""
         widths = list(self.batcher.policy.widths)
         for w in widths:
+            t0 = time.monotonic()
             net = self.compiler.network_for(w)
             zeros = np.zeros((w, self.batcher.chan, self.batcher.img,
                               self.batcher.img), np.float32)
@@ -553,6 +558,7 @@ class VisionEngine:
             else:
                 x = jnp.asarray(zeros)
             np.asarray(net(self.params, x))
+            self.warmup_s[w] = time.monotonic() - t0
         return widths
 
     def step(self) -> int:

@@ -3,7 +3,7 @@ import math
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.folds import PEArray, decompose
 from repro.core.loopnest import ConvLoopNest, synthetic_suite

@@ -104,11 +104,12 @@ def test_conv2d_strided_gradient():
 def test_fold_kernel_uses_plan_geometry():
     """The Pallas block plan solves eq (2) under VMEM limits."""
     from repro.core.loopnest import ConvLoopNest
-    from repro.core.mapping import plan_conv_blocks
+    from repro.core.mapping import VMEM_LIMIT_BYTES, plan_conv_blocks
     cv = ConvLoopNest(n=1, nf=512, c=512, r=3, s=3, x=56, y=56,
                       stride=1, pad=1)
     plan = plan_conv_blocks(cv)
-    assert plan.vmem_bytes <= 32 * 1024 * 1024      # half of VMEM
+    assert plan.vmem_bytes <= VMEM_LIMIT_BYTES // 2   # half of VMEM
+    assert plan.c_block % 128 == 0                  # weight-fold lane tile
     assert plan.nf_block % 8 == 0                   # MXU lane alignment
     g_nf, g_c, g_p = plan.grid
     assert g_nf * plan.nf_block >= cv.nf

@@ -145,9 +145,9 @@ def test_fused_network_single_pallas_call_per_conv(tiny_mnv2):
     net = mobilenet.compile_forward(params, img=IMG, batch=1,
                                     policy="pallas", jit=False)
     shape = (1, 3, IMG, IMG)
-    # the structured auditor owns the 4-D filtering and pjit-name
+    # the structured auditor owns the 4-D filtering and jit-name
     # resolution these assertions used to hand-roll (rank-1 BN-vector
-    # folds and the 2-D head don't count; jnp.clip traces as a pjit eqn
+    # folds and the 2-D head don't count; jnp.clip traces as a jit eqn
     # named 'clip')
     audit = audit_compiled(net, params, shape)
     assert audit.ok, "\n".join(map(str, audit.findings))

@@ -1,6 +1,7 @@
 """Pipeline parallelism (GPipe over the pod axis): schedule, exactness,
 and a real 4-device shard_map run (subprocess so the device count can be
 forced before jax initializes)."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -11,6 +12,9 @@ import numpy as np
 
 from repro.distributed.pipeline import (gpipe_schedule,
                                         make_pipelined_stack, split_stages)
+
+# the checkout under test: subprocess programs import its src/
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_gpipe_schedule_shape_and_bubble():
@@ -76,5 +80,5 @@ def test_shard_map_pipeline_on_four_devices():
         print("PIPELINE_OK", err)
     """)
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=300)
+                       text=True, cwd=ROOT, timeout=300)
     assert "PIPELINE_OK" in r.stdout, (r.stdout, r.stderr[-1500:])

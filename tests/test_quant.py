@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.engine import (ScheduleCache, ScheduleKey, compile_network,
                                dataflow_traffic_bytes, stream_bytes_per_elem,
                                traffic_components)

@@ -4,7 +4,7 @@ import jax
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.folds import PEArray
 from repro.core.loopnest import ConvLoopNest, vgg16_conv_layers

@@ -1,0 +1,148 @@
+"""The served fold kernels compile for a TPU v5e.
+
+Each test lowers one fold kernel launch of the served models at their
+published widths (224 px, width 1.0, batch bucket 8) and compiles it for
+a v5e chip that is *described*, not attached
+(``jax.experimental.topologies``): the TPU compiler refuses here what it
+would refuse on the chip — an illegal block tiling, a primitive Mosaic
+cannot lower, a kernel that needs more VMEM than it asked for.  The
+interpreter accepts all of these, so these tests are the guard for the
+real lowering.
+
+The kernels are called directly with ``interpret=False`` on shape-only
+arguments: code that asks ``jax.default_backend()`` sees the CPU here and
+would pick interpret mode.  Schedules come from the engine's
+``ScheduleCache`` as on the served path, and each test checks that the
+VMEM the compiled kernel was granted is exactly what foldlint's figure
+(``conv_working_set``: the blocks the kernel really holds) asks for.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.analysis.plan_check import check_plan
+from repro.core.engine import ScheduleCache
+from repro.core.epilogue import Epilogue
+from repro.core.loopnest import ConvLoopNest
+from repro.core.mapping import conv_working_set, vmem_request_bytes
+from repro.core.quant import requant_epilogue
+from repro.kernels.conv2d_ws import conv2d_folded
+
+BATCH = 8                       # the largest default serving bucket
+
+_RELU = Epilogue(bias=True, relu=True)
+_POOL = Epilogue(bias=True, relu=True, pool="max2")
+
+# (input channels, filters, input height = width, stride, groups, epilogue)
+# of every distinct VGG-16 conv launch at 224 px (conv5_2 == conv5_1):
+# eight fold schedules, each with the epilogue and spatial size it serves
+VGG16 = {
+    "conv1_1": (3, 64, 224, 1, 1, _RELU),
+    "conv1_2": (64, 64, 224, 1, 1, _POOL),
+    "conv2_1": (64, 128, 112, 1, 1, _RELU),
+    "conv2_2": (128, 128, 112, 1, 1, _POOL),
+    "conv3_1": (128, 256, 56, 1, 1, _RELU),
+    "conv3_2": (256, 256, 56, 1, 1, _RELU),
+    "conv3_3": (256, 256, 56, 1, 1, _POOL),
+    "conv4_1": (256, 512, 28, 1, 1, _RELU),
+    "conv4_2": (512, 512, 28, 1, 1, _RELU),
+    "conv4_3": (512, 512, 28, 1, 1, _POOL),
+    "conv5_1": (512, 512, 14, 1, 1, _RELU),
+    "conv5_3": (512, 512, 14, 1, 1, _POOL),
+}
+
+# the other dataflows ``policy="auto"`` sends to the same kernels on TPU
+OTHERS = {
+    "resnet18.s3b0_c1": (128, 256, 112, 2, 1, _RELU),          # stride 2
+    "mobilenetv2.b7_dw": (384, 384, 56, 1, 384,                 # depthwise
+                          Epilogue(scale=True, relu6=True)),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e chip.  The persistent compilation cache is off
+    while these compile: an entry written for the described chip cannot
+    be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _granted_vmem(hlo: str):
+    """The scoped-VMEM size each compiled Mosaic kernel was granted."""
+    return [int(m) for m in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"\}\],"custom_call_config"', hlo)]
+
+
+def _compile_launch(one_chip, c, nf, hw, stride, groups, epi,
+                    precision="fp32"):
+    cv = ConvLoopNest(n=BATCH, nf=nf, c=c, r=3, s=3, x=hw, y=hw,
+                      stride=stride, pad=1, groups=groups)
+    sched = ScheduleCache().schedule_for(cv, precision=precision)
+    plan = sched.plan.clamped(cv.nf, cv.c, cv.p)
+    dtype, width = ((jnp.int8, 1) if precision == "int8"
+                    else (jnp.float32, 4))
+    if precision == "int8":
+        epi = requant_epilogue(epi)
+
+    def shape(dims, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    x = shape((cv.n, cv.c, cv.padded_x, cv.padded_y), dtype)
+    w = shape((cv.nf, cv.cg, cv.r, cv.s), dtype)
+    vecs = {}
+    if epi.bias:
+        vecs["bias"] = shape((cv.nf,))
+    if epi.scale:
+        vecs["scale"] = vecs["shift"] = shape((cv.nf,))
+
+    def launch(x, w, vecs):
+        return conv2d_folded(x, w, stride=stride, plan=plan,
+                             dataflow=sched.dataflow, interpret=False,
+                             epilogue=epi, groups=groups, **vecs)
+    hlo = jax.jit(launch).lower(x, w, vecs).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+    rep = check_plan(cv, plan, precision=precision,
+                     dataflow=sched.dataflow, epilogue=epi)
+    assert rep.ok, rep.errors
+    blocks = conv_working_set(cv, plan.nf_block, plan.c_block, plan.p_block,
+                              width, dataflow=sched.dataflow, epilogue=epi)
+    assert _granted_vmem(hlo) == [vmem_request_bytes(blocks)]
+
+
+@pytest.mark.parametrize("layer", sorted(VGG16))
+def test_vgg16_kernel_compiles_for_v5e(one_chip, layer):
+    _compile_launch(one_chip, *VGG16[layer])
+
+
+@pytest.mark.parametrize("layer", sorted(OTHERS))
+def test_strided_and_depthwise_kernels_compile_for_v5e(one_chip, layer):
+    _compile_launch(one_chip, *OTHERS[layer])
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "Mosaic refuses the int8 stream's per-row input load: 'cannot "
+    "statically prove that index in dimension 2 is a multiple of 8' — a "
+    "packed int8 tile cannot be read one row at a dynamic offset "
+    "(ROADMAP Speed 7)"))
+def test_int8_kernel_compiles_for_v5e(one_chip):
+    _compile_launch(one_chip, *VGG16["conv3_2"], precision="int8")

@@ -3,6 +3,7 @@ batcher packing/drain order, engine outputs vs the direct compiled
 forward, pay-once compilation across buckets, and mesh-sharded
 equivalence."""
 import json
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +14,9 @@ import numpy as np
 import pytest
 
 from repro.serve.batcher import BucketPolicy, ImageBatcher
+
+# the checkout under test: subprocess programs import its src/
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 IMG, WIDTH, CLASSES = 32, 0.0625, 10
 
@@ -271,12 +275,14 @@ def test_bucket_compiler_autotune_pay_once_across_buckets(tmp_path):
 # mesh-sharded serving (2 forced host devices, subprocess-isolated)
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("policy", ["auto", "pallas"])
 @pytest.mark.parametrize("mesh_shape", ["2x1", "1x2"])
-def test_mesh_sharded_matches_single_device(mesh_shape):
+def test_mesh_sharded_matches_single_device(mesh_shape, policy):
     """The identical engine code on a 2-device CPU mesh — batch (image
     folds) on the data axis, N_F (filter folds) on the model axis via
     ``MappingPlan.partition_spec`` — produces the single-device outputs
-    bitwise."""
+    bitwise.  Under ``pallas`` every fold kernel runs per shard inside
+    ``shard_map`` (GSPMD cannot partition a Mosaic kernel)."""
     data, model = (int(t) for t in mesh_shape.split("x"))
     prog = textwrap.dedent(f"""
         import os
@@ -295,14 +301,21 @@ def test_mesh_sharded_matches_single_device(mesh_shape):
                 for n in (1, 3, 2)]
 
         single = VisionEngine(params, vgg.to_graph(), img={IMG},
-                              policy="auto", buckets=(2, 4))
+                              policy="{policy}", buckets=(2, 4))
         reqs_s = [single.submit(im) for im in imgs]
         single.run()
 
         mesh = make_local_mesh({data}, {model})
         eng = VisionEngine(params, vgg.to_graph(), img={IMG},
-                           policy="auto", buckets=(2, 4), mesh=mesh)
+                           policy="{policy}", buckets=(2, 4), mesh=mesh)
         assert all(w % {data} == 0 for w in eng.batcher.policy.widths)
+        x = jax.ShapeDtypeStruct((4, 3, {IMG}, {IMG}), np.float32,
+                                 sharding=eng._x_sharding)
+        net = eng.compiler.network_for(4)
+        hlo = net.apply.lower(eng.params, x).as_text()
+        per_shard = hlo.count("sdy.manual_computation")   # shard_map bodies
+        assert per_shard == ({policy!r} == "pallas") * len(
+            net.layer_schedules), per_shard
         reqs_m = [eng.submit(im) for im in imgs]
         eng.run()
         for rs, rm in zip(reqs_s, reqs_m):
@@ -313,7 +326,7 @@ def test_mesh_sharded_matches_single_device(mesh_shape):
         assert spec == want, (spec, want)
         print("MESH_OK", dict(mesh.shape))
     """)
-    out = subprocess.run([sys.executable, "-c", prog], cwd="/root/repo",
+    out = subprocess.run([sys.executable, "-c", prog], cwd=ROOT,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "MESH_OK" in out.stdout
