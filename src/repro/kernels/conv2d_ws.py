@@ -654,6 +654,18 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
         scratch=(nf_b, p_b, q), **folds)
 
 
+_DATAFLOW_TAGS = {"weight_stationary": "ws", "output_stationary": "os",
+                  "depthwise": "dw", "weight_stationary_psum": "wspsum"}
+
+
+def kernel_name(spec: FoldKernelSpec) -> str:
+    """The launch's stable name, from its resolved dataflow and window
+    geometry (``fold_ws_r3s3_st1``): the Mosaic custom call's
+    ``kernel_name``, by which a profiler trace finds the kernel."""
+    return (f"fold_{_DATAFLOW_TAGS[spec.dataflow]}_r{spec.r}s{spec.s}"
+            f"_st{spec.stride}")
+
+
 def _pad_to(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
     """Zero-pad ``arr`` up to ``shape`` (no-op when already aligned)."""
     pads = tuple((0, t - d) for d, t in zip(arr.shape, shape))
@@ -796,7 +808,7 @@ def conv2d_folded(x_padded: jnp.ndarray, w: jnp.ndarray, *,
                else [pltpu.VMEM(spec.scratch, acc_dtype)])
     stream_bytes = jnp.dtype(x_padded.dtype).itemsize
     out = pl.pallas_call(
-        kern, grid=spec.grid,
+        kern, grid=spec.grid, name=kernel_name(spec),
         in_specs=[op.block_spec() for op in spec.inputs],
         out_specs=spec.output.block_spec(),
         out_shape=jax.ShapeDtypeStruct(spec.output.array_shape, out_dtype),

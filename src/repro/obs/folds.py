@@ -103,8 +103,7 @@ class FoldStreamCounters:
     ``observe_compile`` registers a compiled network's layer → schedule
     mapping (idempotent per layer name); ``observe_dispatch`` folds one
     measured kernel interval into the per-schedule totals and returns the
-    per-layer apportionment so the caller can also emit trace spans from
-    the very same numbers.
+    per-layer apportionment.
     """
 
     def __init__(self, pe: Optional[PEArray] = None,

@@ -3,18 +3,39 @@
 A ``Tracer`` records **spans** — named intervals with a category, a
 track (``tid``), and key/value args — through an injectable clock, and
 exports Chrome trace-event JSON that Perfetto / ``chrome://tracing``
-load directly.  The serving stack opens one span per lifecycle stage
-(``submit``/``admit``/``form``/``dispatch``/``kernel``/``epilogue``/
-``degrade``/``complete``) and one *lifetime* span per request on its own
-track, closed at the single terminal accounting point with the outcome
-in ``args`` — so the zero-loss invariant ("every submitted request
-reaches exactly one of ok/rejected/expired/failed") is visible in the
-trace itself.
+load directly.  The serving stack records one flat span per stage of
+the engine thread — ``idle`` (blocked on the inbox), ``submit``,
+``admit``, ``form``, ``stage`` (host→device put), ``dispatch``,
+``readback``, ``epilogue``, ``complete``, ``resolve`` (futures set),
+``degrade`` — so no engine span encloses another and a device idle gap
+names the stage that held the thread.  Beside them: the ``kernel``
+span (cat ``device``, dispatch start → readback done, with the engine
+thread's CPU clock at both ends when tracing), one *lifetime* span per
+request on its own track, closed at the single terminal accounting
+point with the outcome and the request's queue waits in ``args`` — so
+the zero-loss invariant ("every submitted request reaches exactly one
+of ok/rejected/expired/failed") is visible in the trace itself — and
+the HTTP front's spans (``serve/transport.py``): one per wire request
+from its first byte, with ``read``/``decode``/``wait``/``encode``
+children carrying the engine's ``request_id``.
+
+Same clock as the device.  An enabled ``Tracer`` mirrors every inline
+(``begin``/``end``) span outside the ``request`` category into a
+``jax.profiler.TraceAnnotation`` of the same name, opened and closed on
+the recording thread, so a ``jax.profiler`` trace of the server shows
+the host stages on its ``/host:`` plane beside the device ops.  Spans
+recorded with explicit timing (``add_span``) and request lifetimes are
+not mirrored.
+
+Threads.  The engine thread and the HTTP thread record into one
+``Tracer``: span ids come from one shared counter, and open-span
+stacks are kept per (thread, track), so concurrent recorders neither
+repeat an id nor adopt each other's spans as parents.
 
 Determinism: span IDs are a plain sequence number, and all timestamps
-come from the injected ``clock``, so a test driving a fake clock gets a
-byte-identical event list and can assert exact trees via
-``span_tree``.
+come from the injected ``clock``, so a test driving a fake clock from
+one thread gets a byte-identical event list and can assert exact trees
+via ``span_tree``.
 
 The no-op path is ``NULL_TRACER`` (a ``NullTracer``): every method is a
 ``pass``, so instrumented hot paths cost one method call when tracing
@@ -29,16 +50,18 @@ format requires.
 """
 from __future__ import annotations
 
+import itertools
 import json
-from typing import Any, Dict, List, Optional
+import threading
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "SpanHandle",
            "validate_trace", "span_tree"]
 
 # Well-known track ids: one per pipeline stage, requests above REQ_TID0.
-TID_ENGINE = 0        # engine control: stage/form/admission
-TID_DISPATCH = 1      # device dispatch + kernel + per-layer children
-TID_COMPLETE = 2      # readback/epilogue/completion
+TID_ENGINE = 0        # engine control: idle/submit/admit/form/stage
+TID_DISPATCH = 1      # device dispatch + kernel
+TID_COMPLETE = 2      # readback/epilogue/completion/resolve
 TID_COMPILE = 3       # compile_network / schedule planning
 TID_TRANSPORT = 4     # HTTP front-end: one span per wire request
 REQ_TID0 = 1000       # request r lives on track REQ_TID0 + r
@@ -47,11 +70,13 @@ REQ_TID0 = 1000       # request r lives on track REQ_TID0 + r
 class SpanHandle:
     """An open span: returned by ``begin``, closed by ``end``."""
 
-    __slots__ = ("id", "name", "cat", "tid", "ts_s", "args", "parent")
+    __slots__ = ("id", "name", "cat", "tid", "ts_s", "args", "parent",
+                 "thread", "annotation")
 
     def __init__(self, sid: int, name: str, cat: str, tid: int,
                  ts_s: float, args: Dict[str, Any],
-                 parent: Optional[int]) -> None:
+                 parent: Optional[int], thread: int,
+                 annotation=None) -> None:
         self.id = sid
         self.name = name
         self.cat = cat
@@ -59,6 +84,8 @@ class SpanHandle:
         self.ts_s = ts_s
         self.args = args
         self.parent = parent
+        self.thread = thread
+        self.annotation = annotation
 
 
 class Tracer:
@@ -73,20 +100,27 @@ class Tracer:
     enabled = True
 
     def __init__(self, clock, pid: int = 0) -> None:
+        from jax.profiler import TraceAnnotation
         self.clock = clock
         self.pid = int(pid)
         self.events: List[dict] = []
-        self._next_id = 1
-        self._open: Dict[int, List[SpanHandle]] = {}   # tid -> span stack
+        self._ids = itertools.count(1)     # next() is atomic across threads
+        # (thread, tid) -> open-span stack
+        self._open: Dict[Tuple[int, int], List[SpanHandle]] = {}
+        self._annotation = TraceAnnotation
 
     # -- span lifecycle ----------------------------------------------------
     def begin(self, name: str, cat: str = "serve", tid: int = TID_ENGINE,
               **args) -> SpanHandle:
-        stack = self._open.setdefault(tid, [])
+        thread = threading.get_ident()
+        stack = self._open.setdefault((thread, tid), [])
         parent = stack[-1].id if stack else None
-        h = SpanHandle(self._next_id, name, cat, tid, float(self.clock()),
-                       dict(args), parent)
-        self._next_id += 1
+        annotation = None
+        if cat != "request":
+            annotation = self._annotation(name)
+            annotation.__enter__()
+        h = SpanHandle(next(self._ids), name, cat, tid, float(self.clock()),
+                       dict(args), parent, thread, annotation)
         stack.append(h)
         return h
 
@@ -94,16 +128,23 @@ class Tracer:
             **args) -> None:
         """Close ``handle``.  ``discard=True`` drops the span instead of
         recording it — used for no-work iterations (an idle ``form()``
-        call) that would otherwise bury the trace in noise."""
-        stack = self._open.get(handle.tid, [])
+        call) that would otherwise bury the trace in noise; its profiler
+        annotation, already open, is still closed."""
+        key = (handle.thread, handle.tid)
+        stack = self._open.get(key, [])
         if handle in stack:
             # close any children left open (crash paths) along the way
             while stack and stack[-1] is not handle:
                 self.end(stack[-1])
             stack.pop()
+            if not stack:
+                del self._open[key]
+        end_s = None if discard else float(self.clock())
+        if handle.annotation is not None:
+            handle.annotation.__exit__(None, None, None)
+            handle.annotation = None
         if discard:
             return
-        end_s = float(self.clock())
         handle.args.update(args)
         self.events.append(self._event(
             handle.name, handle.cat, "X", handle.tid, handle.ts_s,
@@ -121,18 +162,18 @@ class Tracer:
         an injected fault firing)."""
         self.events.append(self._event(
             name, cat, "i", tid, float(self.clock()), args=dict(args),
-            id=self._next_id, scope="t"))
-        self._next_id += 1
+            id=next(self._ids), scope="t"))
 
     def add_span(self, name: str, cat: str, tid: int, ts_s: float,
                  dur_s: float, parent: Optional[int] = None,
                  **args) -> int:
         """Record a complete span with explicit timing — for intervals
-        not measurable inline, like per-layer kernel spans apportioned
-        from a jitted forward's total (tagged ``apportioned`` by the
-        caller).  Returns the span id for use as a later ``parent``."""
-        sid = self._next_id
-        self._next_id += 1
+        not measurable inline: the device's ``kernel`` interval, a wait
+        recorded only once it returned work, and spans that cross an
+        ``await`` (concurrent connections on one track would otherwise
+        nest into each other).  Returns the span id for use as a later
+        ``parent``."""
+        sid = next(self._ids)
         self.events.append(self._event(
             name, cat, "X", tid, float(ts_s), dur_s=max(0.0, float(dur_s)),
             args=dict(args), id=sid, parent=parent))

@@ -74,6 +74,11 @@ class ImageRequest:
     # the admission controller's predicted queue wait at submit time —
     # the transport layer surfaces it as a 429 Retry-After on shed
     predicted_wait_s: Optional[float] = None
+    # queue-wait stamps on the engine's clock: when a caller on another
+    # thread handed the request over (``t_submit`` for a direct caller)
+    # and when ``form`` took it into a batch (None until then)
+    t_handoff: Optional[float] = None
+    t_formed: Optional[float] = None
 
     @property
     def n(self) -> int:
@@ -254,6 +259,7 @@ class ImageBatcher:
         total = 0
         while self.queue and total + self.queue[0].n <= self.policy.max_width:
             req = self.queue.pop(0)
+            req.t_formed = now
             take.append(req)
             total += req.n
         bucket = self.policy.bucket_for(total)

@@ -45,9 +45,14 @@ Endpoints:
   metrics (the load generator reads ``lost_requests`` here).
 
 Observability: per-endpoint request counters
-(``transport_requests_total{endpoint,status}``) and a per-request
-transport span on ``TID_TRANSPORT`` extend the PR-8 lifecycle traces
-with the wire stage.
+(``transport_requests_total{endpoint,status}``), a handling-time
+histogram (``transport_request_seconds``) and a per-request transport
+span on ``TID_TRANSPORT``, all from the request's first byte (not from
+the keep-alive wait before it).  The span's children are the request's
+stages — ``read`` (first byte → body complete), ``decode`` (body →
+images, deadline header included), ``wait`` (the router and engine) and
+``encode`` (result → response bytes) — each carrying the engine's
+``request_id``, so a request's wire and engine spans join.
 """
 from __future__ import annotations
 
@@ -64,7 +69,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.obs.trace import NULL_TRACER, TID_TRANSPORT
+from repro.obs.trace import (NULL_TRACER, TID_COMPLETE, TID_ENGINE,
+                             TID_TRANSPORT)
 from repro.serve.admission import BadRequestError
 from repro.serve.batcher import ImageRequest
 
@@ -266,9 +272,11 @@ class EngineWorker:
     def submit(self, images: np.ndarray,
                deadline_s: Optional[float] = None) -> Future:
         """Thread-safe: resolves to the terminal ``ImageRequest`` (or
-        raises ``BadRequestError`` for malformed payloads)."""
+        raises ``BadRequestError`` for malformed payloads).  The hand-off
+        is stamped here, so the request's wait in the inbox is seen."""
         fut: Future = Future()
-        self._inbox.put(("infer", (images, deadline_s), fut))
+        self._inbox.put(("infer", (images, deadline_s, time.monotonic()),
+                         fut))
         return fut
 
     def call(self, fn: Callable) -> Future:
@@ -315,10 +323,15 @@ class EngineWorker:
                     break
                 continue
             if not drained:
+                tr = self.engine.tracer
+                t0 = tr.clock() if tr.enabled else None
                 try:
                     item = self._inbox.get(timeout=self.poll_s)
                 except queue.Empty:
                     continue
+                if t0 is not None:   # only a wait that returned work
+                    tr.add_span("idle", "serve", TID_ENGINE, t0,
+                                tr.clock() - t0)
                 self._handle(item)
 
     def _handle(self, item) -> None:
@@ -331,9 +344,10 @@ class EngineWorker:
             except Exception as e:
                 fut.set_exception(e)
             return
-        images, deadline_s = payload
+        images, deadline_s, t_handoff = payload
         try:
-            req = self.engine.submit(images, deadline_s=deadline_s)
+            req = self.engine.submit(images, deadline_s=deadline_s,
+                                     t_handoff=t_handoff)
         except Exception as e:
             fut.set_exception(e)
             return
@@ -345,9 +359,14 @@ class EngineWorker:
     def _resolve_terminal(self) -> None:
         done = [rid for rid, (req, _) in self._waiting.items()
                 if req.outcome.terminal]
+        if not done:
+            return
+        tr = self.engine.tracer
+        span = tr.begin("resolve", tid=TID_COMPLETE, n_requests=len(done))
         for rid in done:
             req, fut = self._waiting.pop(rid)
             fut.set_result(req)
+        tr.end(span)
 
     def _fail_waiting(self, why: str) -> None:
         for _, fut in self._waiting.values():
@@ -368,6 +387,12 @@ async def _read_http_message(reader: asyncio.StreamReader,
     line = await reader.readline()
     if not line:
         return None
+    return await _read_http_rest(reader, line, max_body)
+
+
+async def _read_http_rest(reader: asyncio.StreamReader, line: bytes,
+                          max_body: int):
+    """The headers and body of a message whose start ``line`` is read."""
     parts = line.decode("latin-1").split()
     if len(parts) < 2:
         raise ValueError(f"malformed HTTP start line: {line!r}")
@@ -557,7 +582,14 @@ class TransportServer:
 
     # -- observability -----------------------------------------------------
     def _observe(self, endpoint: str, status: int, t0: float,
-                 **span_args) -> None:
+                 stages: Optional[Dict[str, Tuple[float, float]]] = None,
+                 request_id: Optional[int] = None) -> None:
+        """Count one wire request and record its span from the first
+        byte (``t0``), with one child per ``stages`` entry (name -> start,
+        end), each carrying the engine's ``request_id`` where it has
+        one.  All use explicit timing: they cross ``await``s, so
+        concurrent connections on the one transport track must not nest
+        into each other."""
         dur = self.clock() - t0
         if self.registry is not None:
             self.registry.counter(
@@ -569,8 +601,12 @@ class TransportServer:
                 "Wire request handling time",
                 endpoint=endpoint).record(dur)
         if self.tracer.enabled:
-            self.tracer.add_span(endpoint, "transport", TID_TRANSPORT,
-                                 t0, dur, status=status, **span_args)
+            ids = {} if request_id is None else {"request_id": request_id}
+            sid = self.tracer.add_span(endpoint, "transport", TID_TRANSPORT,
+                                       t0, dur, status=status, **ids)
+            for name, (a, b) in (stages or {}).items():
+                self.tracer.add_span(name, "transport", TID_TRANSPORT, a,
+                                     b - a, parent=sid, **ids)
         if self._access_fh is not None:
             self._access_fh.write(
                 f"{time.time():.3f} {endpoint} {status} "
@@ -581,9 +617,13 @@ class TransportServer:
                            writer: asyncio.StreamWriter) -> None:
         try:
             while True:
-                t0 = self.clock()
                 try:
-                    msg = await _read_http_message(reader, self.max_body)
+                    # a keep-alive connection idles here, outside any span
+                    line = await reader.readline()
+                    if not line:
+                        break        # client closed between requests
+                    t0 = self.clock()            # the request's first byte
+                    msg = await _read_http_rest(reader, line, self.max_body)
                 except PayloadTooLarge as e:
                     # the body was never read: answer and drop the
                     # connection rather than resynchronize mid-stream
@@ -595,21 +635,26 @@ class TransportServer:
                     break
                 except (ValueError, asyncio.IncompleteReadError):
                     break            # malformed framing: drop quietly
-                if msg is None:
-                    break            # client closed between requests
+                stages = {"read": (t0, self.clock())}
                 parts, headers, body = msg
                 method, target = parts[0], parts[1]
                 path = target.split("?", 1)[0]
                 endpoint = f"{method} {path}"
                 status, payload, extra, ctype = await self._route(
-                    method, path, headers, body)
+                    method, path, headers, body, stages)
                 close = (headers.get("connection", "").lower() == "close"
                          or status in (413, 503))
-                writer.write(_http_response(
-                    status, payload, content_type=ctype,
-                    extra_headers=extra, close=close))
+                t_enc = self.clock()
+                request_id = None
+                if isinstance(payload, InferResult):
+                    request_id = payload.request_id
+                    payload = payload.body()
+                raw = _http_response(status, payload, content_type=ctype,
+                                     extra_headers=extra, close=close)
+                stages["encode"] = (t_enc, self.clock())
+                writer.write(raw)
                 await writer.drain()
-                self._observe(endpoint, status, t0)
+                self._observe(endpoint, status, t0, stages, request_id)
                 if close:
                     break
         except (ConnectionError, OSError):
@@ -623,8 +668,11 @@ class TransportServer:
 
     # -- routing -----------------------------------------------------------
     async def _route(self, method: str, path: str,
-                     headers: Dict[str, str], body: bytes):
-        """(status, payload, extra_headers, content_type) per endpoint."""
+                     headers: Dict[str, str], body: bytes,
+                     stages: Dict[str, Tuple[float, float]]):
+        """(status, payload, extra_headers, content_type) per endpoint;
+        ``/v1/infer`` adds its ``decode`` and ``wait`` to ``stages`` and
+        answers an ``InferResult``, encoded by the caller."""
         json_t = "application/json"
         if path == "/healthz":
             if self.draining:
@@ -644,10 +692,11 @@ class TransportServer:
             if method != "POST":
                 return 405, {"error": f"{method} not allowed; POST"}, \
                     None, json_t
-            return await self._infer(headers, body) + (json_t,)
+            return await self._infer(headers, body, stages) + (json_t,)
         return 404, {"error": f"no such endpoint {path!r}"}, None, json_t
 
-    async def _infer(self, headers: Dict[str, str], body: bytes):
+    async def _infer(self, headers: Dict[str, str], body: bytes,
+                     stages: Dict[str, Tuple[float, float]]):
         from repro.serve.router import NoWorkersAvailable
         if self.draining:
             return 503, {"outcome": "draining",
@@ -655,6 +704,7 @@ class TransportServer:
                                   "requested); refusing new requests"}, \
                 None
         try:
+            t_dec = self.clock()
             images, deadline_s = decode_infer_body(body)
             hdr = headers.get("x-deadline-s")
             if hdr is not None:        # the header wins over the body
@@ -664,12 +714,15 @@ class TransportServer:
                     raise BadRequestError(
                         f"X-Deadline-S header {hdr!r} is not a "
                         "number") from e
+            t_wait = self.clock()
+            stages["decode"] = (t_dec, t_wait)
             res = await self.router.infer(images, deadline_s)
+            stages["wait"] = (t_wait, self.clock())
         except BadRequestError as e:
             return 400, {"outcome": "bad_request", "error": str(e)}, None
         except NoWorkersAvailable as e:
             return 503, {"outcome": "unavailable", "error": str(e)}, None
-        return res.status, res.body(), res.headers()
+        return res.status, res, res.headers()
 
     # -- metrics endpoints -------------------------------------------------
     async def _sync_engines(self):

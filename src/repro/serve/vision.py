@@ -263,14 +263,17 @@ class VisionEngine:
 
     # -- request side ------------------------------------------------------
     def submit(self, images: np.ndarray,
-               deadline_s: Optional[float] = None) -> ImageRequest:
+               deadline_s: Optional[float] = None,
+               t_handoff: Optional[float] = None) -> ImageRequest:
         """Validate, admission-check, and enqueue one request.
 
         Malformed payloads raise ``BadRequestError`` (they never get a
         request object).  A well-formed request whose ``deadline_s`` the
         measured queue already blows is *returned un-queued* with
         ``outcome == REJECTED`` (counted ``shed``) — load shedding is a
-        terminal outcome the caller observes, not an exception."""
+        terminal outcome the caller observes, not an exception.
+        ``t_handoff`` (``time.monotonic``) is when a caller on another
+        thread handed the request over; it defaults to now."""
         tr = self.tracer
         sub = tr.begin("submit", tid=TID_ENGINE)
         try:
@@ -279,6 +282,7 @@ class VisionEngine:
             # malformed payload: no request object, no lifetime span
             tr.end(sub, error=repr(e))
             raise
+        req.t_handoff = req.t_submit if t_handoff is None else t_handoff
         self.metrics.submitted += 1
         if tr.enabled:
             # the request's lifetime span, on its own track; closed with
@@ -288,6 +292,8 @@ class VisionEngine:
                 f"request-{req.rid}", cat="request",
                 tid=REQ_TID0 + req.rid, request_id=req.rid,
                 n_images=req.n, deadline_s=deadline_s)
+        # ``submit`` closes where ``admit`` opens: engine spans stay flat
+        tr.end(sub, request_id=req.rid)
         adm = tr.begin("admit", tid=TID_ENGINE)
         ok, predicted = self.admission.admit(
             req.n, self.batcher.pending_images, deadline_s)
@@ -299,10 +305,8 @@ class VisionEngine:
                              f"exceeds deadline {deadline_s:.4f}s")
             self.metrics.shed += 1
             self._account(req)
-            tr.end(sub, request_id=req.rid, shed=True)
             return req
         self.batcher.queue.append(req)
-        tr.end(sub, request_id=req.rid, shed=False)
         return req
 
     @property
@@ -322,7 +326,13 @@ class VisionEngine:
                 m.deadline_hits += 1
         span = self._req_spans.pop(req.rid, None)
         if span is not None:
+            # queue waits from the hand-off: to the engine's submit (the
+            # worker's inbox) and to the batch that took the request
+            waits = {"inbox_ms": (req.t_submit - req.t_handoff) * 1e3}
+            if req.t_formed is not None:
+                waits["queued_ms"] = (req.t_formed - req.t_handoff) * 1e3
             self.tracer.end(span, outcome=key, served_by=req.served_by,
+                            **waits,
                             **({"error": req.error} if req.error else {}))
 
     def _drain_expired(self) -> None:
@@ -347,10 +357,12 @@ class VisionEngine:
                         occupancy=fb.occupancy)
         # one transfer, straight to the (possibly sharded) device layout —
         # never commit to the default device first and reshard
+        span = self.tracer.begin("stage", tid=TID_ENGINE, bucket=fb.bucket)
         if self._x_sharding is not None:
             x = jax.device_put(fb.x, self._x_sharding)
         else:
             x = jnp.asarray(fb.x)
+        self.tracer.end(span)
         return fb, x
 
     def _dispatch(self, staged: Tuple[FormedBatch, jnp.ndarray]):
@@ -358,33 +370,38 @@ class VisionEngine:
         (jit dispatch is async — the device computes while the host forms
         and stages the next batch).  A dispatch-time fault is carried in
         the inflight tuple instead of raised, so the feeder keeps
-        feeding and recovery happens at completion time."""
+        feeding and recovery happens at completion time.  With tracing
+        on, the engine thread's CPU clock is read beside ``t0``."""
         fb, x = staged
         net = self.compiler.network_for(fb.bucket)
         span = self.tracer.begin("dispatch", tid=TID_DISPATCH,
                                  bucket=fb.bucket, n_images=fb.n_images)
         t0 = time.monotonic()
+        cpu0 = time.thread_time() if self.tracer.enabled else None
         try:
             if self.chaos is not None:
                 out = self.chaos.call(lambda a: net(self.params, a), x)
             else:
                 out = net(self.params, x)
             self.tracer.end(span)
-            return fb, out, t0, None
+            return fb, out, (t0, cpu0), None
         except Exception as e:
             self.tracer.end(span, error=repr(e))
-            return fb, None, t0, e
+            return fb, None, (t0, cpu0), e
 
     def _complete(self, inflight, record: bool = True) -> None:
-        fb, out, t0, exc = inflight
+        fb, out, (t0, cpu0), exc = inflight
         tr = self.tracer
         logits = None
         if exc is None:
+            span = tr.begin("readback", tid=TID_COMPLETE, bucket=fb.bucket)
             try:
                 logits = np.asarray(out)  # blocks until the device is done
             except Exception as e:        # a device fault surfaces here
                 exc = e
+            tr.end(span)
         t_done = time.monotonic()
+        cpu_done = time.thread_time() if tr.enabled else None
         duration = t_done - t0
         verdict = self.watchdog.observe(fb.bucket, duration)
         self.admission.observe(fb.bucket, duration)
@@ -395,27 +412,19 @@ class VisionEngine:
             m.batches += 1
             m.occupancy_hist.record(fb.occupancy)
             m.per_bucket[fb.bucket] = m.per_bucket.get(fb.bucket, 0) + 1
-        # the measured device interval: dispatch start -> readback done.
-        # Per-layer children carve it up by each layer's share of the
-        # modeled T_Ops (the forward is one opaque jitted call), tagged
-        # ``apportioned`` so nobody mistakes them for measurements.
-        kernel_id = None
+        # the measured device interval: dispatch start -> readback done,
+        # with the engine thread's CPU clock at both ends — what of the
+        # host gap between two kernels was off the CPU (GIL or blocking)
         if tr.enabled:
-            kernel_id = tr.add_span(
+            tr.add_span(
                 "kernel", "device", TID_DISPATCH, t0, duration,
-                bucket=fb.bucket, n_images=fb.n_images,
+                bucket=fb.bucket, n_images=fb.n_images, cpu_start_s=cpu0,
+                cpu_end_s=cpu_done,
                 **({"error": repr(exc)} if exc is not None else {}))
         if record and exc is None:
             net = self.compiler.network_for(fb.bucket)
-            parts = self.folds.observe_dispatch(
+            self.folds.observe_dispatch(
                 net.layer_schedules, fb.n_images, duration)
-            if tr.enabled:
-                ts = t0
-                for name, key, dur in parts:
-                    tr.add_span(name, "layer", TID_DISPATCH, ts, dur,
-                                parent=kernel_id, schedule=key,
-                                apportioned=True)
-                    ts += dur
         if exc is None and not np.isfinite(logits[:fb.n_images]).all():
             if record:
                 m.nonfinite_batches += 1
@@ -510,10 +519,12 @@ class VisionEngine:
                 self._account(req)
                 tr.end(span, error=repr(e), quarantined=req.rid)
                 return
+            # the failed attempt's span closes before the halves open
+            # theirs: engine spans stay flat
+            tr.end(span, error=repr(e), bisected=True)
             mid = (len(reqs) + 1) // 2     # bisect: isolate the poison
             self._serve_degraded(reqs[:mid], record=record)
             self._serve_degraded(reqs[mid:], record=record)
-            tr.end(span, error=repr(e), bisected=True)
             return
         t_done = time.monotonic()
         m = self.metrics

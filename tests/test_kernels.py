@@ -115,3 +115,24 @@ def test_fold_kernel_uses_plan_geometry():
     assert g_nf * plan.nf_block >= cv.nf
     assert g_c * plan.c_block >= cv.c
     assert g_p * plan.p_block >= cv.p
+
+
+@pytest.mark.parametrize("x_shape,w_shape,kw,name", [
+    ((1, 8, 10, 10), (16, 8, 3, 3), {}, "fold_ws_r3s3_st1"),
+    ((1, 8, 10, 10), (16, 8, 3, 3), {"dataflow": "output_stationary"},
+     "fold_os_r3s3_st1"),
+    ((1, 8, 10, 10), (8, 1, 3, 3), {"dataflow": "depthwise", "groups": 8},
+     "fold_dw_r3s3_st1"),
+    ((1, 8, 9, 9), (16, 8, 1, 1), {"stride": 2}, "fold_ws_r1s1_st2"),
+])
+def test_fold_kernel_launch_carries_a_stable_name(x_shape, w_shape, kw,
+                                                  name):
+    """Each launch's ``pallas_call`` is named from its dataflow and
+    window, the name a profiler trace finds the kernel by."""
+    from repro.kernels.conv2d_ws import conv2d_folded
+    x, w = jnp.ones(x_shape), jnp.ones(w_shape)
+    jaxpr = jax.make_jaxpr(
+        lambda x, w: conv2d_folded(x, w, interpret=True, **kw))(x, w)
+    names = [str(e.params["name"]) for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(names) == 1 and names[0].startswith(name)

@@ -246,6 +246,74 @@ def test_trace_end_closes_dangling_children_and_discard():
     assert t2.events == []
 
 
+def test_span_ids_and_parents_stay_per_thread_under_contention():
+    """Two threads record into one Tracer at once, on the same track:
+    ids never repeat, and each thread's inner span keeps its own outer
+    span as parent (open-span stacks are per thread)."""
+    import sys
+    import threading
+    t = Tracer(FakeClock(step=1e-6))
+    n = 400
+
+    def nest(tag):
+        for _ in range(n):
+            outer = t.begin(f"outer-{tag}", tid=0)
+            t.end(t.begin(f"inner-{tag}", tid=0))
+            t.add_span(f"wire-{tag}", "transport", 4, 0.0, 1e-6)
+            t.end(outer)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=nest, args=(tag,))
+                   for tag in "ab"]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60.0)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    trace = t.to_json()
+    assert validate_trace(trace) == []
+    assert len(trace["traceEvents"]) == 2 * 3 * n
+    by_id = {e["args"]["span_id"]: e for e in trace["traceEvents"]}
+    for e in trace["traceEvents"]:
+        if e["name"].startswith("inner-"):
+            parent = by_id[e["args"]["parent_id"]]
+            assert parent["name"] == "outer-" + e["name"][-1]
+
+
+def test_inline_spans_mirror_into_the_profiler(vgg_params, tmp_path):
+    """A reference-mode engine traced by ``jax.profiler`` on the CPU:
+    the Tracer's inline stage spans come back as annotations on a
+    ``/host:`` plane (the device ops' clock); request lifetimes do not."""
+    import glob
+    from jax.profiler import ProfileData
+    from repro.models import vgg
+    from repro.serve.vision import VisionEngine
+    eng = VisionEngine(vgg_params, vgg.to_graph(), img=IMG,
+                       policy="reference", buckets=(2,),
+                       tracer=Tracer(FakeClock()))
+    eng.warmup()
+    rng = np.random.default_rng(4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            eng.submit(rng.standard_normal((1, 3, IMG, IMG))
+                       .astype(np.float32))
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {ev.name for plane in ProfileData.from_file(path[0]).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"submit", "form", "stage", "dispatch", "readback",
+            "complete"} <= host
+    assert not [n for n in host if n.startswith("request-")]
+
+
 def test_null_tracer_is_inert():
     assert NULL_TRACER.enabled is False
     with NULL_TRACER.span("anything"):
@@ -341,14 +409,18 @@ def test_serving_trace_zero_loss_and_metrics(vgg_params, tmp_path):
     assert validate_trace(trace) == []
     assert check_trace_outcomes(trace, expect_requests=len(sizes)) == []
     names = {e["name"] for e in trace["traceEvents"]}
-    for stage in ("submit", "admit", "form", "dispatch", "kernel",
-                  "epilogue", "complete"):
+    for stage in ("submit", "admit", "form", "stage", "dispatch",
+                  "kernel", "readback", "epilogue", "complete"):
         assert stage in names, f"lifecycle stage {stage!r} missing"
-    # per-layer children hang off each kernel span, apportioned
-    layer_spans = [e for e in trace["traceEvents"]
-                   if e.get("cat") == "layer"]
-    assert layer_spans and all(e["args"]["apportioned"]
-                               for e in layer_spans)
+    # measured stage spans only: no modelled per-layer children
+    assert not [e for e in trace["traceEvents"] if e.get("cat") == "layer"]
+    kernels = [e for e in trace["traceEvents"] if e["name"] == "kernel"]
+    assert all(0 <= e["args"]["cpu_start_s"] <= e["args"]["cpu_end_s"]
+               for e in kernels)
+    # a direct caller hands over at submit: no inbox wait, then the queue
+    lives = [e["args"] for e in trace["traceEvents"]
+             if e.get("cat") == "request"]
+    assert all(a["inbox_ms"] == 0 and a["queued_ms"] >= 0 for a in lives)
     # fold counters cover every distinct schedule with model utilization
     obs = eng.metrics_dict()["observability"]
     assert obs["distinct_schedules"] == len(obs["schedules"])

@@ -29,7 +29,8 @@ from repro.core.epilogue import Epilogue
 from repro.core.loopnest import ConvLoopNest
 from repro.core.mapping import conv_working_set, vmem_request_bytes
 from repro.core.quant import requant_epilogue
-from repro.kernels.conv2d_ws import conv2d_folded
+from repro.kernels.conv2d_ws import (conv2d_folded, fold_kernel_spec,
+                                     kernel_name)
 
 BATCH = 8                       # the largest default serving bucket
 
@@ -120,6 +121,11 @@ def _compile_launch(one_chip, c, nf, hw, stride, groups, epi,
                              epilogue=epi, groups=groups, **vecs)
     hlo = jax.jit(launch).lower(x, w, vecs).compile().as_text()
     assert "tpu_custom_call" in hlo
+    # the launch's stable name reaches the compiled Mosaic custom call
+    spec = fold_kernel_spec(x.shape, w.shape, stride=stride, plan=plan,
+                            dataflow=sched.dataflow, epilogue=epi,
+                            groups=groups)
+    assert f"%{kernel_name(spec)}." in hlo
 
     rep = check_plan(cv, plan, precision=precision,
                      dataflow=sched.dataflow, epilogue=epi)

@@ -168,9 +168,9 @@ def test_deadline_header_propagates_to_engine_submit(served):
     seen = []
     originals = [(e, e.submit) for e in engines(served)]
     for eng, orig in originals:
-        def recorder(images, deadline_s=None, _orig=orig):
+        def recorder(images, deadline_s=None, _orig=orig, **kw):
             seen.append(deadline_s)
-            return _orig(images, deadline_s=deadline_s)
+            return _orig(images, deadline_s=deadline_s, **kw)
         eng.submit = recorder
     try:
         payload = encode_images_payload(images(1, seed=5), deadline_s=1.0)
