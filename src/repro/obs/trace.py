@@ -8,9 +8,12 @@ the engine thread — ``idle`` (blocked on the inbox), ``submit``,
 ``admit``, ``form``, ``stage`` (host→device put), ``dispatch``,
 ``readback``, ``epilogue``, ``complete``, ``resolve`` (futures set),
 ``degrade`` — so no engine span encloses another and a device idle gap
-names the stage that held the thread.  Beside them: the ``kernel``
-span (cat ``device``, dispatch start → readback done, with the engine
-thread's CPU clock at both ends when tracing), one *lifetime* span per
+names the stage that held the thread (a serving worker's ``readback``
+runs on a thread of its own, on ``TID_READBACK``).  Beside them: the
+``kernel`` span (cat ``device``, from when the device could start the
+batch — its dispatch, or the previous batch's readback if that ended
+later — to readback done, with the engine thread's CPU clock at both
+ends when tracing), one *lifetime* span per
 request on its own track, closed at the single terminal accounting
 point with the outcome and the request's queue waits in ``args`` — so
 the zero-loss invariant ("every submitted request reaches exactly one
@@ -27,7 +30,7 @@ the host stages on its ``/host:`` plane beside the device ops.  Spans
 recorded with explicit timing (``add_span``) and request lifetimes are
 not mirrored.
 
-Threads.  The engine thread and the HTTP thread record into one
+Threads.  The engine, readback and HTTP threads record into one
 ``Tracer``: span ids come from one shared counter, and open-span
 stacks are kept per (thread, track), so concurrent recorders neither
 repeat an id nor adopt each other's spans as parents.
@@ -64,6 +67,7 @@ TID_DISPATCH = 1      # device dispatch + kernel
 TID_COMPLETE = 2      # readback/epilogue/completion/resolve
 TID_COMPILE = 3       # compile_network / schedule planning
 TID_TRANSPORT = 4     # HTTP front-end: one span per wire request
+TID_READBACK = 5      # a serving worker's readback thread
 REQ_TID0 = 1000       # request r lives on track REQ_TID0 + r
 
 

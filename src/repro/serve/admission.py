@@ -121,11 +121,13 @@ class AdmissionController:
     predicts what a new request would wait:
 
         wait ~= (batches ahead of it) * service(max bucket)
+                + (the rest of each batch already dispatched)
                 + service(its own bucket)
 
     where "batches ahead" is the pending image count packed at the widest
-    bucket — the drain rate the FIFO actually achieves under load.  A
-    request with deadline ``d`` seconds is rejected when
+    bucket — the drain rate the FIFO actually achieves under load — and a
+    dispatched batch still holds its bucket's service less what it has
+    run.  A request with deadline ``d`` seconds is rejected when
     ``slack * wait > d``.  With no measurements yet (cold start) or no
     deadline, everything is admitted: shedding is strictly evidence-based,
     never speculative.
@@ -168,10 +170,14 @@ class AdmissionController:
         wider = [w for w in self._ewma if w >= bucket]
         return self._ewma[min(wider)] if wider else self._ewma[max(self._ewma)]
 
-    def predicted_wait_s(self, pending_images: int, n: int) -> float:
+    def predicted_wait_s(self, pending_images: int, n: int,
+                         backlog: Sequence[Tuple[int, float]] = ()
+                         ) -> float:
         """Predicted queue delay + service time for an ``n``-image request
-        arriving behind ``pending_images`` queued images (0.0 when no
-        measurements exist yet)."""
+        arriving behind ``pending_images`` queued images and the
+        ``backlog`` of batches already dispatched, ``(bucket, seconds it
+        has run)`` each, whose remaining service is ahead of it too (0.0
+        when no measurements exist yet)."""
         if not self._ewma:
             return 0.0
         widest = max(self.widths)
@@ -180,14 +186,18 @@ class AdmissionController:
         own_bucket = min((w for w in self.widths if w >= n),
                          default=widest)
         own = self.estimate_s(own_bucket) or drain
-        return ahead * drain + own
+        dispatched = sum(max(self.estimate_s(b) - ran, 0.0)
+                         for b, ran in backlog)
+        return ahead * drain + dispatched + own
 
     def admit(self, n: int, pending_images: int,
-              deadline_s: Optional[float]) -> Tuple[bool, float]:
+              deadline_s: Optional[float],
+              backlog: Sequence[Tuple[int, float]] = ()
+              ) -> Tuple[bool, float]:
         """(admit?, predicted wait) for a candidate request.  ``deadline_s``
         is relative seconds from now; ``None`` means no SLO — always
         admitted."""
-        predicted = self.predicted_wait_s(pending_images, n)
+        predicted = self.predicted_wait_s(pending_images, n, backlog)
         ok = (deadline_s is None
               or self.slack * predicted <= deadline_s)
         if self.registry is not None:

@@ -28,8 +28,11 @@ Threading model: jit dispatch and the batcher are synchronous, so each
 ``VisionEngine`` is owned by one dedicated ``EngineWorker`` thread; the
 asyncio side enqueues ``(payload, Future)`` pairs and awaits the future
 (``asyncio.wrap_future``).  The worker drains its inbox before every
-step so concurrent wire requests pack into wide device batches — the
-continuous-batching discipline survives the wire unchanged.
+batch it forms, so concurrent wire requests pack into wide device
+batches — the continuous-batching discipline survives the wire
+unchanged — and keeps serving the inbox while a batch computes, forming
+and dispatching the next full one behind it (a readback thread per
+worker waits for each batch and wakes the worker when it is done).
 
 Endpoints:
 
@@ -70,7 +73,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import numpy as np
 
 from repro.obs.trace import (NULL_TRACER, TID_COMPLETE, TID_ENGINE,
-                             TID_TRANSPORT)
+                             TID_READBACK, TID_TRANSPORT)
 from repro.serve.admission import BadRequestError
 from repro.serve.batcher import ImageRequest
 
@@ -231,13 +234,27 @@ class EngineWorker:
     """One serving worker: a dedicated thread owning a ``VisionEngine``.
 
     The transport enqueues ``(payload, Future)`` pairs; the thread
-    drains its whole inbox before every ``step()`` so concurrent wire
-    requests pack into the same device batch, then resolves each
-    future the moment its request reaches a terminal outcome (including
+    drains its whole inbox before it forms a batch, so concurrent wire
+    requests pack into the same device batch, then resolves each future
+    the moment its request reaches a terminal outcome (including
     submit-time admission rejects and form-time expiries).  ``call``
     runs an arbitrary function against the engine *on the worker
     thread* — stats and metrics snapshots serialize with serving work
     instead of racing it.
+
+    The step is double-buffered: while batch k computes, the thread
+    keeps serving its inbox, and once the queue holds a widest bucket of
+    images it forms, stages and dispatches batch k+1, which the device
+    runs straight after k (the rule is ``VisionEngine.feed``).  With
+    fewer queued, nothing is formed until k is done; then k completes,
+    its callers are answered, the inbox is drained and whatever is
+    queued goes, in a synchronous step's order.  At most one batch waits
+    behind the one computing.  A readback thread blocks on each batch's
+    logits in dispatch order and posts a wake-up into the inbox, so the
+    thread wakes on whichever comes first, a request or a finished
+    batch; completion (scatter, accounting, the degradation ladder)
+    stays on the engine thread, in dispatch order, after the next batch
+    is on its way.
     """
 
     def __init__(self, name: str, engine, *, poll_s: float = 0.002):
@@ -250,6 +267,8 @@ class EngineWorker:
         self._drain = True
         self._thread = threading.Thread(
             target=self._loop, name=f"engine-worker-{name}", daemon=True)
+        # dispatched batches, in order, for the readback thread
+        self._readbacks: "queue.Queue" = queue.Queue()
         # test hook: when set to an (unset) Event the loop idles until
         # it is set — lets tests hold a request in flight deterministically
         self.gate: Optional[threading.Event] = None
@@ -288,7 +307,8 @@ class EngineWorker:
 
     def stop(self, drain: bool = True, timeout: float = 60.0) -> None:
         """Stop the worker; with ``drain`` (the default) everything
-        already accepted completes first — the SIGTERM discipline."""
+        already accepted completes first — the SIGTERM discipline.  A
+        batch already dispatched completes either way."""
         self._drain = drain
         self._stop.set()
         if self._thread.is_alive():
@@ -296,6 +316,24 @@ class EngineWorker:
 
     # -- worker thread -----------------------------------------------------
     def _loop(self) -> None:
+        reader = threading.Thread(target=self._read_back,
+                                  name=f"engine-readback-{self.name}",
+                                  daemon=True)
+        reader.start()
+        try:
+            self._serve()
+        finally:
+            # the reader reads back every dispatched batch before it
+            # stops; any not yet completed (a stop under a closed gate)
+            # complete now
+            self._readbacks.put(None)
+            reader.join()
+            while self.engine.dispatched:
+                self.engine.complete()
+            self._resolve_terminal()
+
+    def _serve(self) -> None:
+        engine = self.engine
         while True:
             gate = self.gate
             if gate is not None and not gate.wait(timeout=0.01):
@@ -310,12 +348,23 @@ class EngineWorker:
                     break
                 self._handle(item)
                 drained += 1
-            if self.engine.pending:
-                self.engine.step()
+            if not engine.computing and engine.complete_ready():
+                # the device is idle: answer batch k's callers and take in
+                # what came meanwhile before forming, as a synchronous
+                # step orders it
                 self._resolve_terminal()
                 continue
+            fed = engine.feed()
+            for inflight in fed:
+                self._readbacks.put(inflight)
+            # behind a computing batch, k completes once k+1 is on its
+            # way, so the host work overlaps k+1's forward
+            completed = engine.complete_ready()
             self._resolve_terminal()
-            if self._stop.is_set():
+            if fed or completed:
+                continue
+            if self._stop.is_set() and not engine.pending \
+                    and not engine.dispatched:
                 if not self._drain:
                     self._fail_waiting("worker stopped without drain")
                     break
@@ -323,7 +372,7 @@ class EngineWorker:
                     break
                 continue
             if not drained:
-                tr = self.engine.tracer
+                tr = engine.tracer
                 t0 = tr.clock() if tr.enabled else None
                 try:
                     item = self._inbox.get(timeout=self.poll_s)
@@ -334,8 +383,20 @@ class EngineWorker:
                                 tr.clock() - t0)
                 self._handle(item)
 
+    def _read_back(self) -> None:
+        """The readback thread: wait for each dispatched batch, in order,
+        and post a wake-up into the inbox (``None`` stops the thread)."""
+        while True:
+            inflight = self._readbacks.get()
+            if inflight is None:
+                return
+            self.engine.readback(inflight, tid=TID_READBACK)
+            self._inbox.put(("done", None, None))
+
     def _handle(self, item) -> None:
         kind, payload, fut = item
+        if kind == "done":          # a batch is read back: a wake-up
+            return
         if not fut.set_running_or_notify_cancel():
             return
         if kind == "call":

@@ -18,7 +18,10 @@ steps:
 * host→device staging **overlaps compute** with a double-buffered
   feeder: while the device runs batch k, batch k+1 is formed and
   ``device_put`` (the ``data/pipeline.py`` idiom of keeping the host one
-  step ahead of the device);
+  step ahead of the device) — ``run`` over a fixed queue, and the
+  served route's ``EngineWorker`` over its inbox, through ``feed``
+  (which forms behind a computing batch only a full widest bucket),
+  ``readback`` and ``complete_ready``;
 * the **fault-tolerant runtime** wraps the dispatch path: per-request
   deadlines with measured-EWMA admission control and form-time expiry
   (``serve/admission.py``), a degradation ladder that retries a failed
@@ -40,6 +43,7 @@ per-conv fold schedules come from the shared graph lowering.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,7 +63,8 @@ from repro.serve.admission import (AdmissionController, DispatchWatchdog,
 from repro.serve.batcher import (BucketPolicy, FormedBatch, ImageBatcher,
                                  ImageRequest)
 
-__all__ = ["ServingMetrics", "VisionEngine", "serving_summary"]
+__all__ = ["InflightBatch", "ServingMetrics", "VisionEngine",
+           "serving_summary"]
 
 
 def _latency_hist() -> LogHistogram:
@@ -107,6 +112,8 @@ class ServingMetrics:
     straggler_events: int = 0     # bucket lane flagged by the detector
     deadline_total: int = 0       # terminal requests that carried an SLO
     deadline_hits: int = 0        # ... that completed OK in time
+    # primary batches formed while another was still on the device
+    overlapped_batches: int = 0
     outcomes: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
@@ -143,6 +150,7 @@ class ServingMetrics:
             "images": self.images,
             "requests": self.requests,
             "batches": self.batches,
+            "overlapped_batches": self.overlapped_batches,
             "elapsed_s": round(self.elapsed_s, 4),
             "kips": round(self.kips, 6),
             "images_per_s": round(self.images / self.elapsed_s, 3)
@@ -171,6 +179,22 @@ class ServingMetrics:
 
 class _NonFiniteOutput(RuntimeError):
     """A primary forward completed but produced NaN/Inf in active rows."""
+
+
+@dataclasses.dataclass
+class InflightBatch:
+    """A primary batch from its dispatch to its completion: ``readback``
+    fills ``logits`` (or ``exc``) and ``t_done``, ``complete`` consumes
+    it.  Clocks are ``time.monotonic``; ``cpu0`` is the engine thread's
+    CPU clock at ``t0``, read only with tracing on."""
+    fb: FormedBatch
+    out: Any
+    t0: float
+    cpu0: Optional[float]
+    exc: Optional[Exception]
+    overlapped: bool              # formed while another was on the device
+    logits: Optional[np.ndarray] = None
+    t_done: Optional[float] = None
 
 
 class VisionEngine:
@@ -259,6 +283,13 @@ class VisionEngine:
         # forward; tracing alone stays behind the NULL_TRACER check.
         self.folds = FoldStreamCounters(pe=fold_pe)
         self._req_spans: Dict[int, Any] = {}   # rid -> open lifetime span
+        # dispatched, not yet completed, in dispatch order; one busy
+        # period runs from the first of them formed to the last completed
+        self._flight: collections.deque = collections.deque()
+        self._busy_since = 0.0
+        # readback done (and the engine thread's CPU clock then) of the
+        # last completed batch: the device starts the next one no earlier
+        self._last_done: Tuple[float, Optional[float]] = (-np.inf, None)
         self.warmup_s: Dict[int, float] = {}   # bucket -> warmup seconds
 
     # -- request side ------------------------------------------------------
@@ -296,7 +327,8 @@ class VisionEngine:
         tr.end(sub, request_id=req.rid)
         adm = tr.begin("admit", tid=TID_ENGINE)
         ok, predicted = self.admission.admit(
-            req.n, self.batcher.pending_images, deadline_s)
+            req.n, self.batcher.pending_images, deadline_s,
+            backlog=self._device_backlog())
         req.predicted_wait_s = predicted
         tr.end(adm, admitted=ok, predicted_wait_s=predicted)
         if not ok:
@@ -312,6 +344,32 @@ class VisionEngine:
     @property
     def pending(self) -> int:
         return len(self.batcher)
+
+    @property
+    def dispatched(self) -> int:
+        """Batches dispatched and not yet completed."""
+        return len(self._flight)
+
+    @property
+    def computing(self) -> int:
+        """Batches dispatched and not yet read back: the one on the
+        device and any queued behind it."""
+        return sum(f.t_done is None and f.exc is None for f in self._flight)
+
+    def _device_backlog(self) -> List[Tuple[int, float]]:
+        """(bucket, seconds it has run) of each computing batch, oldest
+        first: the device's work ahead of a request besides the queue.
+        The oldest has run since the device could start it, the one
+        behind it not at all."""
+        backlog: List[Tuple[int, float]] = []
+        now, free = time.monotonic(), self._last_done[0]
+        for f in self._flight:
+            if f.t_done is not None:
+                free = f.t_done
+            elif f.exc is None:
+                ran = 0.0 if backlog else max(now - max(f.t0, free), 0.0)
+                backlog.append((f.fb.bucket, ran))
+        return backlog
 
     # -- lifecycle accounting ---------------------------------------------
     def _account(self, req: ImageRequest) -> None:
@@ -365,17 +423,20 @@ class VisionEngine:
         self.tracer.end(span)
         return fb, x
 
-    def _dispatch(self, staged: Tuple[FormedBatch, jnp.ndarray]):
+    def _dispatch(self, staged: Tuple[FormedBatch, jnp.ndarray],
+                  overlapped: bool) -> InflightBatch:
         """Launch the bucket's compiled forward; returns without waiting
         (jit dispatch is async — the device computes while the host forms
-        and stages the next batch).  A dispatch-time fault is carried in
-        the inflight tuple instead of raised, so the feeder keeps
-        feeding and recovery happens at completion time.  With tracing
-        on, the engine thread's CPU clock is read beside ``t0``."""
+        and stages the next batch, and queues a second forward behind
+        the first).  A dispatch-time fault is carried in the
+        ``InflightBatch`` instead of raised, so the feeder keeps feeding
+        and recovery happens at completion time.  With tracing on, the
+        engine thread's CPU clock is read beside ``t0``."""
         fb, x = staged
         net = self.compiler.network_for(fb.bucket)
         span = self.tracer.begin("dispatch", tid=TID_DISPATCH,
-                                 bucket=fb.bucket, n_images=fb.n_images)
+                                 bucket=fb.bucket, n_images=fb.n_images,
+                                 overlapped=overlapped)
         t0 = time.monotonic()
         cpu0 = time.thread_time() if self.tracer.enabled else None
         try:
@@ -384,25 +445,108 @@ class VisionEngine:
             else:
                 out = net(self.params, x)
             self.tracer.end(span)
-            return fb, out, (t0, cpu0), None
+            inflight = InflightBatch(fb, out, t0, cpu0, None, overlapped)
         except Exception as e:
             self.tracer.end(span, error=repr(e))
-            return fb, None, (t0, cpu0), e
+            inflight = InflightBatch(fb, None, t0, cpu0, e, overlapped)
+        self._flight.append(inflight)
+        return inflight
 
-    def _complete(self, inflight, record: bool = True) -> None:
-        fb, out, (t0, cpu0), exc = inflight
+    def launch(self) -> Optional[InflightBatch]:
+        """Form, stage and dispatch the next batch without waiting for
+        it; ``None`` when nothing could be formed.  The batch is
+        ``overlapped`` when another was computing as its forming
+        began."""
+        if not self._flight:
+            self._busy_since = time.monotonic()
+        overlapped = self.computing > 0
+        staged = self._stage()
+        return None if staged is None else self._dispatch(staged,
+                                                          overlapped)
+
+    def feed(self) -> List[InflightBatch]:
+        """Dispatch, without waiting, what the full-bucket rule allows:
+        with nothing computing, whatever is queued; behind one computing
+        batch, one more once the queue holds a widest bucket of images;
+        never a second one behind.  The served route's step
+        (``EngineWorker``); returns the batches dispatched."""
+        fed: List[InflightBatch] = []
+        widest = self.batcher.policy.max_width
+        while self.pending:
+            computing = self.computing
+            if computing > 1 or (computing and
+                                 self.batcher.pending_images < widest):
+                break
+            inflight = self.launch()
+            if inflight is None:
+                break
+            fed.append(inflight)
+        return fed
+
+    def readback(self, inflight: InflightBatch, *,
+                 tid: int = TID_COMPLETE) -> None:
+        """Block until the batch's logits are on the host (a device fault
+        surfaces here, into ``exc``) and stamp when.  The wait releases
+        the interpreter lock, so a worker runs this on a thread of its
+        own."""
         tr = self.tracer
-        logits = None
-        if exc is None:
-            span = tr.begin("readback", tid=TID_COMPLETE, bucket=fb.bucket)
+        if inflight.exc is None:
+            span = tr.begin("readback", tid=tid, bucket=inflight.fb.bucket)
             try:
-                logits = np.asarray(out)  # blocks until the device is done
-            except Exception as e:        # a device fault surfaces here
-                exc = e
+                inflight.logits = np.asarray(inflight.out)
+            except Exception as e:
+                inflight.exc = e
+            inflight.out = None
             tr.end(span)
-        t_done = time.monotonic()
-        cpu_done = time.thread_time() if tr.enabled else None
-        duration = t_done - t0
+        inflight.t_done = time.monotonic()
+
+    def complete(self, record: bool = True) -> InflightBatch:
+        """Finish the oldest dispatched batch on the engine thread: read
+        it back unless ``readback`` already has, then account, scatter
+        or degrade.  ``metrics.elapsed_s`` gains each busy period, from
+        its first batch's form to its last batch's completion."""
+        inflight = self._flight[0]
+        if inflight.t_done is None:
+            self.readback(inflight)
+        self._flight.popleft()
+        self._finish(inflight, record)
+        if not self._flight:
+            self.metrics.elapsed_s += time.monotonic() - self._busy_since
+        return inflight
+
+    def complete_ready(self) -> int:
+        """Complete, oldest first, the dispatched batches already read
+        back; returns how many."""
+        n = 0
+        while self._flight and self._flight[0].t_done is not None:
+            self.complete()
+            n += 1
+        return n
+
+    def _finish(self, inflight: InflightBatch, record: bool) -> None:
+        """Account, scatter or degrade a read-back batch.  Its service
+        time runs from when the device could start it — its dispatch,
+        or the previous batch's readback if that ended later — so a
+        batch queued behind another is not charged for the wait."""
+        fb, exc, logits = inflight.fb, inflight.exc, inflight.logits
+        t_done, cpu_done = inflight.t_done, None
+        tr = self.tracer
+        if tr.enabled:
+            # the engine thread's CPU clock at t_done, from its first own
+            # reading after it — now, or the next batch's dispatch — less
+            # the wall time since: the thread's CPU clock read from
+            # another thread can lag, and this bound keeps every off-CPU
+            # gap between two kernel spans >= 0
+            marks = [(time.monotonic(), time.thread_time())]
+            if self._flight and self._flight[0].t0 >= t_done:
+                marks.append((self._flight[0].t0, self._flight[0].cpu0))
+            t_mark, cpu_mark = min(marks)
+            cpu_done = cpu_mark - (t_mark - t_done)
+        t_start, cpu_start = inflight.t0, inflight.cpu0
+        if self._last_done[0] > t_start:
+            t_start, cpu_start = self._last_done
+        self._last_done = (t_done, cpu_done)
+        duration = t_done - t_start
         verdict = self.watchdog.observe(fb.bucket, duration)
         self.admission.observe(fb.bucket, duration)
         m = self.metrics
@@ -410,16 +554,18 @@ class VisionEngine:
             m.hung_batches += verdict.hung
             m.straggler_events += verdict.straggler
             m.batches += 1
+            m.overlapped_batches += inflight.overlapped
             m.occupancy_hist.record(fb.occupancy)
             m.per_bucket[fb.bucket] = m.per_bucket.get(fb.bucket, 0) + 1
-        # the measured device interval: dispatch start -> readback done,
-        # with the engine thread's CPU clock at both ends — what of the
-        # host gap between two kernels was off the CPU (GIL or blocking)
+        # the measured device interval: device free to start -> readback
+        # done, with the engine thread's CPU clock at both ends — what of
+        # the host gap between two kernels was off the CPU (GIL or
+        # blocking); consecutive kernel spans never overlap
         if tr.enabled:
             tr.add_span(
-                "kernel", "device", TID_DISPATCH, t0, duration,
-                bucket=fb.bucket, n_images=fb.n_images, cpu_start_s=cpu0,
-                cpu_end_s=cpu_done,
+                "kernel", "device", TID_DISPATCH, t_start, duration,
+                bucket=fb.bucket, n_images=fb.n_images,
+                cpu_start_s=cpu_start, cpu_end_s=cpu_done,
                 **({"error": repr(exc)} if exc is not None else {}))
         if record and exc is None:
             net = self.compiler.network_for(fb.bucket)
@@ -575,38 +721,26 @@ class VisionEngine:
     def step(self) -> int:
         """Serve one batch synchronously; returns #images served (0 when
         the queue is empty)."""
-        t0 = time.monotonic()
-        staged = self._stage()
-        if staged is None:
+        if self.launch() is None:
             return 0
-        self._complete(self._dispatch(staged))
-        self.metrics.elapsed_s += time.monotonic() - t0
-        return staged[0].n_images
+        return self.complete().fb.n_images
 
     def run(self, max_batches: int = 1_000_000) -> ServingMetrics:
         """Drain the queue with the double-buffered feeder: batch k+1 is
-        formed and staged host→device while the device computes batch k,
-        and completion (the blocking readback) happens only after k+1 has
-        been dispatched.  Recovery (the degradation ladder) runs inside
+        formed, staged host→device and dispatched while the device
+        computes batch k, and k completes (the blocking readback) only
+        after that.  Recovery (the degradation ladder) runs inside
         completion — the feeder never stalls on a fault."""
-        t0 = time.monotonic()
-        inflight = None
         batches = 0
-        # a batch is only formed (popping its requests) while the budget
-        # allows dispatching it, so no request is ever staged and dropped
-        staged = self._stage() if max_batches > 0 else None
-        while staged is not None or inflight is not None:
-            nxt = None
-            if staged is not None:
-                nxt = self._dispatch(staged)
-                batches += 1
-            # host work overlaps the device computing `nxt`
-            staged = self._stage() if batches < max_batches else None
-            if inflight is not None:
-                self._complete(inflight)
-            inflight = nxt
-        self.metrics.elapsed_s += time.monotonic() - t0
-        return self.metrics
+        while True:
+            # a batch is only formed (popping its requests) while the
+            # budget allows dispatching it, so no request is dropped
+            nxt = self.launch() if batches < max_batches else None
+            batches += nxt is not None
+            if self.dispatched > (nxt is not None):
+                self.complete()
+            if nxt is None:
+                return self.metrics
 
     # -- reporting ---------------------------------------------------------
     def metrics_dict(self) -> dict:
@@ -615,9 +749,11 @@ class VisionEngine:
         d["buckets"] = list(self.batcher.policy.widths)
         d["mesh"] = (dict(self.mesh.shape) if self.mesh is not None else None)
         # zero-loss invariant: submitted == terminal + still-queued
+        # + in dispatched batches
         terminal = sum(self.metrics.outcomes.values())
         d["robustness"]["lost_requests"] = (
-            self.metrics.submitted - terminal - self.pending)
+            self.metrics.submitted - terminal - self.pending
+            - sum(len(f.fb.requests) for f in self._flight))
         if self.chaos is not None:
             d["robustness"]["chaos_injected"] = dict(self.chaos.injected)
         # the live per-ScheduleKey table (obs/folds.py): model-side eq-10
@@ -654,6 +790,9 @@ class VisionEngine:
         c("serve_images_total", "Images served OK").set_total(m.images)
         c("serve_batches_total", "Primary batches completed"
           ).set_total(m.batches)
+        c("engine_overlapped_batches_total",
+          "Primary batches formed while another was on the device"
+          ).set_total(m.overlapped_batches)
         for name, help_ in (("shed", "Admission-rejected at submit"),
                             ("expired", "Deadline passed before forming"),
                             ("failed", "Quarantined requests"),

@@ -165,6 +165,23 @@ def test_admission_sheds_on_measured_queue_delay():
     assert ok
 
 
+def test_admission_counts_the_rest_of_dispatched_batches():
+    ac = AdmissionController((1, 2, 4), alpha=1.0)
+    ac.observe(4, 0.1)
+    ac.observe(2, 0.05)
+    # a bucket-4 batch 0.03 s into its forward, a bucket-2 one behind it,
+    # 4 images queued, then its own bucket-4 batch
+    backlog = [(4, 0.03), (2, 0.0)]
+    assert ac.predicted_wait_s(4, 4, backlog) == pytest.approx(
+        0.07 + 0.05 + 0.1 + 0.1)
+    # a forward past its estimate holds nothing more
+    assert ac.predicted_wait_s(0, 4, [(4, 0.5)]) == pytest.approx(0.1)
+    ok, _ = ac.admit(4, 0, deadline_s=0.15, backlog=[(4, 0.0)])
+    assert not ok
+    ok, _ = ac.admit(4, 0, deadline_s=0.15)
+    assert ok
+
+
 def test_admission_estimates_fall_back_to_nearest_bucket():
     ac = AdmissionController((1, 2, 4), alpha=1.0)
     ac.observe(2, 0.05)

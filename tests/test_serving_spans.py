@@ -3,6 +3,8 @@ server, driven over the wire, and its ``Tracer`` read back.
 
 * the engine thread's stage spans are flat — none encloses another — so
   a device idle gap takes the name of the stage that held the thread;
+  the readback thread's spans are flat on their own track, and
+  consecutive ``kernel`` spans never overlap;
 * the transport span starts at a request's first byte, not at the
   keep-alive wait before it, and its ``read``/``decode``/``wait``/
   ``encode`` children carry the engine's ``request_id``;
@@ -18,7 +20,7 @@ import pytest
 
 from repro.launch.server import start_server
 from repro.obs.trace import (TID_COMPLETE, TID_DISPATCH, TID_ENGINE,
-                             Tracer, validate_trace)
+                             TID_READBACK, Tracer, validate_trace)
 from repro.serve.transport import HttpClient, encode_images_payload
 
 IMG = 32
@@ -80,6 +82,13 @@ def test_trace_validates_with_two_threads_recording(traced):
     assert spans(trace, cat="transport") and spans(trace, name="kernel")
 
 
+def _assert_sequential(events):
+    for a, b in zip(events, events[1:]):
+        # each span ends before the next starts (1 ns of slack for the
+        # microsecond rounding of ts and dur)
+        assert b["ts"] >= a["ts"] + a["dur"] - 1e-3, (a, b)
+
+
 def test_engine_thread_spans_are_flat(traced):
     trace, _ = traced
     engine = sorted((e for e in trace["traceEvents"] if e["ph"] == "X"
@@ -88,12 +97,16 @@ def test_engine_thread_spans_are_flat(traced):
                      and e["cat"] != "device"), key=lambda e: e["ts"])
     names = {e["name"] for e in engine}
     assert {"idle", "submit", "admit", "form", "stage", "dispatch",
-            "readback", "epilogue", "complete", "resolve"} <= names
-    for a, b in zip(engine, engine[1:]):
-        # one thread, no nesting: each span ends before the next starts
-        # (1 ns of slack for the microsecond rounding of ts and dur)
-        assert b["ts"] >= a["ts"] + a["dur"] - 1e-3, (a, b)
+            "epilogue", "complete", "resolve"} <= names
+    _assert_sequential(engine)          # one thread, no nesting
     assert not [e for e in engine if "parent_id" in e["args"]]
+    # the blocking readback runs on the worker's readback thread
+    reads = sorted(spans(trace, tid=TID_READBACK), key=lambda e: e["ts"])
+    assert reads and {e["name"] for e in reads} == {"readback"}
+    _assert_sequential(reads)
+    assert not [e for e in reads if "parent_id" in e["args"]]
+    _assert_sequential(sorted(spans(trace, name="kernel"),
+                              key=lambda e: e["ts"]))
 
 
 def test_transport_span_starts_at_first_byte(traced):
