@@ -831,6 +831,9 @@ class CompiledNetwork:
     graph: Optional[StreamGraph] = None   # the graph actually lowered
     precision: str = "fp32"  # streamed conv dtype ("fp32" | "int8")
     quant: Optional[Any] = None  # the QuantRecipe the int8 lowering baked in
+    # per conv, the dataflow its fold kernel launches with (after the
+    # kernel's VMEM fallback); empty where no fold kernel runs
+    fold_dataflows: Tuple[str, ...] = ()
 
     def __call__(self, params: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
         return self.apply(params, x)
@@ -891,6 +894,18 @@ def _verify_graph(original, fused_graph, fused: bool) -> None:
         raise FoldLintError(errors)
 
 
+def _launch_spec(cv: ConvLoopNest, sched: "ConvSchedule", epi,
+                 groups: int):
+    """The fold kernel launch geometry (``FoldKernelSpec``) one conv
+    layer's schedule binds."""
+    from repro.kernels.conv2d_ws import fold_kernel_spec
+    return fold_kernel_spec(
+        (cv.n, cv.c, cv.padded_x, cv.padded_y),
+        (cv.nf, cv.c // groups, cv.r, cv.s), stride=cv.stride,
+        plan=sched.plan.clamped(cv.nf, cv.c, cv.p),
+        dataflow=sched.dataflow, epilogue=epi, groups=groups)
+
+
 def _verify_schedule(name: str, cv: ConvLoopNest, sched: "ConvSchedule",
                      epi, groups: int) -> None:
     """Prove one conv layer's schedule before its kernel is bound: the
@@ -907,16 +922,11 @@ def _verify_schedule(name: str, cv: ConvLoopNest, sched: "ConvSchedule",
     from repro.analysis.index_check import check_kernel_spec
     from repro.analysis.plan_check import check_plan
     from repro.analysis.report import FoldLintError
-    from repro.kernels.conv2d_ws import fold_kernel_spec
     rep = check_plan(cv, plan, where=name, precision=sched.key.precision,
                      dataflow=sched.dataflow, epilogue=epi)
     if rep.ok:
-        spec = fold_kernel_spec(
-            (cv.n, cv.c, cv.padded_x, cv.padded_y),
-            (cv.nf, cv.c // groups, cv.r, cv.s),
-            stride=cv.stride, plan=plan, dataflow=sched.dataflow,
-            epilogue=epi, groups=groups)
-        rep.extend(check_kernel_spec(spec, where=name))
+        rep.extend(check_kernel_spec(_launch_spec(cv, sched, epi, groups),
+                                     where=name))
     if not rep.ok:
         raise FoldLintError(rep.errors)
     _VERIFIED_SCHEDULES[key] = True
@@ -1087,6 +1097,7 @@ def compile_network(params: Dict[str, Any],
     # -- shape-inferring walk: one step per node, schedules built eagerly --
     shapes: Dict[str, Tuple[int, ...]] = {g.input: tuple(input_shape)}
     layer_schedules: List[Tuple[str, ConvSchedule]] = []
+    fold_dataflows: List[str] = []
     plan_steps: List[Tuple] = []   # (op, out, in_names, static payload)
 
     def _need4d(nd, shape):
@@ -1167,15 +1178,17 @@ def compile_network(params: Dict[str, Any],
             x_scale = None
             if precision == "int8":
                 x_scale = quant.scale_for(nd.name)
-            if verify and mode == "pallas":
+            if mode == "pallas":
+                # the epilogue the kernel actually flushes — for int8 the
+                # requant affine always occupies the scale slot
+                kernel_epi = epi
                 if precision == "int8":
-                    # verify the epilogue the kernel actually flushes —
-                    # the requant affine always occupies the scale slot
                     from repro.core.quant import requant_epilogue
-                    _verify_schedule(nd.name, cv, sched,
-                                     requant_epilogue(epi), groups)
-                else:
-                    _verify_schedule(nd.name, cv, sched, epi, groups)
+                    kernel_epi = requant_epilogue(epi)
+                if verify:
+                    _verify_schedule(nd.name, cv, sched, kernel_epi, groups)
+                fold_dataflows.append(
+                    _launch_spec(cv, sched, kernel_epi, groups).dataflow)
             layer_schedules.append((nd.name, sched))
             po, qo = epilogue_out_hw(nd.epilogue, cv.p, cv.q)
             shapes[nd.name] = (n_, nf, po, qo)
@@ -1298,7 +1311,8 @@ def compile_network(params: Dict[str, Any],
                            build_stats=build_stats, cache=cache,
                            mode=mode, interpret=interpret,
                            fused=fused, autotuned=autotune, graph=g,
-                           precision=precision, quant=quant)
+                           precision=precision, quant=quant,
+                           fold_dataflows=tuple(fold_dataflows))
 
 
 # --------------------------------------------------------------------------

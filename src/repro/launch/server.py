@@ -54,17 +54,26 @@ def visible_tpu_chips() -> int:
 
 def boot_report(workers) -> dict:
     """What the in-process workers run on: the JAX device platform,
-    ``device_kind`` and count, and the execution mode the engines
-    resolved (``core/engine.py:resolve_execution``)."""
+    ``device_kind`` and count, the execution mode the engines resolved
+    (``core/engine.py:resolve_execution``), and the fold kernel launches
+    of one forward by dataflow, read off the first worker's compiled
+    forwards (empty before a bucket is compiled, or where the convs do
+    not run on the fold kernels)."""
+    import collections
+
     import jax
 
     from repro.core.engine import resolve_execution
     devices = jax.devices()
-    mode, interpret = resolve_execution(workers[0].worker.engine.compiler.policy)
+    compiler = workers[0].worker.engine.compiler
+    mode, interpret = resolve_execution(compiler.policy)
+    launches = collections.Counter(
+        compiler.network_for(compiler.buckets[0]).fold_dataflows
+        if compiler.buckets else ())
     return {"platform": devices[0].platform,
             "device_kind": devices[0].device_kind,
             "device_count": len(devices), "mode": mode,
-            "interpret": interpret}
+            "interpret": interpret, "fold_launches": dict(launches)}
 
 
 def build_workers(model: str, n: int, *, img: int = 32,

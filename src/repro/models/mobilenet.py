@@ -14,14 +14,19 @@ conv+BN(+residual) — batch-norm folds to the epilogue's scale/shift at
 trace time (``core/graph.py:bn_scale_shift``), so no standalone BN, ReLU6
 or add op survives in the lowered jaxpr.
 
-The default is CIFAR-scale: 3x3 stride-1 stem, the standard (t, c, n, s)
-table with the first two downsamples removed (32px in, 4px at the head),
-global average pool and a single fc classifier.  ``forward`` is the
-graph-free reference walk used as the test oracle; ``to_graph`` exports
-the ``StreamGraph`` the engine lowers.
+One block list and one graph builder serve two stride tables
+(``StrideTable``): ``IMAGENET`` is the published network (Sandler et al.,
+arXiv:1801.04381, Table 2, width 1.0: 3x3 stride-2 stem, 224px in, 7px at
+the head, 1000 classes), registered as ``mobilenetv2_imagenet``;
+``CIFAR``, the default and ``mobilenetv2``, is the same table with the
+stem's and the 24-channel row's strides dropped to 1 (32px in, 4px at the
+head, 10 classes).  Both end in global average pool and a single fc
+classifier.  ``forward`` is the graph-free reference walk used as the test
+oracle; ``to_graph`` exports the ``StreamGraph`` the engine lowers.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -33,25 +38,39 @@ from repro.kernels.ops import conv2d
 
 from repro.models.common import Axes, TreeMaker
 
-__all__ = ["INVERTED_RESIDUAL_CFG", "block_specs", "n_convs",
-           "n_residual_adds", "init_params", "forward", "to_graph",
-           "compile_forward", "bucket_compiler", "n_classes"]
+__all__ = ["StrideTable", "TABLE2", "IMAGENET", "CIFAR", "block_specs",
+           "n_convs", "n_residual_adds", "init_params", "forward",
+           "to_graph", "compile_forward", "bucket_compiler", "n_classes"]
 
-# (expand ratio t, output channels c, repeats n, first-block stride s) —
-# the MobileNetV2 table with the stem and stage-2 strides dropped to 1
-# (CIFAR inputs are 32px; three downsamples remain: 32 -> 16 -> 8 -> 4).
-INVERTED_RESIDUAL_CFG: Tuple[Tuple[int, int, int, int], ...] = (
-    (1, 16, 1, 1), (6, 24, 2, 1), (6, 32, 3, 2), (6, 64, 4, 2),
+# (expand ratio t, output channels c, repeats n, first-block stride s) of
+# every bottleneck row, Table 2 of the paper
+TABLE2: Tuple[Tuple[int, int, int, int], ...] = (
+    (1, 16, 1, 1), (6, 24, 2, 2), (6, 32, 3, 2), (6, 64, 4, 2),
     (6, 96, 3, 1), (6, 160, 3, 2), (6, 320, 1, 1))
 STEM_CH, HEAD_CH = 32, 1280
 n_classes = 10          # CIFAR-scale default
+
+
+@dataclasses.dataclass(frozen=True)
+class StrideTable:
+    """Where the network downsamples: the 3x3 stem's stride and the
+    bottleneck rows (t, c, n, s)."""
+    name: str
+    stem_stride: int
+    rows: Tuple[Tuple[int, int, int, int], ...]
+
+
+IMAGENET = StrideTable("mobilenetv2_imagenet", 2, TABLE2)
+# 32px inputs keep three downsamples: 32 -> 16 -> 8 -> 4
+CIFAR = StrideTable("mobilenetv2", 1, tuple(
+    (t, c, n, 1 if c == 24 else s) for t, c, n, s in TABLE2))
 
 
 def _width(c: int, mult: float) -> int:
     return max(int(c * mult), 1)
 
 
-def block_specs(width_mult: float = 1.0
+def block_specs(width_mult: float = 1.0, strides: StrideTable = CIFAR
                 ) -> List[Tuple[str, int, int, int, int, int]]:
     """The inverted-residual block list:
     (name, cin, cout, stride, expand_t, hidden).
@@ -62,7 +81,7 @@ def block_specs(width_mult: float = 1.0
     specs = []
     cin = _width(STEM_CH, width_mult)
     bi = 0
-    for t, c, n, s in INVERTED_RESIDUAL_CFG:
+    for t, c, n, s in strides.rows:
         cout = _width(c, width_mult)
         for i in range(n):
             stride = s if i == 0 else 1
@@ -72,16 +91,18 @@ def block_specs(width_mult: float = 1.0
     return specs
 
 
-def n_convs() -> int:
+def n_convs(strides: StrideTable = CIFAR) -> int:
     """Conv count (= fused pallas_call count): stem + head + 3 per block
-    (2 when t == 1) — 52 for the default table."""
-    return 2 + sum(2 + (t != 1) for _, _, _, _, t, _ in block_specs())
+    (2 when t == 1) — 52 for either table."""
+    return 2 + sum(2 + (t != 1)
+                   for _, _, _, _, t, _ in block_specs(strides=strides))
 
 
-def n_residual_adds() -> int:
+def n_residual_adds(strides: StrideTable = CIFAR) -> int:
     """Blocks with an identity skip (stride 1, cin == cout) — their adds
     all flush inside the project conv's kernel when fused."""
-    return sum(1 for _, cin, cout, stride, _, _ in block_specs()
+    return sum(1 for _, cin, cout, stride, _, _
+               in block_specs(strides=strides)
                if stride == 1 and cin == cout)
 
 
@@ -125,13 +146,13 @@ def init_params(key: jax.Array, *, width_mult: float = 1.0,
     return p
 
 
-def to_graph() -> StreamGraph:
+def to_graph(strides: StrideTable = CIFAR) -> StreamGraph:
     """Export MobileNetV2 as a streaming graph.  Every conv is followed by
     a ``batchnorm`` node (own parameter entry) and — except the linear
     projection — ``relu6``; the fusion pass folds each chain into the
     conv's epilogue, and the identity-skip ``residual_add`` into the
     project conv (``Epilogue(scale=True, residual=True)``)."""
-    g = StreamGraph(name="mobilenetv2")
+    g = StreamGraph(name=strides.name)
 
     def conv_bn(name: str, src=None, *, stride=1, pad=0, dw=False,
                 act=True) -> str:
@@ -144,8 +165,8 @@ def to_graph() -> StreamGraph:
             g.relu6()
         return g.output
 
-    prev = conv_bn("stem", stride=1, pad=1)
-    for name, cin, cout, stride, t, _ in block_specs():
+    prev = conv_bn("stem", stride=strides.stem_stride, pad=1)
+    for name, cin, cout, stride, t, _ in block_specs(strides=strides):
         h = prev
         if t != 1:
             h = conv_bn(f"{name}_exp", h)
@@ -163,7 +184,8 @@ def to_graph() -> StreamGraph:
 
 
 def forward(params: Dict[str, Any], x: jnp.ndarray,
-            impl: Optional[str] = None) -> jnp.ndarray:
+            impl: Optional[str] = None,
+            strides: StrideTable = CIFAR) -> jnp.ndarray:
     """Graph-free per-layer reference walk (the test oracle): x is
     (N, 3, H, W) NCHW -> (N, classes) logits.  ``impl`` selects the conv
     implementation as in ``kernels/ops.conv2d`` (grouped layers pass
@@ -179,8 +201,8 @@ def forward(params: Dict[str, Any], x: jnp.ndarray,
         y = y * scale[None, :, None, None] + shift[None, :, None, None]
         return jnp.clip(y, 0.0, 6.0) if act else y
 
-    x = conv_bn("stem", x, 1, 1)
-    for name, cin, cout, stride, t, _ in block_specs():
+    x = conv_bn("stem", x, strides.stem_stride, 1)
+    for name, cin, cout, stride, t, _ in block_specs(strides=strides):
         h = x
         if t != 1:
             h = conv_bn(f"{name}_exp", h, 1, 0)

@@ -9,6 +9,7 @@ things the engine needs: an ``init_params`` and a ``to_graph`` exporter
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict
 
 __all__ = ["ConvModelSpec", "register_conv_model", "get_conv_model",
@@ -94,5 +95,9 @@ def _ensure_builtin() -> None:
         register_conv_model("resnet18", resnet.init_params, resnet.to_graph)
     if "mobilenetv2" not in _REGISTRY:
         from repro.models import mobilenet
-        register_conv_model("mobilenetv2", mobilenet.init_params,
-                            mobilenet.to_graph)
+        for strides, classes in ((mobilenet.CIFAR, mobilenet.n_classes),
+                                 (mobilenet.IMAGENET, 1000)):
+            register_conv_model(
+                strides.name,
+                functools.partial(mobilenet.init_params, classes=classes),
+                functools.partial(mobilenet.to_graph, strides))

@@ -260,12 +260,131 @@ def test_serving_summary_mobilenetv2():
     assert d["compile"]["distinct_schedules"] == 27
 
 
-def test_zoo_registers_mobilenetv2():
+@pytest.mark.parametrize("strides,stem_stride",
+                         [(mobilenet.CIFAR, 1), (mobilenet.IMAGENET, 2)],
+                         ids=["cifar", "imagenet"])
+def test_zoo_registers_mobilenetv2(strides, stem_stride):
+    """The model's registered name selects its stride table."""
     from repro.models.zoo import conv_model_names, get_conv_model
-    assert "mobilenetv2" in conv_model_names()
-    spec = get_conv_model("mobilenetv2")
+    assert strides.name in conv_model_names()
+    spec = get_conv_model(strides.name)
     g = spec.to_graph()
+    assert g.name == strides.name
     assert sum(1 for nd in g if nd.op == "conv") == mobilenet.n_convs()
+    stem = next(nd for nd in g if nd.op == "conv")
+    assert (stem.name, stem.stride, stem.pad) == ("stem", stem_stride, 1)
+
+
+# --------------------------------------------------------------------------
+# the published network (Table 2, 224 px, 1000 classes) and its boot report
+# --------------------------------------------------------------------------
+
+def _conv_inputs(strides, img, monkeypatch):
+    """(input NCHW shape, OIHW weight shape, stride) of every conv the
+    engine's forward runs, in order (reference mode, batch 1)."""
+    import functools
+
+    from repro.core import engine
+    params = jax.eval_shape(functools.partial(
+        mobilenet.init_params, width_mult=1.0, img=img, classes=1000),
+        jax.random.PRNGKey(0))
+    net = engine.compile_network(params, mobilenet.to_graph(strides),
+                                 (1, 3, img, img), policy="reference",
+                                 jit=False)
+    seen = []
+    step = engine._conv_step
+
+    def spy(x, w, *a, stride, **kw):
+        seen.append((x.shape, w.shape, stride))
+        return step(x, w, *a, stride=stride, **kw)
+    monkeypatch.setattr(engine, "_conv_step", spy)
+    out = jax.eval_shape(net.apply, params,
+                         jax.ShapeDtypeStruct((1, 3, img, img), jnp.float32))
+    assert out.shape == (1, 1000)
+    return seen
+
+
+def test_published_table_downsamples_224_to_7(monkeypatch):
+    seen = list(_conv_inputs(mobilenet.IMAGENET, 224, monkeypatch))
+    assert len(seen) == mobilenet.n_convs(mobilenet.IMAGENET) == 52
+    assert sum(1 for _, w, _ in seen if w[1] == 1) == 17     # depthwise
+    assert mobilenet.n_residual_adds(mobilenet.IMAGENET) == 10
+    sizes = []
+    for x, _, stride in seen:
+        if not sizes or sizes[-1] != x[2]:
+            sizes.append(x[2])
+    assert sizes == [224, 112, 56, 28, 14, 7]
+    assert seen[0][2] == 2 and seen[-1][0][1:] == (320, 7, 7)
+    # the CIFAR table is the same blocks with two strides dropped
+    cifar = _conv_inputs(mobilenet.CIFAR, 32, monkeypatch)
+    assert [w for _, w, _ in cifar] == [w for _, w, _ in seen]
+    assert sorted({x[2] for x, _, _ in cifar}) == [4, 8, 16, 32]
+    fused = mobilenet.to_graph(mobilenet.IMAGENET)
+    assert sum(1 for nd in fused if nd.op == "residual_add") == 10
+
+
+def test_published_param_count():
+    """3,504,872 parameters at width 1.0 (conv and fc weights, fc bias,
+    BN gamma and beta); the BN running statistics are buffers."""
+    from repro.models.zoo import get_conv_model
+    spec = get_conv_model("mobilenetv2_imagenet")
+    shapes = jax.eval_shape(lambda k: spec.init_params(
+        k, width_mult=1.0, img=224), jax.random.PRNGKey(0))
+    leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    count = sum(int(np.prod(a.shape)) for path, a in leaves
+                if path[-1].key not in ("mean", "var"))
+    assert count == 3_504_872
+    assert shapes["fc"]["w"].shape == (1280, 1000)
+
+
+def test_published_engine_forward_matches_reference_walk():
+    """The fold kernels (interpreted) against the graph-free walk, on the
+    published stride table at a small width and image."""
+    from repro.models import zoo
+    img = 64
+    params = _randomize_bn(mobilenet.init_params(
+        jax.random.PRNGKey(4), width_mult=0.25, img=img, classes=1000))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 3, img, img))
+    net = zoo.compile_forward("mobilenetv2_imagenet", params, img=img,
+                              batch=2, policy="pallas")
+    got = np.asarray(net(params, x))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(mobilenet.forward(params, x, impl="xla",
+                                            strides=mobilenet.IMAGENET))
+    assert got.shape == (2, 1000)
+    assert np.abs(got - want).max() / np.abs(want).max() < 1e-5
+
+
+def test_boot_report_counts_fold_launches_by_dataflow():
+    """The boot report reads the compiled forward's launches: 17 of the
+    52 convs run on the depthwise kernel; none where the convs run as
+    XLA convolutions."""
+    import types
+
+    from repro.launch.server import boot_report
+    from repro.models.zoo import get_conv_model
+    from repro.serve.vision import VisionEngine
+    spec = get_conv_model("mobilenetv2_imagenet")
+    params = spec.init_params(jax.random.PRNGKey(0), width_mult=WIDTH,
+                              img=64)
+
+    def report(policy):
+        eng = VisionEngine(params, spec.to_graph(), img=64, policy=policy,
+                           buckets=(2,))
+        worker = types.SimpleNamespace(
+            worker=types.SimpleNamespace(engine=eng))
+        before = boot_report([worker])["fold_launches"]
+        eng.compiler.network_for(2)
+        return before, boot_report([worker])
+    before, rep = report("pallas")
+    assert before == {}
+    assert rep["mode"] == "pallas"
+    launches = rep["fold_launches"]
+    assert launches["depthwise"] == 17
+    assert sum(launches.values()) == 52
+    assert set(launches) <= {"weight_stationary", "output_stationary",
+                             "depthwise"}
+    assert report("reference")[1]["fold_launches"] == {}
 
 
 # --------------------------------------------------------------------------

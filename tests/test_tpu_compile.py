@@ -62,6 +62,23 @@ OTHERS = {
                           Epilogue(scale=True, relu6=True)),
 }
 
+_BN_RELU6 = Epilogue(scale=True, relu6=True)
+_BN = Epilogue(scale=True)
+
+# (input channels, filters, input height = width, stride, groups,
+# epilogue, kernel size) of MobileNetV2-224's distinct launch shapes
+# (Table 2, width 1.0), at its served bucket of 64
+MOBILENETV2_BUCKET = 64
+MOBILENETV2_224 = {
+    "stem": (3, 32, 224, 2, 1, _BN_RELU6, 3),
+    "b1_exp": (16, 96, 112, 1, 1, _BN_RELU6, 1),
+    "b1_dw": (96, 96, 112, 2, 96, _BN_RELU6, 3),
+    "b13_dw": (576, 576, 14, 2, 576, _BN_RELU6, 3),
+    "b14_dw": (960, 960, 7, 1, 960, _BN_RELU6, 3),
+    "b16_proj": (960, 320, 7, 1, 1, _BN, 1),
+    "head": (320, 1280, 7, 1, 1, _BN_RELU6, 1),
+}
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -94,10 +111,10 @@ def _granted_vmem(hlo: str):
         r'"size":"(\d+)"\}\],"custom_call_config"', hlo)]
 
 
-def _compile_launch(one_chip, c, nf, hw, stride, groups, epi,
-                    precision="fp32"):
-    cv = ConvLoopNest(n=BATCH, nf=nf, c=c, r=3, s=3, x=hw, y=hw,
-                      stride=stride, pad=1, groups=groups)
+def _compile_launch(one_chip, c, nf, hw, stride, groups, epi, k=3,
+                    precision="fp32", batch=BATCH):
+    cv = ConvLoopNest(n=batch, nf=nf, c=c, r=k, s=k, x=hw, y=hw,
+                      stride=stride, pad=k // 2, groups=groups)
     sched = ScheduleCache().schedule_for(cv, precision=precision)
     plan = sched.plan.clamped(cv.nf, cv.c, cv.p)
     dtype, width = ((jnp.int8, 1) if precision == "int8"
@@ -143,6 +160,45 @@ def test_vgg16_kernel_compiles_for_v5e(one_chip, layer):
 @pytest.mark.parametrize("layer", sorted(OTHERS))
 def test_strided_and_depthwise_kernels_compile_for_v5e(one_chip, layer):
     _compile_launch(one_chip, *OTHERS[layer])
+
+
+@pytest.mark.parametrize("layer", sorted(MOBILENETV2_224))
+def test_mobilenetv2_224_kernels_compile_for_v5e(one_chip, layer):
+    _compile_launch(one_chip, *MOBILENETV2_224[layer],
+                    batch=MOBILENETV2_BUCKET)
+
+
+def test_mobilenetv2_224_forward_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole served forward at its bucket: one fold kernel per conv,
+    the 17 depthwise convs on the depthwise kernel (strides 1 and 2), and
+    its buffers within one chip's memory."""
+    import collections
+    import functools
+
+    from repro.core import engine
+    from repro.models.zoo import get_conv_model
+    monkeypatch.setattr(engine, "resolve_execution",
+                        lambda policy="auto": ("pallas", False))
+    spec = get_conv_model("mobilenetv2_imagenet")
+    shape = (MOBILENETV2_BUCKET, 3, 224, 224)
+    params = jax.eval_shape(functools.partial(
+        spec.init_params, width_mult=1.0, img=224, classes=1000),
+        jax.random.PRNGKey(0))
+    net = engine.compile_network(params, spec.to_graph(), shape,
+                                 policy="auto")
+    assert net.fold_dataflows.count("depthwise") == 17
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), (params,
+                                               jax.ShapeDtypeStruct(
+                                                   shape, jnp.float32)))
+    compiled = net.apply.lower(*args).compile()
+    names = collections.Counter(re.findall(
+        r"%(fold_[a-z]+_r\ds\d_st\d)\.\d+ = ", compiled.as_text()))
+    assert sum(names.values()) == 52
+    assert names["fold_dw_r3s3_st1"] + names["fold_dw_r3s3_st2"] == 17
+    assert names["fold_dw_r3s3_st2"] == 4
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 @pytest.mark.xfail(strict=True, reason=(
