@@ -832,8 +832,11 @@ class CompiledNetwork:
     precision: str = "fp32"  # streamed conv dtype ("fp32" | "int8")
     quant: Optional[Any] = None  # the QuantRecipe the int8 lowering baked in
     # per conv, the dataflow its fold kernel launches with (after the
-    # kernel's VMEM fallback); empty where no fold kernel runs
+    # kernel's VMEM fallback) and the output rows each of its tap dots
+    # covers (``FoldKernelSpec.rows_per_dot``); empty where no fold
+    # kernel runs
     fold_dataflows: Tuple[str, ...] = ()
+    fold_rows_per_dot: Tuple[int, ...] = ()
 
     def __call__(self, params: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
         return self.apply(params, x)
@@ -1098,6 +1101,7 @@ def compile_network(params: Dict[str, Any],
     shapes: Dict[str, Tuple[int, ...]] = {g.input: tuple(input_shape)}
     layer_schedules: List[Tuple[str, ConvSchedule]] = []
     fold_dataflows: List[str] = []
+    fold_rows_per_dot: List[int] = []
     plan_steps: List[Tuple] = []   # (op, out, in_names, static payload)
 
     def _need4d(nd, shape):
@@ -1187,8 +1191,9 @@ def compile_network(params: Dict[str, Any],
                     kernel_epi = requant_epilogue(epi)
                 if verify:
                     _verify_schedule(nd.name, cv, sched, kernel_epi, groups)
-                fold_dataflows.append(
-                    _launch_spec(cv, sched, kernel_epi, groups).dataflow)
+                launch = _launch_spec(cv, sched, kernel_epi, groups)
+                fold_dataflows.append(launch.dataflow)
+                fold_rows_per_dot.append(launch.rows_per_dot)
             layer_schedules.append((nd.name, sched))
             po, qo = epilogue_out_hw(nd.epilogue, cv.p, cv.q)
             shapes[nd.name] = (n_, nf, po, qo)
@@ -1312,7 +1317,8 @@ def compile_network(params: Dict[str, Any],
                            mode=mode, interpret=interpret,
                            fused=fused, autotuned=autotune, graph=g,
                            precision=precision, quant=quant,
-                           fold_dataflows=tuple(fold_dataflows))
+                           fold_dataflows=tuple(fold_dataflows),
+                           fold_rows_per_dot=tuple(fold_rows_per_dot))
 
 
 # --------------------------------------------------------------------------

@@ -52,6 +52,18 @@ multiplied against the stationary tap column and accumulated — the MXU
 plays the PE array (filters x channels lanes), the VPU shift plays the
 stride right-shift.
 
+**Row packing.**  A tap's dot costs about the same for every 128-lane
+tile of its window, however few of the lanes are used, so a stride-1
+WS/OS launch with narrow rows contracts each tap against ``rows_per_dot``
+(k) consecutive output rows at once.  The kernel lays a row group's k +
+R - 1 padded input rows end to end on the lane axis, ``wph`` lanes
+apart, so every tap's window over the group's k output rows is one run
+of lanes at a static offset; the ``wph - q`` lanes of each row that fall
+on the padding are computed and dropped.  The launch shape alone
+sets k (``_rows_per_dot``); at 1 — strided launches, rows too wide to
+save a lane tile, 1x1 launches of one 128-deep pass, the psum baseline,
+depthwise — one dot covers one row.
+
 Inputs are NCHW, weights OIHW (matching the paper's tensors).  Caller
 pre-pads spatially (``ops.py``).
 """
@@ -69,7 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.epilogue import Epilogue, epilogue_out_hw
 from repro.core.loopnest import ConvLoopNest
 from repro.core.mapping import (WS_ACC_BYTES_LIMIT, ConvBlockPlan,
-                                plan_conv_blocks, tiled_bytes,
+                                _round_up, plan_conv_blocks, tiled_bytes,
                                 vmem_request_bytes)
 
 __all__ = ["conv2d_folded", "default_plan", "DATAFLOWS",
@@ -80,6 +92,38 @@ DATAFLOWS = ("weight_stationary", "output_stationary", "depthwise")
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
+# Elements of one row group's largest value — a tap's (channels, lanes)
+# window, or the (filters, lanes) partial sum the flush rolls — in a
+# packed launch: 1024 lanes at 64 channels, 128 at 512.  Past it the
+# kernel's values outgrow the VMEM the launch requests, and the flush
+# rolls more lanes than the packing saves.
+DOT_WINDOW = 512 * 128
+
+_LANES = 128                            # lanes of one MXU / vreg tile
+
+
+def _rows_per_dot(dataflow: str, stride: int, r: int, s: int, c_b: int,
+                  nf_b: int, p_block: int, wph: int, q: int,
+                  pooled: bool) -> int:
+    """Output rows per dot (k) of a launch: the most rows whose ``k *
+    wph`` lanes (``wph``, the padded row width), in 128-lane tiles over the larger of ``c_b`` channels
+    and ``nf_b`` filters, fit ``DOT_WINDOW``; that divide the P fold (so
+    one loop body walks its row groups); and — under a fused 2x2 pool —
+    even, so pool pairs never straddle a group.  1, one dot per row, for
+    the psum baseline, depthwise and strided launches; where a row's taps
+    make a single 128-deep MXU pass (a 1x1 conv over at most 128
+    channels), whose dot costs less than a packed row's flush; and where
+    packing saves no 128-lane tile of MXU work (rows as wide as VGG's 112
+    and 224 px)."""
+    if (dataflow not in ("weight_stationary", "output_stationary")
+            or stride != 1 or r * s * -(-c_b // _LANES) < 2):
+        return 1
+    lanes = DOT_WINDOW // _round_up(max(c_b, nf_b), 8) // _LANES * _LANES
+    k = next((k for k in range(min(lanes // wph, p_block), 1, -1)
+              if p_block % k == 0 and not (pooled and k % 2)), 1)
+    tiles = (p_block // k) * -(-k * wph // _LANES)
+    return k if tiles < p_block * -(-q // _LANES) else 1
+
 
 def _tap_lane(si: int, stride: int, wph: int) -> int:
     """First lane of filter column ``si``'s window in the stride-phase
@@ -89,28 +133,55 @@ def _tap_lane(si: int, stride: int, wph: int) -> int:
     return (si % stride) * wph + si // stride
 
 
+def _tap_dot(tap, win, acc_dtype):
+    """One tap's (nf_b, c_b) tile against its (c_b, lanes) window, in
+    ``acc_dtype`` — fp32 for the fp32 path (contracted at
+    ``Precision.HIGHEST``: full fp32 products, fp32 accumulation), int32
+    for int8 streams (the MXU contracts the int8 operands directly; the
+    int32 depth-fold accumulation is exact, see ``core/quant.py``)."""
+    if acc_dtype == jnp.float32:
+        return jnp.dot(tap.astype(jnp.float32), win.astype(jnp.float32),
+                       preferred_element_type=acc_dtype, precision=_HIGHEST)
+    return jnp.dot(tap, win, preferred_element_type=acc_dtype)
+
+
 def _row_taps(x_ref, w_ref, xrow, *, r: int, s: int, stride: int, wph: int,
               q: int, acc_dtype):
     """One output row of one fold interaction (Fig 4): the R*S stationary
     taps, each an (nf_b, c_b) filter-fold column, against the (c_b, q)
     image-row window under it.  ``xrow`` is the first input row.  Returns
-    (nf_b, q) in ``acc_dtype`` — fp32 for the fp32 path (contracted at
-    ``Precision.HIGHEST``: full fp32 products, fp32 accumulation), int32
-    for int8 streams (the MXU contracts the int8 operands directly; the
-    int32 depth-fold accumulation is exact, see ``core/quant.py``)."""
-    fp32 = acc_dtype == jnp.float32
+    (nf_b, q) in ``acc_dtype`` (``_tap_dot``)."""
     acc = None
     for ri in range(r):
         line = x_ref[0, :, xrow + ri, :]             # (c_b, stride * wph)
         for si in range(s):
             lane0 = _tap_lane(si, stride, wph)
             win = line[:, lane0:lane0 + q]                      # (c_b, q)
-            tap = w_ref[ri * s + si]                            # (nf_b, c_b)
-            if fp32:
-                tap = tap.astype(jnp.float32)
-                win = win.astype(jnp.float32)
-            part = jnp.dot(tap, win, preferred_element_type=acc_dtype,
-                           precision=_HIGHEST if fp32 else None)
+            part = _tap_dot(w_ref[ri * s + si], win, acc_dtype)
+            acc = part if acc is None else acc + part
+    return acc
+
+
+def _group_taps(x_ref, w_ref, xrow, *, r: int, s: int, wph: int,
+                rows: int, lanes: int, acc_dtype):
+    """``rows`` (k) consecutive output rows of a stride-1 fold in one dot
+    per tap.  For tap row ri the input rows ``xrow`` + ri .. + ri + k - 1
+    are laid end to end on the lane axis, so output row u, column c of
+    tap (ri, si) reads lane u * wph + si + c: the window under a tap is
+    one static slice of ``lanes`` lanes (k * wph in whole 128-lane
+    tiles; the lanes past the rows, zero-filled, reach only columns that
+    are never flushed).  Returns (nf_b, lanes) in ``acc_dtype``; the taps
+    accumulate in the order of ``_row_taps``."""
+    acc = None
+    for ri in range(r):
+        line = [x_ref[0, :, xrow + ri + u, :] for u in range(rows)]
+        tail = lanes - rows * wph + s - 1
+        if tail:
+            line.append(jnp.zeros((line[0].shape[0], tail), line[0].dtype))
+        line = jnp.concatenate(line, axis=1)
+        for si in range(s):
+            part = _tap_dot(w_ref[ri * s + si], line[:, si:si + lanes],
+                            acc_dtype)
             acc = part if acc is None else acc + part
     return acc
 
@@ -171,42 +242,76 @@ def _flush_rows(rows, k, b_ref, res_ref, out_ref, epi: Epilogue, *,
 
 
 def _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref, *, i_c, i_p,
-               n_c: int, acc_row0, res_row0, out_row0, r: int, s: int,
-               stride: int, wph: int, p_block: int, q: int, epi: Epilogue,
-               acc_dtype):
+               n_c: int, resident, r: int, s: int, stride: int, wph: int,
+               p_block: int, q: int, epi: Epilogue, acc_dtype,
+               rows_per_dot: int):
     """The shared fold body of the WS and OS kernels: walk the P fold's
-    output rows (in pool pairs when the pool is fused), accumulate each
-    row's depth-fold partial into ``acc_ref`` (first depth fold writes,
-    later ones add), and flush the epilogue on the last depth fold.  The
-    dataflows differ only in where the accumulator rows live
-    (``acc_row0``: full-height WS scratch vs the OS block scratch) and in
-    the grid order that decides which operand stays resident."""
+    output rows, accumulate their depth-fold partials into ``acc_ref``
+    (first depth fold writes, later ones add), and flush the epilogue row
+    by row on the last depth fold.  At ``rows_per_dot`` 1 a loop takes
+    one row (a pool pair when the pool is fused) per step and ``acc_ref``
+    holds (nf_b, rows, q); above it a loop takes one row group, a dot per
+    tap (``_group_taps``), and ``acc_ref`` holds the groups packed as
+    they leave the MXU, (groups, nf_b, lanes), each row rolled down to
+    lane 0 for its flush.  The dataflows differ only in ``resident``, the
+    P fold's index into the accumulator, residual and output blocks (the
+    WS kernel's are full-height, so ``i_p``; the OS kernel's are one fold,
+    so 0), and in the grid order that decides which operand stays
+    resident."""
     group = 2 if epi.pool == "max2" else 1   # p_block is even when pooled
+    row0 = resident * p_block
 
-    def body(k, carry):
-        rows = []
-        for u in range(group):
-            j = k * group + u                           # row in the fold
-            part = _row_taps(x_ref, w_ref, (i_p * p_block + j) * stride,
-                             r=r, s=s, stride=stride, wph=wph, q=q,
-                             acc_dtype=acc_dtype)
-            acc = jnp.where(i_c == 0, part,
-                            acc_ref[:, acc_row0 + j, :] + part)
-            acc_ref[:, acc_row0 + j, :] = acc
-            rows.append((j, acc))
+    def flush(rows, g):
+        _flush_rows(rows, g, b_ref, res_ref, out_ref, epi, res_row0=row0,
+                    out_row0=resident * (p_block // group))
+
+    if rows_per_dot == 1:
+        def body(k, carry):
+            rows = []
+            for u in range(group):
+                j = k * group + u                       # row in the fold
+                part = _row_taps(x_ref, w_ref, (i_p * p_block + j) * stride,
+                                 r=r, s=s, stride=stride, wph=wph, q=q,
+                                 acc_dtype=acc_dtype)
+                acc = jnp.where(i_c == 0, part,
+                                acc_ref[:, row0 + j, :] + part)
+                acc_ref[:, row0 + j, :] = acc
+                rows.append((j, acc))
+            pl.when(i_c == n_c - 1)(lambda: flush(rows, k))
+            return carry
+
+        jax.lax.fori_loop(0, p_block // group, body, 0)
+        return
+
+    k, n_groups = rows_per_dot, p_block // rows_per_dot
+    lanes = acc_ref.shape[-1]
+
+    def group_body(t, carry):
+        part = _group_taps(x_ref, w_ref, i_p * p_block + t * k, r=r, s=s,
+                           wph=wph, rows=k, lanes=lanes,
+                           acc_dtype=acc_dtype)
+        ga = resident * n_groups + t
+        acc_ref[ga] = jnp.where(i_c == 0, part, acc_ref[ga] + part)
 
         @pl.when(i_c == n_c - 1)
         def _flush():
-            _flush_rows(rows, k, b_ref, res_ref, out_ref, epi,
-                        res_row0=res_row0, out_row0=out_row0)
+            def row_body(v, c):
+                # the v-th row (pool pair) of the group, moved to lane 0
+                top = pltpu.roll(acc_ref[ga], jax.lax.rem(
+                    lanes - v * (group * wph), lanes), 1)
+                j = t * k + v * group
+                flush([(j + u, top[:, u * wph:u * wph + q])
+                       for u in range(group)], t * (k // group) + v)
+                return c
+
+            jax.lax.fori_loop(0, k // group, row_body, 0)
         return carry
 
-    jax.lax.fori_loop(0, p_block // group, body, 0)
+    jax.lax.fori_loop(0, n_groups, group_body, 0)
 
 
-def _ws_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
-               wph: int, p_block: int, q: int, n_c: int, epi: Epilogue,
-               acc_dtype=jnp.float32):
+def _ws_kernel(x_ref, w_ref, b_ref, *refs, n_c: int, epi: Epilogue,
+               acc_dtype=jnp.float32, **geometry):
     """Weight-stationary with in-kernel depth reduction.
 
     Grid: (N, nf, c, p); p fastest.  ``acc_ref`` holds the full output
@@ -222,18 +327,13 @@ def _ws_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
     res_ref, (out_ref, acc_ref) = (refs[0] if epi.residual else None,
                                    refs[-2:])
     i_p = pl.program_id(3)
-    row0 = i_p * p_block
     _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref,
-               i_c=pl.program_id(2), i_p=i_p, n_c=n_c, acc_row0=row0,
-               res_row0=row0,
-               out_row0=i_p * (p_block // 2) if epi.pool == "max2" else row0,
-               r=r, s=s, stride=stride, wph=wph, p_block=p_block, q=q,
-               epi=epi, acc_dtype=acc_dtype)
+               i_c=pl.program_id(2), i_p=i_p, n_c=n_c, resident=i_p,
+               epi=epi, acc_dtype=acc_dtype, **geometry)
 
 
-def _os_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
-               wph: int, p_block: int, q: int, n_c: int, epi: Epilogue,
-               acc_dtype=jnp.float32):
+def _os_kernel(x_ref, w_ref, b_ref, *refs, n_c: int, epi: Epilogue,
+               acc_dtype=jnp.float32, **geometry):
     """Output-stationary variant. Grid: (N, nf, p, c); c fastest — the
     block-sized accumulator and the output block stay resident across the
     depth sweep."""
@@ -241,8 +341,7 @@ def _os_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
                                    refs[-2:])
     _fold_rows(x_ref, w_ref, b_ref, res_ref, out_ref, acc_ref,
                i_c=pl.program_id(3), i_p=pl.program_id(2), n_c=n_c,
-               acc_row0=0, res_row0=0, out_row0=0, r=r, s=s, stride=stride,
-               wph=wph, p_block=p_block, q=q, epi=epi, acc_dtype=acc_dtype)
+               resident=0, epi=epi, acc_dtype=acc_dtype, **geometry)
 
 
 def _dw_kernel(x_ref, w_ref, b_ref, *refs, r: int, s: int, stride: int,
@@ -436,10 +535,12 @@ class FoldKernelSpec:
 
     Operand layouts (what the kernel binds, prepared by ``conv2d_folded``):
     x is NCHW with its width split into ``stride`` phases of ``wph`` lanes
-    (``_phase_split``; the identity at stride 1); w is tap-major
-    (R*S, N_F, C/G) — (R*S, C, 1) for depthwise — so each tap is one 2-D
-    (filters x channels) tile.  ``scratch`` is the accumulator's shape
-    (None when the kernel keeps none).
+    (``_phase_split``; the identity at stride 1); w is tap-major (R*S, N_F, C/G) — (R*S, C, 1) for depthwise — so each
+    tap is one 2-D (filters x channels) tile.  ``scratch`` is the
+    accumulator's shape (None when the kernel keeps none): per row,
+    (nf_b, rows, q), or — where ``rows_per_dot`` (k) > 1 — per row group
+    as the dots leave the MXU, (groups, nf_b, lanes), k * wph rounded
+    up to 128-lane tiles.
     """
     dataflow: str                       # resolved (post-fallback)
     requested: str                      # dataflow as requested by caller
@@ -470,6 +571,7 @@ class FoldKernelSpec:
     p_block: int                        # post pool-even bump
     p_valid: int
     q_valid: int
+    rows_per_dot: int                   # output rows per tap dot (k)
 
     def vmem_bytes(self, stream_bytes: int = 4) -> int:
         """VMEM the launch really holds: every operand block twice (the
@@ -539,11 +641,12 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
     q_o = q // 2 if pooled else q
     rs = r * s
 
-    def common(**kw):
+    def common(rows_per_dot=1, **kw):
         return FoldKernelSpec(
             requested=requested, epilogue=epi, plan=plan, groups=groups,
             nf=nf, c=c, p=p, q=q, r=r, s=s, stride=stride, wph=wph,
-            p_block=p_b, p_valid=p_valid, q_valid=q_valid, **kw)
+            p_block=p_b, p_valid=p_valid, q_valid=q_valid,
+            rows_per_dot=rows_per_dot, **kw)
 
     if dataflow == "depthwise":
         c_pad, p_pad = g_c * c_b, g_p * p_b
@@ -600,6 +703,9 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
                     else "output_stationary")
 
     ws_like = dataflow in ("weight_stationary", "weight_stationary_psum")
+    k = _rows_per_dot(dataflow, stride, r, s, c_b, nf_b, p_b, wph, q,
+                      pooled)
+    lanes = _round_up(k * wph, _LANES)      # a row group's dot width
     ix_x, ix_w, ix_vec = ((_ix_ws_x, _ix_ws_w, _ix_ws_vec) if ws_like
                           else (_ix_os_x, _ix_os_w, _ix_os_vec))
     inputs = [
@@ -610,7 +716,7 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
                     ix_w),
     ]
     folds = dict(nfg_folds=g_nfg, cg_folds=g_c, nf_pad=nf_pad, c_pad=c_pad,
-                 p_pad=p_pad, x_rows=x_rows)
+                 p_pad=p_pad, x_rows=x_rows, rows_per_dot=k)
 
     if dataflow == "weight_stationary_psum":
         # out: one partial-sum fold per depth fold (paper Fig 5, staged in
@@ -638,7 +744,8 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
             dataflow="weight_stationary", grid=(n, g_nf, g_c, g_p),
             grid_axes=("n", "nf", "c", "p"), reduction_axis=2,
             inner_sliced_axes=(3,), inputs=tuple(inputs), output=out,
-            scratch=(nf_b, p_pad, q), **folds)
+            scratch=((p_pad // k, nf_b, lanes) if k > 1
+                     else (nf_b, p_pad, q)), **folds)
 
     # output_stationary
     p_b_o = p_b // 2 if pooled else p_b
@@ -651,7 +758,8 @@ def fold_kernel_spec(x_shape: Tuple[int, int, int, int],
         dataflow="output_stationary", grid=(n, g_nf, g_p, g_c),
         grid_axes=("n", "nf", "p", "c"), reduction_axis=3,
         inner_sliced_axes=(), inputs=tuple(inputs), output=out,
-        scratch=(nf_b, p_b, q), **folds)
+        scratch=(p_b // k, nf_b, lanes) if k > 1 else (nf_b, p_b, q),
+        **folds)
 
 
 _DATAFLOW_TAGS = {"weight_stationary": "ws", "output_stationary": "os",
@@ -660,10 +768,13 @@ _DATAFLOW_TAGS = {"weight_stationary": "ws", "output_stationary": "os",
 
 def kernel_name(spec: FoldKernelSpec) -> str:
     """The launch's stable name, from its resolved dataflow and window
-    geometry (``fold_ws_r3s3_st1``): the Mosaic custom call's
+    geometry (``fold_ws_r3s3_st1``), with ``_k<rows_per_dot>`` where rows
+    are packed (``fold_os_r3s3_st1_k4``): the Mosaic custom call's
     ``kernel_name``, by which a profiler trace finds the kernel."""
-    return (f"fold_{_DATAFLOW_TAGS[spec.dataflow]}_r{spec.r}s{spec.s}"
+    name = (f"fold_{_DATAFLOW_TAGS[spec.dataflow]}_r{spec.r}s{spec.s}"
             f"_st{spec.stride}")
+    return name + (f"_k{spec.rows_per_dot}" if spec.rows_per_dot > 1
+                   else "")
 
 
 def _pad_to(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
@@ -803,7 +914,8 @@ def conv2d_folded(x_padded: jnp.ndarray, w: jnp.ndarray, *,
         body = (_ws_kernel if spec.dataflow == "weight_stationary"
                 else _os_kernel)
         kern = functools.partial(body, n_c=spec.cg_folds, epi=epi,
-                                 acc_dtype=acc_dtype, **kw)
+                                 acc_dtype=acc_dtype,
+                                 rows_per_dot=spec.rows_per_dot, **kw)
     scratch = ([] if spec.scratch is None
                else [pltpu.VMEM(spec.scratch, acc_dtype)])
     stream_bytes = jnp.dtype(x_padded.dtype).itemsize

@@ -56,9 +56,10 @@ def boot_report(workers) -> dict:
     """What the in-process workers run on: the JAX device platform,
     ``device_kind`` and count, the execution mode the engines resolved
     (``core/engine.py:resolve_execution``), and the fold kernel launches
-    of one forward by dataflow, read off the first worker's compiled
-    forwards (empty before a bucket is compiled, or where the convs do
-    not run on the fold kernels)."""
+    of one forward by dataflow and by output rows per tap dot
+    (``fold_rows_per_dot``, 1 where rows are not packed), read off the
+    first worker's compiled forwards (empty before a bucket is compiled,
+    or where the convs do not run on the fold kernels)."""
     import collections
 
     import jax
@@ -67,13 +68,15 @@ def boot_report(workers) -> dict:
     devices = jax.devices()
     compiler = workers[0].worker.engine.compiler
     mode, interpret = resolve_execution(compiler.policy)
-    launches = collections.Counter(
-        compiler.network_for(compiler.buckets[0]).fold_dataflows
-        if compiler.buckets else ())
+    net = (compiler.network_for(compiler.buckets[0]) if compiler.buckets
+           else None)
+    launches = collections.Counter(net.fold_dataflows if net else ())
+    packed = collections.Counter(net.fold_rows_per_dot if net else ())
     return {"platform": devices[0].platform,
             "device_kind": devices[0].device_kind,
             "device_count": len(devices), "mode": mode,
-            "interpret": interpret, "fold_launches": dict(launches)}
+            "interpret": interpret, "fold_launches": dict(launches),
+            "fold_rows_per_dot": dict(sorted(packed.items()))}
 
 
 def build_workers(model: str, n: int, *, img: int = 32,

@@ -357,8 +357,9 @@ def test_published_engine_forward_matches_reference_walk():
 
 def test_boot_report_counts_fold_launches_by_dataflow():
     """The boot report reads the compiled forward's launches: 17 of the
-    52 convs run on the depthwise kernel; none where the convs run as
-    XLA convolutions."""
+    52 convs run on the depthwise kernel, and all 52 take one output row
+    per tap dot at this width; none where the convs run as XLA
+    convolutions."""
     import types
 
     from repro.launch.server import boot_report
@@ -384,7 +385,11 @@ def test_boot_report_counts_fold_launches_by_dataflow():
     assert sum(launches.values()) == 52
     assert set(launches) <= {"weight_stationary", "output_stationary",
                              "depthwise"}
-    assert report("reference")[1]["fold_launches"] == {}
+    # launches by output rows per tap dot: at this width every launch is
+    # depthwise, strided or a 1x1 over at most 128 channels, one row a dot
+    assert rep["fold_rows_per_dot"] == {1: 52}
+    ref = report("reference")[1]
+    assert ref["fold_launches"] == ref["fold_rows_per_dot"] == {}
 
 
 # --------------------------------------------------------------------------

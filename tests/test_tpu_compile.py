@@ -62,6 +62,21 @@ OTHERS = {
                           Epilogue(scale=True, relu6=True)),
 }
 
+_RESIDUAL = Epilogue(bias=True, relu=True, residual=True)
+
+# (input channels, filters, input height = width, stride, groups,
+# epilogue) of ResNet-18's (CIFAR, 32 px) stride-1 3x3 launches of stages
+# 2-4 at its served bucket of 256, whose output rows pack k to a dot
+RESNET18_BUCKET = 256
+RESNET18_PACKED = {
+    "s2_c1": (128, 128, 16, 1, 1, _RELU),
+    "s2_c2": (128, 128, 16, 1, 1, _RESIDUAL),
+    "s3_c1": (256, 256, 8, 1, 1, _RELU),
+    "s3_c2": (256, 256, 8, 1, 1, _RESIDUAL),
+    "s4_c1": (512, 512, 4, 1, 1, _RELU),
+    "s4_c2": (512, 512, 4, 1, 1, _RESIDUAL),
+}
+
 _BN_RELU6 = Epilogue(scale=True, relu6=True)
 _BN = Epilogue(scale=True)
 
@@ -131,6 +146,8 @@ def _compile_launch(one_chip, c, nf, hw, stride, groups, epi, k=3,
         vecs["bias"] = shape((cv.nf,))
     if epi.scale:
         vecs["scale"] = vecs["shift"] = shape((cv.nf,))
+    if epi.residual:
+        vecs["residual"] = shape((cv.n, cv.nf, cv.p, cv.q))
 
     def launch(x, w, vecs):
         return conv2d_folded(x, w, stride=stride, plan=plan,
@@ -150,11 +167,25 @@ def _compile_launch(one_chip, c, nf, hw, stride, groups, epi, k=3,
     blocks = conv_working_set(cv, plan.nf_block, plan.c_block, plan.p_block,
                               width, dataflow=sched.dataflow, epilogue=epi)
     assert _granted_vmem(hlo) == [vmem_request_bytes(blocks)]
+    return spec
 
 
 @pytest.mark.parametrize("layer", sorted(VGG16))
 def test_vgg16_kernel_compiles_for_v5e(one_chip, layer):
-    _compile_launch(one_chip, *VGG16[layer])
+    spec = _compile_launch(one_chip, *VGG16[layer])
+    # stages 3-5 (56-14 px) pack rows; 112 and 224 px rows would save no
+    # 128-lane tile
+    assert (spec.rows_per_dot > 1) == (VGG16[layer][2] <= 56)
+
+
+@pytest.mark.parametrize("layer", sorted(RESNET18_PACKED))
+def test_resnet18_packed_kernels_compile_for_v5e(one_chip, layer):
+    """Packed rows lower: every tap dot of these launches covers k output
+    rows of 4-16 lanes, laid end to end in the kernel."""
+    spec = _compile_launch(one_chip, *RESNET18_PACKED[layer],
+                           batch=RESNET18_BUCKET)
+    assert spec.rows_per_dot > 1
+    assert kernel_name(spec).endswith(f"_k{spec.rows_per_dot}")
 
 
 @pytest.mark.parametrize("layer", sorted(OTHERS))
@@ -168,10 +199,10 @@ def test_mobilenetv2_224_kernels_compile_for_v5e(one_chip, layer):
                     batch=MOBILENETV2_BUCKET)
 
 
-def test_mobilenetv2_224_forward_compiles_for_v5e(one_chip, monkeypatch):
-    """The whole served forward at its bucket: one fold kernel per conv,
-    the 17 depthwise convs on the depthwise kernel (strides 1 and 2), and
-    its buffers within one chip's memory."""
+def _compile_forward(one_chip, monkeypatch, model, img, classes, bucket):
+    """Compile one served forward at its bucket for the described chip:
+    (the compiled network, its fold kernel launches by name, the
+    compiled executable)."""
     import collections
     import functools
 
@@ -179,26 +210,59 @@ def test_mobilenetv2_224_forward_compiles_for_v5e(one_chip, monkeypatch):
     from repro.models.zoo import get_conv_model
     monkeypatch.setattr(engine, "resolve_execution",
                         lambda policy="auto": ("pallas", False))
-    spec = get_conv_model("mobilenetv2_imagenet")
-    shape = (MOBILENETV2_BUCKET, 3, 224, 224)
+    spec = get_conv_model(model)
+    shape = (bucket, 3, img, img)
     params = jax.eval_shape(functools.partial(
-        spec.init_params, width_mult=1.0, img=224, classes=1000),
+        spec.init_params, width_mult=1.0, img=img, classes=classes),
         jax.random.PRNGKey(0))
     net = engine.compile_network(params, spec.to_graph(), shape,
                                  policy="auto")
-    assert net.fold_dataflows.count("depthwise") == 17
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), (params,
                                                jax.ShapeDtypeStruct(
                                                    shape, jnp.float32)))
     compiled = net.apply.lower(*args).compile()
     names = collections.Counter(re.findall(
-        r"%(fold_[a-z]+_r\ds\d_st\d)\.\d+ = ", compiled.as_text()))
+        r"%(fold_[a-z]+_r\ds\d_st\d(?:_k\d+)?)\.\d+ = ", compiled.as_text()))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    return net, names
+
+
+def _packed(names):
+    return sum(n for name, n in names.items() if "_k" in name)
+
+
+def test_mobilenetv2_224_forward_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole served forward at its bucket: one fold kernel per conv,
+    the 17 depthwise convs on the depthwise kernel (strides 1 and 2), and
+    its buffers within one chip's memory."""
+    net, names = _compile_forward(one_chip, monkeypatch,
+                                  "mobilenetv2_imagenet", 224, 1000,
+                                  MOBILENETV2_BUCKET)
+    assert net.fold_dataflows.count("depthwise") == 17
     assert sum(names.values()) == 52
     assert names["fold_dw_r3s3_st1"] + names["fold_dw_r3s3_st2"] == 17
     assert names["fold_dw_r3s3_st2"] == 4
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # the stride-1 1x1s over more than 128 channels pack rows
+    assert _packed(names) == sum(k > 1 for k in net.fold_rows_per_dot) == 19
+
+
+@pytest.mark.parametrize("model,img,classes,bucket,convs,packed", [
+    ("vgg16", 224, 1000, 32, 13, 9),
+    ("resnet18", 32, 10, 256, 20, 14),
+])
+def test_served_forward_packs_rows_on_v5e(one_chip, monkeypatch, model, img,
+                                          classes, bucket, convs, packed):
+    """VGG-16 and ResNet-18 compile whole at their served buckets, one
+    fold kernel per conv: every stride-1 3x3 launch at 56 px and below
+    packs output rows (VGG's stages 3-5; ResNet's stem and stages 1-4),
+    within the VMEM each kernel asks for."""
+    net, names = _compile_forward(one_chip, monkeypatch, model, img,
+                                  classes, bucket)
+    assert sum(names.values()) == len(net.fold_rows_per_dot) == convs
+    assert _packed(names) == sum(k > 1 for k in net.fold_rows_per_dot) \
+        == packed
 
 
 @pytest.mark.xfail(strict=True, reason=(
