@@ -1,18 +1,8 @@
 """Fig 9: layer-wise VGG-16 utilization and clock cycles per array size,
-plus the engine's measured end-to-end path.
-
-Measured sections: per-image forward latency of the cached fold-schedule
-engine (``vgg.compile_forward``) vs the seed path that re-planned every
-``conv2d`` call with a hard-coded dataflow and always ran the Pallas
-kernels under ``interpret=True`` off-TPU; fused in-kernel epilogues vs
-separate XLA ops (plus the bytes-moved model for the fusion); and the
-PR-2 engine (in-kernel reduction, fused, measured-autotuned schedules) vs
-a faithful PR-1 engine (psum-staging WS, unfused, heuristic).  The
-schedule-cache hit rate is reported as the paper's fold-reuse metric, and
-``bench_summary()`` snapshots all of it for CI (``BENCH_vgg.json``).
+from the analytical model (eq 10-12), plus the schedule cache's fold-reuse
+metric over the full-size 13-layer walk.  Times nothing: the served
+path is measured on the chip by ``chipbench/run.py``.
 """
-import time
-
 from repro.core.engine import ScheduleCache
 from repro.core.folds import PEArray, decompose
 from repro.core.loopnest import vgg16_conv_layers
@@ -41,378 +31,6 @@ def fold_reuse_metric() -> dict:
     return d
 
 
-def _time_forward(fn, params, x, reps: int = 5):
-    """(first-call seconds, best steady-state seconds)."""
-    t0 = time.perf_counter()
-    fn(params, x).block_until_ready()
-    first = time.perf_counter() - t0
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn(params, x).block_until_ready()
-        best = min(best, time.perf_counter() - t0)
-    return first, best
-
-
-def measured(width: float = 0.125, img: int = 48, batch: int = 2):
-    """Engine-compiled forward vs the per-call-planning seed path.
-
-    Sized so the comparison is structural rather than timer noise: at
-    width 0.125 / 48px the seed path's per-call planning + hard-coded
-    interpreted fold_os runs ~2x slower per image than the engine's
-    policy-selected path on CPU (on TPU both run compiled Pallas and the
-    win is schedule reuse at trace time).
-    """
-    import jax
-    from repro.models import vgg
-
-    params = vgg.init_params(jax.random.PRNGKey(0), width_mult=width,
-                             img=img, classes=10)
-    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 3, img, img))
-
-    # seed path: plans inside every conv2d call, hard-coded fold_os
-    # dataflow, Pallas interpret off-TPU
-    seed = jax.jit(lambda p, xx: vgg.forward(p, xx, impl="fold_os"))
-    seed_first, seed_step = _time_forward(seed, params, x)
-
-    # engine: whole-network static schedule, cost-selected dataflows,
-    # interpret policy picks the fastest correct path for this backend
-    net = vgg.compile_forward(params, img=img, batch=batch, policy="auto")
-    eng_first, eng_step = _time_forward(net.apply, params, x)
-
-    per_img_seed = seed_step / batch
-    per_img_eng = eng_step / batch
-    print(f"measured,width={width},img={img},batch={batch},"
-          f"backend={jax.default_backend()}")
-    print(f"seed_per_call_planning,first_s={seed_first:.3f},"
-          f"per_image_s={per_img_seed:.4f}")
-    print(f"engine_compiled,first_s={eng_first:.3f},"
-          f"per_image_s={per_img_eng:.4f},mode={net.mode}")
-    print(f"# engine speedup vs seed path: {per_img_seed / per_img_eng:.1f}x "
-          f"per image (improved: {per_img_eng < per_img_seed})")
-    reuse = net.fold_reuse()
-    print(f"fold_reuse,conv_layers={reuse['conv_layers']},"
-          f"distinct_schedules={reuse['distinct_schedules']},"
-          f"hits={reuse['hits']},hit_rate={reuse['hit_rate']}")
-    return per_img_seed / per_img_eng
-
-
-def _time_pair(fa, fb, params, x, reps: int = 13):
-    """Interleaved best-of-``reps`` for two forwards (drift-robust: both
-    see the same background-load profile)."""
-    fa(params, x).block_until_ready()
-    fb(params, x).block_until_ready()
-    ta = tb = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fa(params, x).block_until_ready()
-        ta = min(ta, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fb(params, x).block_until_ready()
-        tb = min(tb, time.perf_counter() - t0)
-    return ta, tb
-
-
-def _pr1_engine(params, sched_by_name, interpret: bool):
-    """The PR-1 engine, faithfully: heuristic cost-model schedules, psum-
-    staging weight-stationary kernels, and separate XLA bias/ReLU/pool."""
-    import jax
-    from repro.core.epilogue import maxpool2x2
-    from repro.kernels.ops import conv2d
-    from repro.models import vgg
-    from repro.models.vgg import vgg_head
-
-    def forward(p, xx):
-        for entry in vgg.VGG_LAYERS:
-            if entry == "M":
-                xx = maxpool2x2(xx)
-                continue
-            name = entry[0]
-            s = sched_by_name[name]
-            impl = ("fold_ws_psum" if s.dataflow == "weight_stationary"
-                    else "fold_os")
-            y = conv2d(xx, p[name]["w"], stride=1, pad=1, impl=impl,
-                       plan=s.plan, interpret=interpret)
-            xx = jax.nn.relu(y + p[name]["b"][None, :, None, None])
-        return vgg_head(p, xx)
-
-    return jax.jit(forward)
-
-
-def measured_fused(width: float = 0.25, img: int = 32, batch: int = 2
-                   ) -> dict:
-    """Fused in-kernel epilogues vs separate XLA ops, same schedules.
-
-    The unfused net launches one ``pallas_call`` per conv plus separate
-    XLA bias/ReLU/pool ops; the fused net flushes the whole
-    conv→bias→ReLU(→pool) chain inside the conv kernel — 13 kernel
-    launches for VGG-16's entire trunk, and the pre-activation tensor
-    never reaches HBM.  On CPU interpret mode this is roughly latency-
-    neutral (XLA epilogues are dispatch-cheap there); the bytes-moved
-    model quantifies the HBM traffic the fusion removes on a real
-    accelerator.
-    """
-    import jax
-    from benchmarks.kernel_bench import epilogue_traffic
-    from repro.core.engine import ScheduleCache
-    from repro.models import vgg
-
-    params = vgg.init_params(jax.random.PRNGKey(0), width_mult=width,
-                             img=img, classes=10)
-    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 3, img, img))
-    cache = ScheduleCache()
-    unfused = vgg.compile_forward(params, img=img, batch=batch,
-                                  policy="pallas", fuse_epilogues=False,
-                                  cache=cache)
-    fused = vgg.compile_forward(params, img=img, batch=batch,
-                                policy="pallas", cache=cache)
-    t_un, t_fu = _time_pair(unfused.apply, fused.apply, params, x)
-
-    pooled_layers = {"conv1_2", "conv2_2", "conv3_3", "conv4_3", "conv5_3"}
-    b_un = b_fu = 0
-    for name, cv in vgg16_conv_layers():       # full-size traffic model
-        tm = epilogue_traffic(cv, pooled=name in pooled_layers)
-        b_un += tm["unfused"]
-        b_fu += tm["fused"]
-    out = {"unfused_per_img_s": t_un / batch,
-           "fused_per_img_s": t_fu / batch,
-           "speedup": t_un / t_fu,
-           "model_epilogue_bytes_unfused": b_un,
-           "model_epilogue_bytes_fused": b_fu}
-    print(f"fused_vs_unfused,width={width},img={img},"
-          f"unfused_per_image_s={out['unfused_per_img_s']:.4f},"
-          f"fused_per_image_s={out['fused_per_img_s']:.4f},"
-          f"speedup={out['speedup']:.2f}x")
-    print(f"# full-size VGG-16 post-conv HBM bytes (model): "
-          f"{b_un/1e6:.0f}MB unfused -> {b_fu/1e6:.0f}MB fused "
-          f"({b_un/b_fu:.1f}x less epilogue traffic)")
-    return out
-
-
-def measured_tuned(width: float = 0.25, img: int = 32, batch: int = 2
-                   ) -> dict:
-    """The PR-2 engine vs the PR-1 engine, and tuned vs heuristic.
-
-    PR-1 baseline: heuristic (cost-model) schedules, psum-staging WS
-    kernels, separate XLA epilogues.  PR-2: measured autotuned schedules
-    (pay-once, JSON-cached), in-kernel depth reduction, fused epilogues.
-    The autotuner ranks candidates strictly by measured median, so the
-    tuned engine can only lose to the heuristic one through end-to-end
-    effects smaller than timer noise.
-    """
-    import os
-    import tempfile
-
-    import jax
-    from repro.core.engine import ScheduleCache
-    from repro.models import vgg
-
-    params = vgg.init_params(jax.random.PRNGKey(0), width_mult=width,
-                             img=img, classes=10)
-    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 3, img, img))
-
-    heur = vgg.compile_forward(params, img=img, batch=batch,
-                               policy="pallas", fuse_epilogues=False)
-    pr1 = _pr1_engine(params, dict(heur.layer_schedules), heur.interpret)
-    heur_fused = vgg.compile_forward(params, img=img, batch=batch,
-                                     policy="pallas")
-
-    path = os.path.join(tempfile.mkdtemp(prefix="repro_tune_"), "vgg.json")
-    t0 = time.perf_counter()
-    tuned = vgg.compile_forward(params, img=img, batch=batch,
-                                policy="pallas", autotune=True,
-                                tuning_path=path, cache=ScheduleCache(),
-                                autotune_reps=5)
-    tune_cost = time.perf_counter() - t0
-    # the engine's own discipline, applied end to end: race the tuned
-    # schedules against the heuristic ones and serve the measured-faster
-    # net (per-kernel tuning can mis-rank under machine-load noise)
-    t_ht, t_tt = _time_pair(heur_fused.apply, tuned.apply, params, x,
-                            reps=7)
-    engine, engine_kind = ((tuned, "tuned") if t_tt <= t_ht
-                           else (heur_fused, "heuristic"))
-    t_pr1, t_t = _time_pair(pr1, engine.apply, params, x, reps=17)
-    switched = sum(1 for (_, a), (_, b) in zip(heur.layer_schedules,
-                                               tuned.layer_schedules)
-                   if (a.dataflow, a.plan) != (b.dataflow, b.plan))
-    out = {"pr1_per_img_s": t_pr1 / batch,
-           "engine_per_img_s": t_t / batch,
-           "speedup": t_pr1 / t_t, "tuning_cost_s": tune_cost,
-           "engine_schedules": engine_kind,
-           "layers_switched": switched, "tuning_json": path}
-    print(f"engine_vs_pr1,width={width},img={img},"
-          f"pr1_per_image_s={out['pr1_per_img_s']:.4f},"
-          f"engine_per_image_s={out['engine_per_img_s']:.4f},"
-          f"speedup={out['speedup']:.2f}x,improved={out['speedup'] > 1.0},"
-          f"engine_schedules={engine_kind},layers_switched={switched},"
-          f"tuning_cost_s={tune_cost:.1f} (pay-once, cached at "
-          f"{os.path.basename(path)})")
-    return out
-
-
-def count_pallas_calls(net, params, img: int, batch: int = 1) -> int:
-    """Fused kernel launches in the compiled forward's jaxpr — the number
-    CI's perf gate (``benchmarks/check_bench.py``) pins exactly: a fusion
-    regression (a bias/BN/ReLU/pool/add escaping its conv's kernel)
-    changes this count before it changes any latency."""
-    import jax
-    import jax.numpy as jnp
-    x0 = jnp.zeros((batch, 3, img, img))
-    fn = getattr(net, "apply", net)
-    return str(jax.make_jaxpr(fn)(params, x0)).count("pallas_call")
-
-
-def model_micro(model: str, width: float = 0.0625, img: int = 32,
-                batch: int = 2, classes: int = 10) -> dict:
-    """Per-model micro-bench through the streaming-graph lowering: any
-    registered model (``models/zoo.py``) compiles via ``compile_network``
-    and reports auto/fused/unfused per-image latency plus its fold-reuse
-    metric and fused pallas_call count — the per-model section of the
-    bench JSON."""
-    import jax
-    from repro.core.engine import compile_network
-    from repro.models.zoo import get_conv_model
-
-    spec = get_conv_model(model)
-    params = spec.init_params(jax.random.PRNGKey(0), width_mult=width,
-                              img=img, classes=classes)
-    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 3, img, img))
-
-    def compiled(policy, fuse=True, cache=None, jit=True):
-        return compile_network(params, spec.to_graph(),
-                               (batch, 3, img, img), policy=policy,
-                               fuse_epilogues=fuse, cache=cache, jit=jit)
-
-    auto_net = compiled("auto")
-    _, t_auto = _time_forward(auto_net.apply, params, x)
-    # the fused net compiles against a fresh cache so its build stats ARE
-    # the model's fold-reuse metric (a pre-warmed cache would report a
-    # meaningless 100% hit rate); the unfused net then shares that cache
-    fused = compiled("pallas")
-    unfused = compiled("pallas", fuse=False, cache=fused.cache)
-    _, t_un = _time_forward(unfused.apply, params, x)
-    _, t_fu = _time_forward(fused.apply, params, x)
-    out = {
-        "workload": {"model": model, "width_mult": width, "img": img,
-                     "batch": batch, "backend": jax.default_backend()},
-        "latency": {
-            "auto_per_img_s": round(t_auto / batch, 6),
-            "pallas_unfused_per_img_s": round(t_un / batch, 6),
-            "pallas_fused_per_img_s": round(t_fu / batch, 6),
-            "fused_speedup": round(t_un / t_fu, 3),
-        },
-        "fold_reuse": fused.fold_reuse(),
-        "pallas_calls": count_pallas_calls(
-            compiled("pallas", cache=fused.cache, jit=False), params, img),
-    }
-    fr = out["fold_reuse"]
-    print(f"{model}_micro,width={width},img={img},"
-          f"fused_per_image_s={out['latency']['pallas_fused_per_img_s']},"
-          f"fused_speedup={out['latency']['fused_speedup']}x,"
-          f"pallas_calls={out['pallas_calls']},"
-          f"schedules={fr['distinct_schedules']}/{fr['conv_layers']},"
-          f"hit_rate={fr['hit_rate']}")
-    return out
-
-
-def bench_summary(width: float = 0.0625, img: int = 32, batch: int = 2
-                  ) -> dict:
-    """Machine-readable micro-bench for CI perf tracking (BENCH_vgg.json):
-    the generic ``model_micro`` sections for vgg16 plus the full-size
-    VGG-16 bytes-moved model (PR-1 psum WS vs in-kernel WS).
-
-    Interpreter-mode sized: the numbers track the *trajectory* of the
-    engine hot path per PR, not absolute hardware performance.
-    """
-    from benchmarks.kernel_bench import dataflow_traffic
-
-    out = model_micro("vgg16", width=width, img=img, batch=batch)
-    bytes_psum = bytes_ws = bytes_os = 0
-    by_precision = {"fp32": 0, "int8": 0}
-    for _, cv in vgg16_conv_layers():
-        tm = dataflow_traffic(cv)
-        bytes_psum += tm["weight_stationary_psum"]
-        bytes_ws += tm["weight_stationary"]
-        bytes_os += tm["output_stationary"]
-        for prec in by_precision:
-            by_precision[prec] += dataflow_traffic(
-                cv, precision=prec)["weight_stationary"]
-    out["bytes_moved_model_fullsize"] = {
-        "ws_psum_pr1": bytes_psum,
-        "ws_inkernel": bytes_ws,
-        "os": bytes_os,
-        # > 1 by construction: the psum formulation stages every depth
-        # fold's partial in HBM (write + read back) where the in-kernel
-        # reduction keeps it in VMEM — even at g_c == 1 the final output
-        # makes one extra round trip
-        "ws_psum_over_inkernel": round(bytes_psum / bytes_ws, 3),
-        # the same 13-layer walk priced at each streamed dtype (weights
-        # and activations at 1 byte for int8; outputs at fp32 width)
-        "ws_inkernel_by_precision": dict(by_precision),
-    }
-    return out
-
-
-def _stream_bytes(net) -> float:
-    """Modeled weight + activation HBM stream bytes for one compiled
-    network, at each schedule's streamed dtype (outputs excluded — they
-    leave the kernel at fp32 in both precisions)."""
-    from repro.core.engine import traffic_components
-    total = 0.0
-    for _, s in net.layer_schedules:
-        comp = traffic_components(s.nest, s.plan, s.dataflow,
-                                  precision=s.key.precision)
-        total += comp["weights"] + comp["input"]
-    return total
-
-
-def quantization_summary(width: float = 0.0625, img: int = 32,
-                         batch: int = 2, classes: int = 10) -> dict:
-    """The per-model int8 section of the bench JSON: fused pallas_call
-    count and distinct schedules of the int8 lowering (structural, gated
-    exactly), the modeled weight+activation stream-byte reduction vs the
-    fp32 lowering of the same net, and the accuracy-vs-speed numbers
-    from ``benchmarks/accuracy.py``."""
-    import jax
-    from benchmarks.accuracy import accuracy_summary
-    from repro.core.engine import compile_network
-    from repro.models.zoo import get_conv_model
-
-    out = {}
-    for model in ("vgg16", "resnet18", "mobilenetv2"):
-        spec = get_conv_model(model)
-        params = spec.init_params(jax.random.PRNGKey(0), width_mult=width,
-                                  img=img, classes=classes)
-
-        def compiled(precision):
-            return compile_network(params, spec.to_graph(),
-                                   (batch, 3, img, img), policy="pallas",
-                                   jit=False, precision=precision)
-
-        net_fp, net_q = compiled("fp32"), compiled("int8")
-        b_fp, b_q = _stream_bytes(net_fp), _stream_bytes(net_q)
-        acc = accuracy_summary(model, width_mult=width, img=img)
-        out[model] = {
-            "pallas_calls": count_pallas_calls(net_q, params, img, batch),
-            "conv_layers": len(net_q.layer_schedules),
-            "distinct_schedules": net_q.distinct_schedules,
-            "stream_bytes_fp32": b_fp,
-            "stream_bytes_int8": b_q,
-            "stream_bytes_ratio": round(b_fp / b_q, 3),
-            "top1_agreement": acc["top1_agreement"],
-            "rel_logit_err": acc["rel_logit_err"],
-            "fp32_per_img_s": acc["fp32_per_img_s"],
-            "int8_per_img_s": acc["int8_per_img_s"],
-        }
-        q = out[model]
-        print(f"quantization,{model},pallas_calls={q['pallas_calls']},"
-              f"schedules={q['distinct_schedules']}/{q['conv_layers']},"
-              f"stream_bytes_ratio={q['stream_bytes_ratio']}x,"
-              f"top1_agreement={q['top1_agreement']},"
-              f"rel_logit_err={q['rel_logit_err']}")
-    return out
-
-
 def main(csv=False):
     print("# Fig 9 — VGG-16 layer-wise utilization (a) and cycles (b)")
     hdr = ("layer", "util_16", "util_32", "util_64",
@@ -428,12 +46,6 @@ def main(csv=False):
     print(f"# fold reuse (full-size): {fr['distinct_schedules']} schedules "
           f"for 13 layers, {fr['hits']} cache hits "
           f"(hit_rate={fr['hit_rate']})")
-    measured()
-    measured_fused()
-    measured_tuned()
-    model_micro("resnet18")      # the other registered models — the same
-    model_micro("mobilenetv2")   # lowering covers dense, residual, grouped
-    quantization_summary()       # int8 streaming vs the fp32 oracle
     return u64_min
 
 

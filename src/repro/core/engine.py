@@ -170,9 +170,9 @@ def traffic_components(cv: ConvLoopNest, plan: ConvBlockPlan, dataflow: str,
                        precision: str = "fp32") -> Dict[str, float]:
     """Per-tensor-class HBM byte split for one dataflow formulation —
     weights and input at the *streamed* dtype, output at the accumulate/
-    write dtype.  ``dataflow_traffic_bytes`` sums these; benchmarks
-    report them so per-dtype totals are visible (the int8 win is on the
-    weight/input streams only)."""
+    write dtype.  ``dataflow_traffic_bytes`` sums these; kept apart so
+    per-dtype totals are visible (the int8 win is on the weight/input
+    streams only)."""
     bpe = bytes_per_elem
     sbpe = stream_bytes_per_elem(precision, bytes_per_elem)
     sizes = cv.tensor_sizes()
@@ -213,7 +213,7 @@ def dataflow_traffic_bytes(cv: ConvLoopNest, plan: ConvBlockPlan,
                            bytes_per_elem: int = 4,
                            precision: str = "fp32") -> Dict[str, float]:
     """Modeled HBM bytes per dataflow formulation — the single source of
-    truth shared by ``dataflow_costs`` and ``benchmarks/kernel_bench``.
+    truth ``dataflow_costs`` prices schedules with.
 
     ``weight_stationary_psum`` is the PR-1 staging formulation; the
     in-kernel ``weight_stationary`` entry prices the psum fallback the
@@ -263,25 +263,24 @@ def dataflow_costs(cv: ConvLoopNest, plan: ConvBlockPlan,
     bandwidth and clock; the shared compute term is MACs spread over the
     tile's PEs.  Purely geometric — deterministic for a given nest.
 
-    Calibration (PR 2, methodology — ``benchmarks/kernel_bench.calibrate``):
-    measured on this container's CPU backend with the Pallas kernels under
-    ``interpret=True`` (the roadmap's real-TPU validation is still open),
-    median-of-5 after one warmup, per-kernel over three small geometries
-    with g_c forced > 1.  Findings: single-kernel interpret-mode wall time
-    is dispatch-dominated, not bandwidth-dominated — the model's psum
-    ratio (1.7-2.2x extra WS traffic for the PR-1 formulation) showed up
-    as measured ratios of only 0.5-1.1x, because XLA's host-side psum
+    Calibration: measured on a CPU backend with the Pallas kernels
+    under ``interpret=True``, median-of-5 after one warmup, per-kernel
+    over three small geometries with g_c forced > 1.  Findings:
+    single-kernel interpret-mode wall time is dispatch-dominated, not
+    bandwidth-dominated — the model's psum ratio (1.7-2.2x extra WS
+    traffic for the psum-staging formulation) showed up as measured ratios of
+    only 0.5-1.1x, because XLA's host-side psum
     reduce is nearly free on CPU while the in-kernel reduction pays per-
     grid-step ``pl.when`` overhead.  At the *network* level the fused
-    in-kernel path is what wins on this backend (benchmarks/fig9: ~1.2x
-    per image, fused vs unfused pallas engine).  Consequently the absolute
+    in-kernel path won on that backend (~1.2x per image, fused vs
+    unfused pallas engine).  Consequently the absolute
     ``offchip_gbps``/``freq_ghz`` constants are kept at the paper's §V.A
     values — they model the target accelerator, not this CI host — and
     this function's ranking is treated as the *no-tuning default only*:
     ``autotune_schedule`` below replaces it with real measurements
     (pay-once, JSON-persisted) whenever trusting the model is not good
-    enough.  Re-run ``calibrate()`` on a real TPU before trusting absolute
-    cycle counts.
+    enough.  Calibrate on the chip before trusting absolute cycle
+    counts.
     """
     cfg = cfg or MavecConfig()
     traffic = dataflow_traffic_bytes(cv, plan, cfg.bytes_per_elem, precision)

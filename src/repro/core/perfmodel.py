@@ -8,7 +8,8 @@ Reproduces, from fold geometry alone:
   * system throughput (KIPS)           eqs (13)-(15)
 
 Validated against the paper's own numbers in ``tests/test_perfmodel.py`` and
-``benchmarks/``: Table 3 fold counts, the 75% -> >92% utilization step, the
+printed by ``benchmarks/{table3_folds,fig9_vgg,kips}.py``: Table 3 fold
+counts, the 75% -> >92% utilization step, the
 ~78 GFLOP/s (16x16) -> ~1.56 TFLOP/s (64x64) throughput span and the
 12.7 KIPS VGG-16 system figure.
 

@@ -125,7 +125,7 @@ def solve_elastic_mesh(available_devices: int, model_parallel: int,
 class PreemptionGuard:
     """SIGTERM/SIGINT -> request a clean stop at the next step boundary.
 
-    Training drains to a checkpoint; serving (``launch/serve.py --vision``)
+    Training drains to a checkpoint; serving (``launch/server.py``)
     stops admitting, flushes in-flight batches, and still emits metrics.
     Usable as a context manager: ``with PreemptionGuard() as guard: ...``
     installs on entry and always restores the original handlers on exit.

@@ -30,9 +30,9 @@ chain is one ``pallas_call`` and the pre-activation tensor never leaves
 VMEM.
 
 ``weight_stationary_psum`` keeps the original PR-1 formulation — each
-depth fold emits a partial-sum fold to HBM, reduced afterwards with XLA —
-as a benchmarking baseline only (``benchmarks/kernel_bench.py`` reports
-the bytes-moved delta); the engine never selects it.
+depth fold emits a partial-sum fold to HBM, reduced afterwards with XLA;
+the engine never selects it, and a weight-stationary launch falls back to
+it only when its accumulator outgrows VMEM under an identity epilogue.
 
 **Grouped convolution** (``groups > 1``) reuses both dataflows unchanged:
 the block plan solves the fold geometry *within one group* (``nf_block``

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
-Every entry point that compiles the served model (``launch/server.py``,
-``launch/serve.py``, ``chip_smoke.py``) calls ``enable_compile_cache``
+Every entry point that compiles a served model (``launch/server.py``,
+``launch/serve.py``, ``chipbench/``) calls ``enable_compile_cache``
 before its first compile:
 
 * with ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it — nothing

@@ -2,11 +2,15 @@
 front-end (``serve/transport.py``) and the SLO-aware router
 (``serve/router.py``).
 
-    # 2 in-process replicas of the reduced-width vgg16, interpret backend
-    python -m repro.launch.server --workers 2 --backend interpret
+    # vgg16 at its published shape on one chip
+    python -m repro.launch.server --model vgg16 --img 224 --width 1.0 \
+        --classes 1000 --buckets 8,32
+
+    # 2 in-process replicas of the reduced-width vgg16
+    python -m repro.launch.server --workers 2
 
     # multi-host-shaped: each worker its own subprocess + engine
-    python -m repro.launch.server --workers 2 --spawn --backend interpret
+    python -m repro.launch.server --workers 2 --spawn
 
     curl -s localhost:8080/healthz
     curl -s -XPOST localhost:8080/v1/infer -d '{"images": [[[[...]]]]}'
@@ -34,7 +38,9 @@ import threading
 import time
 from typing import List, Optional, Sequence
 
-from repro.launch.serve import VISION_POLICIES
+# --backend choice -> core/engine.py execution policy
+VISION_POLICIES = {"auto": "auto", "interpret": "pallas",
+                   "reference": "reference"}
 
 __all__ = ["ServerHandle", "start_server", "build_workers", "boot_report",
            "visible_tpu_chips", "main"]

@@ -13,14 +13,8 @@ observable:
   stage (submit/admit/form/dispatch/kernel/epilogue/degrade/complete)
   plus per compiled-layer spans, recorded through an injectable clock
   with deterministic span IDs and exported as Chrome trace-event JSON
-  (loadable in Perfetto).
-* ``obs.folds``    — per-schedule streaming counters: measured dispatch
-  timings joined with the analytical model (utilization, bytes moved,
-  achieved-vs-model throughput) per ``ScheduleKey`` — the paper's Fig 9
-  and Table 3 as running counters.
-* ``obs.report``   — the CLI (``python -m repro.obs.report``): the live
-  per-layer table for any zoo model, plus trace/metrics artifact schema
-  validation for CI.
+  (loadable in Perfetto); ``validate_trace`` checks its schema and
+  ``check_trace_outcomes`` the zero-loss invariant.
 
 Everything defaults to a no-op recorder (``trace.NULL_TRACER``) so the
 instrumented hot paths cost one attribute check when observability is

@@ -19,9 +19,9 @@ Design constraints, in order:
 * **Two exports, one source.**  ``to_prometheus()`` emits the text
   exposition format (histograms as cumulative ``_bucket{le=...}`` series
   over the *occupied* buckets plus ``+Inf``); ``snapshot()`` emits a
-  plain-JSON dict that ``launch/serve.py --metrics-json`` writes and
-  ``merge_bench_json`` can merge.  ``validate_metrics_snapshot`` is the
-  schema check CI's observability smoke runs against the artifact.
+  plain-JSON dict that ``launch/server.py --metrics-json`` writes and
+  ``/metrics.json`` serves.  ``validate_metrics_snapshot`` is its schema
+  check.
 
 Everything here is numpy + plain Python — no jax, no device.
 """
@@ -372,8 +372,8 @@ def _fmt(v: float) -> str:
 
 def validate_metrics_snapshot(snap) -> List[str]:
     """Every schema problem in a ``snapshot()``-shaped object (empty
-    list = valid).  CI's observability smoke runs this against the
-    ``--metrics-json`` artifact."""
+    list = valid): the ``--metrics-json`` artifact and the
+    ``/metrics.json`` scrape."""
     problems: List[str] = []
     if not isinstance(snap, dict):
         return [f"snapshot must be a JSON object, got "

@@ -59,7 +59,7 @@ import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "SpanHandle",
-           "validate_trace", "span_tree"]
+           "validate_trace", "check_trace_outcomes", "span_tree"]
 
 # Well-known track ids: one per pipeline stage, requests above REQ_TID0.
 TID_ENGINE = 0        # engine control: idle/submit/admit/form/stage
@@ -359,4 +359,37 @@ def validate_trace(trace) -> List[str]:
         if parent is not None and parent not in seen_ids:
             problems.append(f"event[{i}]: parent_id {parent} does not "
                             "match any span_id")
+    return problems
+
+
+TERMINAL_OUTCOMES = ("ok", "rejected", "expired", "failed")
+
+
+def check_trace_outcomes(trace: dict, expect_requests: int) -> List[str]:
+    """The zero-loss invariant, read off the trace: every submitted
+    request's lifetime span (``cat == "request"``) ends with exactly one
+    terminal outcome in its args."""
+    problems: List[str] = []
+    seen = {}
+    for ev in trace.get("traceEvents", []):
+        if not isinstance(ev, dict) or ev.get("cat") != "request":
+            continue
+        if ev.get("ph") != "X":
+            continue
+        args = ev.get("args", {})
+        rid = args.get("request_id")
+        outcome = args.get("outcome")
+        if rid is None:
+            problems.append(f"request span {ev.get('name')!r} has no "
+                            "request_id")
+            continue
+        if rid in seen:
+            problems.append(f"request {rid}: more than one lifetime span")
+        seen[rid] = outcome
+        if outcome not in TERMINAL_OUTCOMES:
+            problems.append(f"request {rid}: outcome {outcome!r} is not "
+                            f"one of {TERMINAL_OUTCOMES}")
+    if len(seen) != expect_requests:
+        problems.append(f"trace has {len(seen)} request lifetime spans, "
+                        f"expected {expect_requests}")
     return problems

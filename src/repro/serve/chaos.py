@@ -19,7 +19,7 @@ Schedules are pure data (``{dispatch_index: Fault}``) built
 deterministically from a seed by ``ChaosInjector.from_profile`` — the
 same ``(profile, seed)`` always injects the same faults at the same
 dispatch indices, so every recovery path is exercised reproducibly by
-tests and the CI chaos smoke.  Scheduled faults fire on the **primary**
+the tests.  Scheduled faults fire on the **primary**
 dispatch stream only; recovery dispatches (the reference fallback and
 quarantine bisection) see them never — otherwise a recovery could chase
 its own injected faults forever and determinism would depend on recovery
@@ -64,8 +64,8 @@ class Fault:
 
 PROFILES = ("kernel-fault", "nan", "slow-batch", "mixed")
 
-# what a chaos run under each profile must have exercised (checked by the
-# CI smoke): metric-name -> the robustness counter that must be nonzero
+# what a chaos run under each profile must have exercised (checked by
+# ``verify_chaos_run``): profile -> robustness counters that must be nonzero
 PROFILE_EXPECTATIONS: Dict[str, tuple] = {
     "kernel-fault": ("degraded_batches",),
     "nan": ("degraded_batches", "nonfinite_batches"),
@@ -183,24 +183,21 @@ class ChaosInjector:
 
 
 # --------------------------------------------------------------------------
-# The chaos smoke harness (CLI + CI entry point)
+# The chaos smoke (the tests drive it)
 # --------------------------------------------------------------------------
 
 class ChaosVerificationError(AssertionError):
     """The chaos run violated a recovery invariant; message lists all."""
 
 
-def _direct_logits(engine, images: np.ndarray, policy: str) -> np.ndarray:
-    """Oracle forward: ``compile_network`` at the request's own size (no
-    padding, no batching), sharing the engine's schedule cache."""
-    import jax.numpy as jnp
+def _direct_forward(engine, n: int, policy: str) -> Callable:
+    """Oracle forward: ``compile_network`` at a request's own size ``n``
+    (no padding, no batching), sharing the engine's schedule cache."""
     from repro.core.engine import compile_network
-    net = compile_network(
+    return compile_network(
         engine.params, engine.compiler.graph,
-        (images.shape[0], engine.batcher.chan, engine.batcher.img,
-         engine.batcher.img),
+        (n, engine.batcher.chan, engine.batcher.img, engine.batcher.img),
         policy=policy, cache=engine.compiler.cache)
-    return np.asarray(net(engine.params, jnp.asarray(images)))
 
 
 def verify_chaos_run(engine, requests: List, inputs: List[np.ndarray], *,
@@ -217,6 +214,7 @@ def verify_chaos_run(engine, requests: List, inputs: List[np.ndarray], *,
     * with shedding configured, at least one request was shed or expired.
     """
     problems: List[str] = []
+    oracles: Dict[tuple, Callable] = {}    # (n, policy) -> direct forward
     for req, images in zip(requests, inputs):
         if not req.outcome.terminal:
             problems.append(f"request {req.rid} never reached a terminal "
@@ -226,7 +224,10 @@ def verify_chaos_run(engine, requests: List, inputs: List[np.ndarray], *,
             continue
         oracle_policy = (engine.compiler.policy
                          if req.served_by == "primary" else "reference")
-        want = _direct_logits(engine, images, oracle_policy)
+        key = (images.shape[0], oracle_policy)
+        if key not in oracles:
+            oracles[key] = _direct_forward(engine, key[0], oracle_policy)
+        want = np.asarray(oracles[key](engine.params, images))
         if not np.array_equal(req.logits, want):
             problems.append(
                 f"request {req.rid} ({req.served_by}) logits differ from "
@@ -250,8 +251,7 @@ def chaos_summary(model: str, *, profile: str, seed: int,
                   policy: str = "pallas", buckets=(1, 2, 4, 8),
                   deadline_s: float = 0.001, deadline_every: int = 3,
                   hang_timeout_s: float = 0.15, slow_s: float = 0.4,
-                  period: int = 3, tracer=None, registry=None,
-                  verbose: bool = False) -> dict:
+                  period: int = 3) -> dict:
     """Run the deterministic chaos smoke: a mixed-size request stream with
     periodic deadlines, served under an injected fault schedule, then
     verified against every recovery invariant (``verify_chaos_run``).
@@ -277,8 +277,7 @@ def chaos_summary(model: str, *, profile: str, seed: int,
                                        period=period)
     engine = VisionEngine(params, spec.to_graph(), img=img, policy=policy,
                           buckets=buckets, chaos=chaos,
-                          hang_timeout_s=hang_timeout_s, tracer=tracer,
-                          registry=registry)
+                          hang_timeout_s=hang_timeout_s)
     engine.warmup()
     rng = np.random.default_rng(seed)
     max_n = engine.batcher.policy.max_width
@@ -299,19 +298,10 @@ def chaos_summary(model: str, *, profile: str, seed: int,
         raise ChaosVerificationError(
             f"chaos run ({model}, {profile}, seed {seed}) violated "
             f"{len(problems)} invariant(s):\n  " + "\n  ".join(problems))
-    if registry is not None:
-        engine.snapshot_registry(registry)
     d = engine.metrics_dict()
     d["chaos"] = chaos.describe()
     d["workload"] = {"model": model, "profile": profile, "seed": seed,
                      "requests": int(requests), "policy": policy,
                      "deadline_s": deadline_s,
                      "deadline_every": deadline_every}
-    if verbose:
-        rb = d["robustness"]
-        print(f"CHAOS_OK {model}/{profile}/seed={seed}: "
-              f"{rb['outcomes']} degraded={rb['degraded_batches']} "
-              f"shed={rb['shed']} expired={rb['expired']} "
-              f"hung={rb['hung_batches']} "
-              f"injected={d['chaos']['injected']}")
     return d

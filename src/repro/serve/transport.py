@@ -20,9 +20,8 @@ real clients as HTTP semantics:
 
 Every submitted request still reaches exactly one terminal outcome and
 every wire request receives exactly one response carrying it — the
-zero-loss invariant now holds across the transport, which is what the
-load generator (``benchmarks/run_async_requests.py``) and the CI
-``transport`` job assert.
+zero-loss invariant now holds across the transport, which is what
+``chipbench/loadgen.py`` and ``tests/test_transport.py`` assert.
 
 Threading model: jit dispatch and the batcher are synchronous, so each
 ``VisionEngine`` is owned by one dedicated ``EngineWorker`` thread; the
@@ -43,7 +42,7 @@ Endpoints:
 * ``GET /metrics``    — Prometheus text exposition of the shared
   ``MetricsRegistry`` (engines synced per scrape under a ``worker``
   label); ``GET /metrics.json`` is the JSON snapshot
-  ``obs.report --validate-metrics`` checks.
+  ``obs.metrics.validate_metrics_snapshot`` checks.
 * ``GET /stats``      — router dispatch state + per-worker engine
   metrics (the load generator reads ``lost_requests`` here).
 

@@ -31,15 +31,15 @@ steps:
   straggling dispatches, and an optional deterministic fault injector
   (``serve/chaos.py``).  The static fold schedules are never touched —
   all dynamism lives in this host runtime;
-* serving metrics — measured KIPS, p50/p95/p99 request latency, slot
-  occupancy, schedule-cache / fold-reuse hit rates, plus the robustness
-  counters (shed / expired / failed / degraded / hung / deadline hit
-  rate) — snapshot into the bench JSON via ``benchmarks/run.py`` and
-  ``launch/serve.py --vision``.
+* serving metrics — images per second, p50/p95/p99 request latency,
+  slot occupancy, schedule-cache / fold-reuse hit rates, plus the
+  robustness counters (shed / expired / failed / degraded / hung /
+  deadline hit rate) — read through ``metrics_dict`` and
+  ``snapshot_registry`` (the served route's ``/stats`` and ``/metrics``).
 
 The engine is model-agnostic: it serves any ``StreamGraph`` registered in
-``models/zoo.py`` (``serving_summary`` looks models up by name), and the
-per-conv fold schedules come from the shared graph lowering.
+``models/zoo.py``, and the per-conv fold schedules come from the shared
+graph lowering.
 """
 from __future__ import annotations
 
@@ -54,7 +54,6 @@ import numpy as np
 
 from repro.core.engine import BucketCompiler, ScheduleCache
 from repro.core.mapping import serving_conv_plan
-from repro.obs.folds import FoldStreamCounters
 from repro.obs.metrics import LogHistogram, MetricsRegistry
 from repro.obs.trace import (NULL_TRACER, REQ_TID0, TID_COMPLETE,
                              TID_DISPATCH, TID_ENGINE)
@@ -63,8 +62,7 @@ from repro.serve.admission import (AdmissionController, DispatchWatchdog,
 from repro.serve.batcher import (BucketPolicy, FormedBatch, ImageBatcher,
                                  ImageRequest)
 
-__all__ = ["InflightBatch", "ServingMetrics", "VisionEngine",
-           "serving_summary"]
+__all__ = ["InflightBatch", "ServingMetrics", "VisionEngine"]
 
 
 def _latency_hist() -> LogHistogram:
@@ -117,12 +115,6 @@ class ServingMetrics:
     outcomes: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
-    def kips(self) -> float:
-        """Measured kilo-images-per-second — the paper's eq (13) unit,
-        here from wall clock rather than the cycle model."""
-        return self.images / self.elapsed_s / 1e3 if self.elapsed_s else 0.0
-
-    @property
     def slot_occupancy(self) -> float:
         return self.occupancy_hist.mean
 
@@ -134,9 +126,8 @@ class ServingMetrics:
                 if self.deadline_total else 1.0)
 
     def latency_percentiles(self) -> Dict[str, float]:
-        """Same keys and rounding as the original list-backed version
-        (the ``check_bench`` baselines compare these); percentiles now
-        come from the bounded histogram, the mean stays exact."""
+        """p50/p95/p99 and mean, in seconds: the percentiles come from
+        the bounded histogram, the mean stays exact."""
         h = self.latency_hist
         if not h.count:
             return {"p50_s": 0.0, "p95_s": 0.0, "p99_s": 0.0, "mean_s": 0.0}
@@ -152,7 +143,6 @@ class ServingMetrics:
             "batches": self.batches,
             "overlapped_batches": self.overlapped_batches,
             "elapsed_s": round(self.elapsed_s, 4),
-            "kips": round(self.kips, 6),
             "images_per_s": round(self.images / self.elapsed_s, 3)
                             if self.elapsed_s else 0.0,
             "latency": self.latency_percentiles(),
@@ -236,7 +226,7 @@ class VisionEngine:
                  chaos=None, hang_timeout_s: float = 30.0,
                  admission: Optional[AdmissionController] = None,
                  tracer=None, registry: Optional[MetricsRegistry] = None,
-                 fold_pe=None, precision: str = "fp32"):
+                 precision: str = "fp32"):
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.registry = registry
         bucket_policy = BucketPolicy(buckets)
@@ -278,10 +268,6 @@ class VisionEngine:
         self.watchdog = DispatchWatchdog(bucket_policy.widths,
                                          hang_timeout_s=hang_timeout_s)
         self._ref_compiler: Optional[BucketCompiler] = None
-        # per-ScheduleKey streaming counters (obs/folds.py).  Always on:
-        # the per-batch cost is O(conv layers) float ops, noise next to a
-        # forward; tracing alone stays behind the NULL_TRACER check.
-        self.folds = FoldStreamCounters(pe=fold_pe)
         self._req_spans: Dict[int, Any] = {}   # rid -> open lifetime span
         # dispatched, not yet completed, in dispatch order; one busy
         # period runs from the first of them formed to the last completed
@@ -567,10 +553,6 @@ class VisionEngine:
                 bucket=fb.bucket, n_images=fb.n_images,
                 cpu_start_s=cpu_start, cpu_end_s=cpu_done,
                 **({"error": repr(exc)} if exc is not None else {}))
-        if record and exc is None:
-            net = self.compiler.network_for(fb.bucket)
-            self.folds.observe_dispatch(
-                net.layer_schedules, fb.n_images, duration)
         if exc is None and not np.isfinite(logits[:fb.n_images]).all():
             if record:
                 m.nonfinite_batches += 1
@@ -756,9 +738,6 @@ class VisionEngine:
             - sum(len(f.fb.requests) for f in self._flight))
         if self.chaos is not None:
             d["robustness"]["chaos_injected"] = dict(self.chaos.injected)
-        # the live per-ScheduleKey table (obs/folds.py): model-side eq-10
-        # utilization + modeled bytes joined with measured dispatch time
-        d["observability"] = self.folds.as_dict()
         return d
 
     def snapshot_registry(self, registry: Optional[MetricsRegistry] = None,
@@ -803,7 +782,6 @@ class VisionEngine:
                             ("deadline_total", "Terminal with an SLO"),
                             ("deadline_hits", "SLO met")):
             c(f"serve_{name}_total", help_).set_total(getattr(m, name))
-        g("serve_kips", "Measured kilo-images per second").set(m.kips)
         g("serve_deadline_hit_rate", "SLO hit fraction"
           ).set(m.deadline_hit_rate)
         g("serve_pending_requests", "Still queued").set(self.pending)
@@ -822,91 +800,7 @@ class VisionEngine:
             for kind, n in sorted(self.chaos.injected.items()):
                 c("chaos_injected_total", "Faults fired by the injector",
                   kind=kind).set_total(n)
-        for row in self.folds.rows():
-            g("fold_util_model_pct", "eq-10 model PE utilization",
-              schedule=row["key"]).set(row["util_model_pct"])
-            g("fold_achieved_vs_model_pct",
-              "Measured GFLOP/s over eq-12 model GFLOP/s",
-              schedule=row["key"]).set(row["achieved_vs_model_pct"])
         c("admission_observations_total", "Batch service-time samples"
           ).set_total(self.admission.observations)
         return reg
 
-
-def serving_summary(model: str, *, requests: int = 32, img: int = 32,
-                    width_mult: float = 0.0625, classes: int = 10,
-                    policy: str = "auto", buckets: Sequence[int] = (1, 2, 4, 8),
-                    mesh=None, seed: int = 0, autotune: bool = False,
-                    tuning_path: Optional[str] = None,
-                    deadline_s: Optional[float] = None,
-                    deadline_every: int = 1,
-                    guard=None,
-                    tracer=None,
-                    registry: Optional[MetricsRegistry] = None,
-                    precision: str = "fp32",
-                    verbose: bool = False) -> dict:
-    """Serve a deterministic mixed-size random request stream through a
-    reduced-width registered model (``models/zoo.py``) and return the
-    metrics dict (the per-model serving section of the bench JSON).
-    Shared by ``launch/serve.py --vision`` and ``benchmarks/run.py``.
-
-    ``deadline_s`` attaches an SLO to every ``deadline_every``-th request.
-    ``guard`` is a ``ft/fault_tolerance.py:PreemptionGuard`` (or anything
-    with a ``requested`` attribute): once it trips, admission stops —
-    remaining requests are never submitted — while everything already
-    queued is flushed and the metrics still emit (the clean SIGTERM
-    drain)."""
-    from repro.models.zoo import get_conv_model
-    spec = get_conv_model(model)
-    params = spec.init_params(jax.random.PRNGKey(0), width_mult=width_mult,
-                              img=img, classes=classes)
-    engine = VisionEngine(params, spec.to_graph(), img=img, policy=policy,
-                          buckets=buckets, mesh=mesh, autotune=autotune,
-                          tuning_path=tuning_path, tracer=tracer,
-                          registry=registry, precision=precision)
-    engine.warmup()
-    rng = np.random.default_rng(seed)
-    max_n = engine.batcher.policy.max_width
-    sizes = rng.integers(1, max_n + 1, requests)
-    preempted = 0
-    for i, n in enumerate(sizes):
-        if guard is not None and getattr(guard, "requested", False):
-            preempted = len(sizes) - i      # stop admitting, keep draining
-            break
-        dl = (deadline_s if deadline_s is not None
-              and (deadline_every <= 1 or i % deadline_every == 0) else None)
-        engine.submit(rng.standard_normal((int(n), 3, img, img))
-                      .astype(np.float32), deadline_s=dl)
-    engine.run()                            # flush everything in flight
-    if registry is not None:
-        engine.snapshot_registry(registry)
-    d = engine.metrics_dict()
-    d["workload"] = {"model": model, "width_mult": width_mult, "img": img,
-                     "requests": int(requests), "policy": policy,
-                     "precision": precision,
-                     "seed": seed, "backend": jax.default_backend(),
-                     "deadline_s": deadline_s, "preempted": preempted}
-    if verbose:
-        lat = d["latency"]
-        print(f"served {d['requests']} requests / {d['images']} images in "
-              f"{d['elapsed_s']}s: {d['kips']} KIPS "
-              f"({d['images_per_s']} img/s)")
-        print(f"latency p50={lat['p50_s']}s p95={lat['p95_s']}s "
-              f"p99={lat['p99_s']}s; slot occupancy "
-              f"{d['slot_occupancy']}; batches/bucket "
-              f"{d['per_bucket_batches']}")
-        rb = d["robustness"]
-        print(f"robustness: outcomes {rb['outcomes']}, "
-              f"shed={rb['shed']} expired={rb['expired']} "
-              f"failed={rb['failed']} degraded={rb['degraded_batches']} "
-              f"deadline_hit_rate={rb['deadline_hit_rate']} "
-              f"lost={rb['lost_requests']}")
-        if preempted:
-            print(f"preemption drain: {preempted} request(s) never "
-                  "admitted; queue flushed cleanly")
-        c = d["compile"]
-        print(f"buckets compiled {c['buckets']}, "
-              f"{c['distinct_schedules']} distinct schedules, "
-              f"schedule-cache hit_rate={c['hit_rate']}")
-        print(engine.folds.table())
-    return d

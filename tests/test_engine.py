@@ -189,3 +189,40 @@ def test_fold_auto_impl_matches_reference():
     ref = np.asarray(conv2d(x, w, stride=1, pad=1, impl="direct"))
     out = np.asarray(conv2d(x, w, stride=1, pad=1, impl="fold_auto"))
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+
+
+# --------------------------------------------------------------------------
+# whole-zoo compile counts: fold reuse and one fused launch per conv
+# --------------------------------------------------------------------------
+
+# conv_layers, distinct_schedules, hits, misses, replans, pallas_calls of
+# a fresh-cache compile at width 0.0625 / 32 px, the same at either
+# precision: the precision is in every key, so neither adds a schedule
+ZOO_COMPILE_COUNTS = {
+    "vgg16": (13, 8, 5, 8, 0, 13),
+    "resnet18": (20, 11, 9, 11, 0, 20),
+    "mobilenetv2": (52, 27, 25, 27, 0, 52),
+}
+
+
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+@pytest.mark.parametrize("model", sorted(ZOO_COMPILE_COUNTS))
+def test_zoo_compile_counts(model, precision):
+    """The counts are exact: a fusion regression (an epilogue op escaping
+    its conv's kernel) or a planner change that splits or merges fold
+    geometries moves one of them before it moves any latency."""
+    from repro.analysis import audit_compiled
+    from repro.core.engine import compile_network
+    from repro.models.zoo import get_conv_model
+    spec = get_conv_model(model)
+    params = spec.init_params(jax.random.PRNGKey(0), width_mult=0.0625,
+                              img=32, classes=10)
+    shape = (2, 3, 32, 32)
+    net = compile_network(params, spec.to_graph(), shape, policy="pallas",
+                          jit=False, precision=precision)
+    assert all(s.key.precision == precision for _, s in net.layer_schedules)
+    reuse = net.fold_reuse()
+    got = (reuse["conv_layers"], reuse["distinct_schedules"], reuse["hits"],
+           reuse["misses"], reuse["replans"],
+           audit_compiled(net, params, shape).pallas_calls)
+    assert got == ZOO_COMPILE_COUNTS[model]

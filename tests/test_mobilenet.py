@@ -250,16 +250,6 @@ def test_serving_bitwise_equals_direct_forward(tiny_mnv2, policy):
             np.testing.assert_allclose(req.logits, want, rtol=1e-5)
 
 
-def test_serving_summary_mobilenetv2():
-    from repro.serve.vision import serving_summary
-    d = serving_summary("mobilenetv2", requests=5, img=IMG,
-                        width_mult=WIDTH, policy="auto", buckets=(1, 2, 4),
-                        seed=11)
-    assert d["workload"]["model"] == "mobilenetv2"
-    assert d["requests"] == 5 and d["images"] >= 5 and d["kips"] > 0
-    assert d["compile"]["distinct_schedules"] == 27
-
-
 @pytest.mark.parametrize("strides,stem_stride",
                          [(mobilenet.CIFAR, 1), (mobilenet.IMAGENET, 2)],
                          ids=["cifar", "imagenet"])
@@ -464,80 +454,3 @@ def test_tuning_json_forward_compat_unknown_fields(tmp_path):
     fresh = ScheduleCache()
     assert fresh.load_tuning(path) == 2
     assert fresh.schedule_for(dw).tuned
-
-
-def test_bench_gate_distills_and_compares(tmp_path):
-    """The CI perf gate: exact counters gate on any drift, latency gates
-    one-sided within tolerance."""
-    from benchmarks.check_bench import compare, extract
-    bench = {
-        "latency": {"auto_per_img_s": 0.01,
-                    "pallas_unfused_per_img_s": 0.02,
-                    "pallas_fused_per_img_s": 0.015},
-        "fold_reuse": {"hits": 5, "misses": 8, "replans": 0,
-                       "hit_rate": 0.38, "conv_layers": 13,
-                       "distinct_schedules": 8},
-        "pallas_calls": 13,
-        "mobilenetv2": {
-            "latency": {"pallas_fused_per_img_s": 0.03},
-            "fold_reuse": {"hits": 25, "misses": 27, "replans": 0,
-                           "conv_layers": 52, "distinct_schedules": 27},
-            "pallas_calls": 52,
-        },
-        "serving_by_model": {
-            "vgg16": {"kips": 1.0, "latency": {"p95_s": 0.05},
-                      "compile": {"distinct_schedules": 8}},
-        },
-    }
-    base = extract(bench)
-    assert base["exact"]["vgg16.pallas_calls"] == 13
-    assert base["exact"]["mobilenetv2.fold_reuse.conv_layers"] == 52
-    assert compare(extract(bench), base, tol=0.2) == []
-    # 10% slower: within budget; 30% slower: out of budget
-    ok = json.loads(json.dumps(bench))
-    ok["latency"]["pallas_fused_per_img_s"] = 0.0165
-    assert compare(extract(ok), base, tol=0.2) == []
-    slow = json.loads(json.dumps(bench))
-    slow["latency"]["pallas_fused_per_img_s"] = 0.0196
-    fails = compare(extract(slow), base, tol=0.2)
-    assert len(fails) == 1 and fails[0][0] == "latency"
-    # any pallas-call / fold-reuse drift fails regardless of tolerance
-    drift = json.loads(json.dumps(bench))
-    drift["mobilenetv2"]["pallas_calls"] = 53
-    drift["fold_reuse"]["hits"] = 6
-    kinds = {m for _, m, _ in compare(extract(drift), base, tol=10.0)}
-    assert "mobilenetv2.pallas_calls" in kinds
-    assert "vgg16.fold_reuse.hits" in kinds
-    # throughput drop beyond tolerance fails
-    slow_srv = json.loads(json.dumps(bench))
-    slow_srv["serving_by_model"]["vgg16"]["kips"] = 0.7
-    fails = compare(extract(slow_srv), base, tol=0.2)
-    assert [k for k, _, _ in fails] == ["throughput"]
-
-
-def test_bench_gate_validates_baseline_schema():
-    """A malformed baseline is refused up front with *every* defect
-    reported in one pass — not a KeyError on the first missing section."""
-    from benchmarks.check_bench import extract, validate_baseline
-    good = extract({"pallas_calls": 13,
-                    "latency": {"auto_per_img_s": 0.01}})
-    assert validate_baseline(good) == []
-    # several defects at once: all surface in a single validation run
-    bad = {"exact": {"vgg16.pallas_calls": 13.5,
-                     "vgg16.fold_reuse.hits": "five"},
-           "latency": {"serving.vgg16.p95_s": -0.1},
-           "extra_section": {}}
-    problems = validate_baseline(bad)
-    assert len(problems) == 9
-    text = "\n".join(problems)
-    assert "not an integral count" in text          # 13.5
-    assert "not a number" in text                   # "five"
-    assert "negative value" in text                 # -0.1
-    assert "missing section 'throughput'" in text
-    assert "missing section 'robustness'" in text
-    assert "missing section 'observability'" in text
-    assert "missing section 'quantization'" in text
-    assert "missing section 'transport'" in text
-    assert "unknown section 'extra_section'" in text
-    assert validate_baseline([1, 2]) \
-        == ["baseline must be a JSON object, got list"]

@@ -1,6 +1,6 @@
 """Streaming telemetry (DESIGN.md §11): bounded histograms and the
 metrics registry, the span tracer's determinism and Chrome trace-event
-schema, the per-schedule fold counters, and the serving integration —
+schema, and the serving integration —
 every submitted request visible in the trace with a terminal outcome.
 """
 import json
@@ -135,13 +135,13 @@ def test_registry_label_cardinality_cap():
 def test_prometheus_exposition_format():
     reg = MetricsRegistry()
     reg.counter("serve_requests_total", "Requests", outcome="ok").inc(7)
-    reg.gauge("serve_kips", "KIPS").set(0.5)
+    reg.gauge("serve_deadline_hit_rate", "SLO hit fraction").set(0.5)
     h = reg.histogram("serve_latency_seconds", "Latency")
     h.record_many([0.01, 0.02, 0.02, 5.0])
     text = reg.to_prometheus()
     assert '# TYPE serve_requests_total counter' in text
     assert 'serve_requests_total{outcome="ok"} 7' in text
-    assert '# TYPE serve_kips gauge' in text
+    assert '# TYPE serve_deadline_hit_rate gauge' in text
     # histogram: cumulative buckets, closed by +Inf == count, plus
     # _sum/_count — the format scrapers actually parse
     lines = [ln for ln in text.splitlines() if not ln.startswith("#")]
@@ -156,23 +156,15 @@ def test_prometheus_exposition_format():
     assert len(inf_lines) == 1 and inf_lines[0].endswith(" 4")
 
 
-def test_snapshot_schema_and_merge_bench_json(tmp_path):
-    from repro.launch.serve import merge_bench_json
+def test_snapshot_schema():
     reg = MetricsRegistry()
     reg.counter("a_total").inc(2)
     reg.gauge("b").set(1.5)
     reg.histogram("h_seconds").record_many([0.1, 0.2])
     snap = reg.snapshot()
     assert validate_metrics_snapshot(snap) == []
-    # the snapshot round-trips through JSON and merges into the bench
-    # file the perf tooling reads, without disturbing other sections
-    path = tmp_path / "BENCH.json"
-    path.write_text(json.dumps({"serving": {"kips": 1.0}}))
-    merge_bench_json(json.loads(json.dumps(snap)), str(path),
-                     model="vgg16", section="metrics")
-    data = json.loads(path.read_text())
-    assert data["serving"] == {"kips": 1.0}
-    assert data["metrics_by_model"]["vgg16"]["counters"]["a_total"] == 2
+    # the snapshot round-trips through JSON unchanged
+    assert json.loads(json.dumps(snap)) == snap
     # and the validator actually rejects malformed artifacts
     assert validate_metrics_snapshot({"counters": {"x": -1},
                                       "gauges": {}, "histograms": {}})
@@ -341,57 +333,15 @@ def test_validate_trace_rejects_bad_events():
 
 
 # --------------------------------------------------------------------------
-# Fold counters: model join + apportionment arithmetic
-# --------------------------------------------------------------------------
-
-def test_fold_counters_join_model_and_measurement():
-    from repro.core.engine import (ConvSchedule, ScheduleKey, dataflow_costs,
-                                   plan_and_dataflow)
-    from repro.core.loopnest import ConvLoopNest
-    from repro.obs.folds import FoldStreamCounters
-
-    def sched(nest):
-        plan, dataflow = plan_and_dataflow(nest)
-        costs = tuple(sorted(dataflow_costs(nest, plan).items()))
-        return ConvSchedule(key=ScheduleKey.from_loopnest(nest), nest=nest,
-                            plan=plan, dataflow=dataflow, costs=costs)
-
-    nest_a = ConvLoopNest(n=1, nf=16, c=8, r=3, s=3, x=8, y=8, pad=1)
-    nest_b = ConvLoopNest(n=1, nf=32, c=16, r=3, s=3, x=4, y=4, pad=1)
-    ls = [("conv0", sched(nest_a)), ("conv1", sched(nest_b)),
-          ("conv2", sched(nest_b))]   # conv1/conv2 share a key
-    fc = FoldStreamCounters()
-    fc.observe_compile(ls)
-    assert len(fc.rows()) == 2
-    parts = fc.observe_dispatch(ls, items=4, kernel_time_s=0.1)
-    assert [p[0] for p in parts] == ["conv0", "conv1", "conv2"]
-    # apportionment conserves the measured interval exactly
-    assert sum(p[2] for p in parts) == pytest.approx(0.1)
-    rows = {r["key"]: r for r in fc.rows()}
-    assert all(r["dispatches"] == 1 and r["items"] == 4
-               for r in rows.values())
-    total_time = sum(r["measured_s"] for r in rows.values())
-    assert total_time == pytest.approx(0.1, abs=1e-5)
-    # model side is populated from the analytical perf model
-    for r in rows.values():
-        assert 0.0 < r["util_model_pct"] <= 100.0
-        assert r["gflops_model"] > 0 and r["bytes_moved_model"] > 0
-    d = fc.as_dict()
-    assert d["distinct_schedules"] == 2 and d["conv_layers"] == 3
-    assert "schedule" in fc.table()
-
-
-# --------------------------------------------------------------------------
 # Serving integration: lifecycle spans + bounded metrics end to end
 # --------------------------------------------------------------------------
 
 def test_serving_trace_zero_loss_and_metrics(vgg_params, tmp_path):
     """One engine run with the tracer and registry on: every submitted
     request appears as a lifetime span with a terminal outcome, the
-    trace and metrics artifacts validate, and the per-schedule fold
-    table carries the model-side utilization for every schedule."""
+    trace and metrics artifacts validate."""
     from repro.models import vgg
-    from repro.obs.report import check_trace_outcomes
+    from repro.obs.trace import check_trace_outcomes
     from repro.serve.vision import VisionEngine
     clock = FakeClock(step=0.0005)
     tracer = Tracer(clock)
@@ -421,11 +371,6 @@ def test_serving_trace_zero_loss_and_metrics(vgg_params, tmp_path):
     lives = [e["args"] for e in trace["traceEvents"]
              if e.get("cat") == "request"]
     assert all(a["inbox_ms"] == 0 and a["queued_ms"] >= 0 for a in lives)
-    # fold counters cover every distinct schedule with model utilization
-    obs = eng.metrics_dict()["observability"]
-    assert obs["distinct_schedules"] == len(obs["schedules"])
-    assert all(r["util_model_pct"] > 0
-               for r in obs["schedules"].values())
     # registry snapshot: bounded histograms in, schema-valid out
     eng.snapshot_registry(reg)
     snap = reg.snapshot()

@@ -310,7 +310,8 @@ def test_quantize_graph_records_every_conv():
         recipe.scale_for("not_a_conv")
 
 
-@pytest.mark.parametrize("model,n_convs", [("vgg16", 13), ("resnet18", 20)])
+@pytest.mark.parametrize("model,n_convs", [("vgg16", 13), ("resnet18", 20),
+                                           ("mobilenetv2", 52)])
 def test_zoo_int8_matches_fp32_oracle(model, n_convs):
     from repro.models.zoo import get_conv_model
     spec = get_conv_model(model)
@@ -333,6 +334,30 @@ def test_zoo_int8_matches_fp32_oracle(model, n_convs):
     # oracle's logit spread
     spread = float(yf.max() - yf.min())
     assert float(np.abs(yf - yq).max()) <= 0.15 * spread
+
+
+@pytest.mark.parametrize("model", ["vgg16", "resnet18", "mobilenetv2"])
+def test_zoo_int8_stream_bytes_ratio(model):
+    """Int8 streams each compiled zoo network's weights and activations
+    in at least 3.5x fewer modelled HBM bytes than fp32 (4x per element;
+    the per-channel scales and the fp32 output stay wide)."""
+    from repro.models.zoo import get_conv_model
+    spec = get_conv_model(model)
+    params = spec.init_params(jax.random.PRNGKey(0), width_mult=0.0625,
+                              img=32, classes=10)
+
+    def stream_bytes(precision):
+        net = compile_network(params, spec.to_graph(), (2, 3, 32, 32),
+                              policy="pallas", jit=False,
+                              precision=precision)
+        total = 0.0
+        for _, sc in net.layer_schedules:
+            comp = traffic_components(sc.nest, sc.plan, sc.dataflow,
+                                      precision=sc.key.precision)
+            total += comp["weights"] + comp["input"]
+        return total
+
+    assert stream_bytes("fp32") / stream_bytes("int8") >= 3.5
 
 
 def test_zoo_int8_reference_policy_matches_pallas_policy():

@@ -178,15 +178,6 @@ def test_serving_bitwise_equals_direct_forward(tiny_resnet, policy):
             np.testing.assert_allclose(req.logits, want, rtol=1e-5)
 
 
-def test_serving_summary_resnet18():
-    from repro.serve.vision import serving_summary
-    d = serving_summary("resnet18", requests=5, img=IMG, width_mult=WIDTH,
-                        policy="auto", buckets=(1, 2, 4), seed=11)
-    assert d["workload"]["model"] == "resnet18"
-    assert d["requests"] == 5 and d["images"] >= 5 and d["kips"] > 0
-    assert d["compile"]["distinct_schedules"] == 11
-
-
 def test_bucket_compiler_pay_once_across_buckets(tiny_resnet):
     params, _, _ = tiny_resnet
     comp = resnet.bucket_compiler(params, img=IMG, policy="auto")

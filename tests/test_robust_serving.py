@@ -3,16 +3,21 @@ state machine, strict bucket validation, typed bad-request rejection,
 deadline expiry and SLO-aware admission, the degradation ladder
 (reference fallback + quarantine bisection), watchdog hang flagging,
 deterministic chaos injection, and the preemption drain."""
+import os
+import signal
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.ft.fault_tolerance import PreemptionGuard
 from repro.serve.admission import (AdmissionController, BadRequestError,
                                    DispatchWatchdog, RequestOutcome,
                                    validate_images)
 from repro.serve.batcher import BucketPolicy, ImageBatcher, ImageRequest
-from repro.serve.chaos import (ChaosInjector, ChaosKernelFault, Fault,
+from repro.serve.chaos import (PROFILE_EXPECTATIONS, PROFILES,
+                               ChaosInjector, ChaosKernelFault, Fault,
                                chaos_summary)
 
 IMG, WIDTH, CLASSES = 32, 0.0625, 10
@@ -383,47 +388,64 @@ def test_admission_shed_through_engine(vgg_params):
 # the acceptance criteria, end to end
 # --------------------------------------------------------------------------
 
-def test_chaos_run_zero_lost_requests_all_invariants():
-    """ISSUE acceptance: under the deterministic chaos profile every
+@pytest.mark.parametrize("profile,buckets", [
+    pytest.param("mixed", (1, 2, 4, 8), id="mixed"),
+    # a single-fault profile still fires its fault several times in the
+    # ten-request stream at two bucket widths, for half the compiles
+    pytest.param("kernel-fault", (1, 2), id="kernel-fault"),
+    pytest.param("nan", (1, 2), id="nan"),
+    pytest.param("slow-batch", (1, 2), id="slow-batch"),
+])
+def test_chaos_run_zero_lost_requests_all_invariants(profile, buckets):
+    """Under each deterministic chaos profile every
     submitted request reaches a terminal outcome (zero lost), quarantine
     isolates the poison, degraded logits are bitwise reference, healthy
-    logits bitwise primary — ``chaos_summary`` raises on any violation."""
-    d = chaos_summary("vgg16", profile="mixed", seed=7, requests=10,
-                      img=IMG, width_mult=WIDTH, policy="pallas")
+    logits bitwise primary — ``chaos_summary`` raises on any violation —
+    and the recovery path the profile targets actually ran."""
+    assert profile in PROFILES
+    d = chaos_summary("vgg16", profile=profile, seed=7, requests=10,
+                      img=IMG, width_mult=WIDTH, policy="pallas",
+                      buckets=buckets)
     rb = d["robustness"]
     assert rb["lost_requests"] == 0
     assert rb["submitted"] == 10 == sum(rb["outcomes"].values())
-    assert rb["degraded_batches"] > 0
+    for counter in PROFILE_EXPECTATIONS[profile]:
+        assert rb[counter] > 0, counter
     assert rb["shed"] + rb["expired"] > 0
-    assert d["chaos"]["profile"] == "mixed"
-    # deterministic: the same (profile, seed) injects identically
-    d2 = chaos_summary("vgg16", profile="mixed", seed=7, requests=10,
-                       img=IMG, width_mult=WIDTH, policy="pallas")
-    assert d2["chaos"]["schedule"] == d["chaos"]["schedule"]
-    assert d2["robustness"]["outcomes"] == rb["outcomes"]
+    assert d["chaos"]["profile"] == profile
+    # deterministic: the same (profile, seed) schedules identically ...
+    again = ChaosInjector.from_profile(profile, 7).describe()
+    assert again["schedule"] == d["chaos"]["schedule"]
+    if profile == "mixed":              # ... and replays identically
+        d2 = chaos_summary("vgg16", profile=profile, seed=7, requests=10,
+                           img=IMG, width_mult=WIDTH, policy="pallas",
+                           buckets=buckets)
+        assert d2["chaos"]["schedule"] == d["chaos"]["schedule"]
+        assert d2["robustness"]["outcomes"] == rb["outcomes"]
 
 
-def test_serving_summary_preemption_drain(vgg_params):
-    """A tripped guard stops admission mid-stream but everything already
-    queued is flushed and metrics still emit — the clean SIGTERM drain."""
-    from repro.serve.vision import serving_summary
-
-    class TrippedAfter:
-        def __init__(self, n):
-            self.n = n
-
-        @property
-        def requested(self):
-            self.n -= 1
-            return self.n < 0
-
-    d = serving_summary("vgg16", requests=8, img=IMG, width_mult=WIDTH,
-                        policy="auto", buckets=(1, 2), seed=0,
-                        guard=TrippedAfter(3))
-    assert d["workload"]["preempted"] == 5      # 3 admitted, 5 never
-    assert d["robustness"]["submitted"] == 3
-    assert d["robustness"]["lost_requests"] == 0
-    assert sum(d["robustness"]["outcomes"].values()) == 3
+def test_preemption_drain_flushes_admitted_requests(vgg_params):
+    """A SIGTERM mid-stream stops admission, but everything already
+    queued is flushed and metrics still emit — the clean drain."""
+    eng = _engine(vgg_params, buckets=(1, 2))
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    sizes = rng.integers(1, 3, 8)
+    admitted = []
+    with PreemptionGuard() as guard:
+        for i, n in enumerate(sizes):
+            if i == 3:
+                os.kill(os.getpid(), signal.SIGTERM)
+            if guard.requested:          # stop admitting, keep draining
+                break
+            admitted.append(eng.submit(_imgs(rng, int(n))))
+        eng.run()
+    assert len(sizes) - len(admitted) == 5      # 3 admitted, 5 never
+    assert all(r.outcome is RequestOutcome.OK for r in admitted)
+    rb = eng.metrics_dict()["robustness"]
+    assert rb["submitted"] == 3
+    assert rb["lost_requests"] == 0
+    assert sum(rb["outcomes"].values()) == 3
 
 
 def test_metrics_dict_has_robustness_section(vgg_params):

@@ -17,6 +17,9 @@ under test are the transport versions of the serving contracts:
 import asyncio
 import base64
 import json
+import pathlib
+import subprocess
+import sys
 import threading
 import time
 
@@ -32,6 +35,11 @@ from repro.serve.transport import (InferResult, decode_infer_body,
 
 IMG = 32
 BUCKETS = (1, 2, 4)
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+# modules of the language-model stack the served entry must not import
+LM_STACK = tuple(f"repro.models.{m}" for m in (
+    "api", "transformer", "attention", "encdec", "moe", "rwkv", "ssm")) + (
+    "repro.kernels.attention_fold", "repro.serve.engine", "repro.configs")
 
 
 class FakeGuard:
@@ -362,78 +370,78 @@ def test_router_failed_outcome_does_not_failover():
 
 
 # ---------------------------------------------------------------------------
-# load generator + perf gate
+# closed-loop load against the live server
 # ---------------------------------------------------------------------------
 
-def test_load_generator_smoke_against_live_server(served, tmp_path):
-    from benchmarks.run_async_requests import main
-    bench = tmp_path / "BENCH_test.json"
-    metrics = tmp_path / "metrics_scrape.json"
-    rc = main(["--host", served.host, "--port", str(served.port),
-               "--requests", "8", "--concurrency", "4",
-               "--buckets", ",".join(str(b) for b in BUCKETS),
-               "--bench-json", str(bench),
-               "--metrics-out", str(metrics)])
-    assert rc == 0
-    tr = json.loads(bench.read_text())["transport"]
-    assert tr["requests"] == 8 and tr["ok"] == 8
-    assert tr["lost_requests"] == 0 and tr["kips"] > 0
+def test_load_generator_smoke_against_live_server(served):
+    """64 requests from 16 concurrent clients: every one answered ok,
+    nothing lost on either ledger, and the ``/metrics.json`` scrape
+    stays schema-valid under load."""
     from repro.obs.metrics import validate_metrics_snapshot
-    assert validate_metrics_snapshot(json.loads(metrics.read_text())) == []
+    rng = np.random.default_rng(11)
+    bodies = [encode_images_payload(images(int(n), seed=i))
+              for i, n in enumerate(rng.integers(1, max(BUCKETS) + 1, 64))]
+
+    async def drive():
+        gate = asyncio.Semaphore(16)
+
+        async def one(body):
+            async with gate:
+                return await http_json(served.host, served.port, "POST",
+                                       "/v1/infer", body)
+        return await asyncio.gather(*(one(b) for b in bodies))
+
+    before = submitted_total(served)
+    results = asyncio.run(drive())
+    assert [s for s, _ in results] == [200] * len(bodies)
+    assert all(obj["outcome"] == "ok" for _, obj in results)
+    assert submitted_total(served) - before == len(bodies)
+    status, stats = http(served, "GET", "/stats")
+    assert status == 200 and stats["totals"]["lost_requests"] == 0
+    status, snap = http(served, "GET", "/metrics.json")
+    assert status == 200 and validate_metrics_snapshot(snap) == []
 
 
-def test_check_bench_transport_scope(tmp_path):
-    from benchmarks.check_bench import compare, extract, scope_filter
-    bench = {"transport": {"lost_requests": 0, "kips": 1.0,
-                           "shed_rate": 0.05}}
-    fresh = extract(bench)
-    assert fresh["exact"]["transport.lost_requests"] == 0
-    assert fresh["throughput"]["transport.kips"] == 1.0
-    assert fresh["transport"]["transport.shed_rate"] == 0.05
-    # scope core drops every transport.* metric; scope transport keeps
-    # nothing else
-    assert scope_filter(fresh, "core")["exact"] == {}
-    assert scope_filter(fresh, "transport") == fresh
-    # shed_rate gates as a ceiling: shedding less than baseline passes,
-    # more fails; a lost request fails exactly
-    base = {"exact": {"transport.lost_requests": 0},
-            "latency": {}, "throughput": {"transport.kips": 1.0},
-            "robustness": {}, "observability": {}, "quantization": {},
-            "transport": {"transport.shed_rate": 0.1}}
-    assert compare(fresh, base, tol=0.2) == []
-    worse = extract({"transport": {"lost_requests": 1, "kips": 1.0,
-                                   "shed_rate": 0.5}})
-    kinds = {(k, m) for k, m, _ in compare(worse, base, tol=0.2)}
-    assert ("exact", "transport.lost_requests") in kinds
-    assert ("transport", "transport.shed_rate") in kinds
+# ---------------------------------------------------------------------------
+# launcher: compile cache, TPU spawn guard, import hygiene
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    import jax
+
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        want = str(ROOT / ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_check_bench_scoped_update_preserves_other_scope(tmp_path):
-    from benchmarks.check_bench import main as gate_main
-    core_bench = tmp_path / "core.json"
-    core_bench.write_text(json.dumps({
-        "latency": {"auto_per_img_s": 0.01,
-                    "pallas_unfused_per_img_s": 0.02,
-                    "pallas_fused_per_img_s": 0.015},
-        "fold_reuse": {"hits": 5, "misses": 8, "replans": 0,
-                       "conv_layers": 13, "distinct_schedules": 8},
-        "pallas_calls": 13}))
-    tr_bench = tmp_path / "transport.json"
-    tr_bench.write_text(json.dumps({
-        "transport": {"lost_requests": 0, "kips": 2.0,
-                      "shed_rate": 0.0}}))
-    baseline = tmp_path / "baseline.json"
-    assert gate_main(["--bench", str(core_bench), "--scope", "core",
-                      "--baseline", str(baseline), "--update"]) == 0
-    assert gate_main(["--bench", str(tr_bench), "--scope", "transport",
-                      "--baseline", str(baseline), "--update"]) == 0
-    merged = json.loads(baseline.read_text())
-    # the transport-scoped update kept the core metrics and vice versa
-    assert merged["latency"]["vgg16.latency.auto_per_img_s"] == 0.01
-    assert merged["exact"]["transport.lost_requests"] == 0
-    assert merged["throughput"]["transport.kips"] == 2.0
-    # each job gates only its own scope against the shared baseline
-    assert gate_main(["--bench", str(core_bench), "--scope", "core",
-                      "--baseline", str(baseline)]) == 0
-    assert gate_main(["--bench", str(tr_bench), "--scope", "transport",
-                      "--baseline", str(baseline)]) == 0
+def test_tpu_host_refuses_more_spawned_workers_than_one(monkeypatch):
+    from repro.launch import server
+    monkeypatch.setattr(server, "visible_tpu_chips", lambda: 1)
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        server.start_server("vgg16", n_workers=2, spawn=True)
+
+
+def test_launcher_imports_initialize_no_backend():
+    """A load generator's ``--boot`` parent imports the launchers; if that
+    opened a backend, the parent would hold the chip its server needs.
+    The served entry also loads nothing of the language-model stack."""
+    prog = ("import sys; sys.path[:0] = ['src', '.']\n"
+            "import repro.launch.server\n"
+            f"lm = [m for m in {LM_STACK!r} if m in sys.modules]\n"
+            "assert not lm, lm\n"
+            "import repro.launch.serve\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
+            "print('NO_BACKEND')")
+    out = subprocess.run([sys.executable, "-c", prog], cwd=ROOT,
+                         capture_output=True, text=True, timeout=300)
+    assert "NO_BACKEND" in out.stdout, out.stderr[-2000:]

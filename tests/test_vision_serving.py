@@ -213,8 +213,9 @@ def test_metrics_shape_and_kips(vgg_params):
     eng.run()
     d = eng.metrics_dict()
     assert d["images"] == 5 and d["batches"] == 3
-    assert d["kips"] > 0 and d["images_per_s"] == pytest.approx(
-        d["kips"] * 1e3, rel=1e-3)
+    m = eng.metrics
+    assert d["images_per_s"] > 0 and d["images_per_s"] == pytest.approx(
+        m.images / m.elapsed_s, rel=1e-3)
     lat = d["latency"]
     assert lat["p50_s"] <= lat["p95_s"] <= lat["p99_s"]
     assert d["compile"]["buckets"] == [2]
@@ -333,59 +334,44 @@ def test_mesh_sharded_matches_single_device(mesh_shape, policy):
 
 
 # --------------------------------------------------------------------------
-# launcher / bench snapshot plumbing
+# a served stream through every zoo model, at both precisions
 # --------------------------------------------------------------------------
 
-def test_merge_bench_json_preserves_sections(tmp_path):
-    from repro.launch.serve import merge_bench_json
-    path = str(tmp_path / "BENCH_vgg.json")
-    json.dump({"latency": {"x": 1}}, open(path, "w"))
-    merge_bench_json({"kips": 2.0}, path)
-    data = json.load(open(path))
-    assert data["latency"] == {"x": 1} and data["serving"] == {"kips": 2.0}
-    # corrupt snapshot: overwritten, not fatal
-    open(path, "w").write("{nope")
-    merge_bench_json({"kips": 3.0}, path)
-    assert json.load(open(path))["serving"] == {"kips": 3.0}
+# distinct fold schedules per model: the batch axis is not in the key,
+# and neither fp32 nor int8 adds any
+SCHEDULES = {"vgg16": 8, "resnet18": 11, "mobilenetv2": 27}
 
 
-def test_serving_summary_emits_all_metrics(tmp_path):
-    from repro.serve.vision import serving_summary
-    d = serving_summary("vgg16", requests=6, img=IMG, width_mult=WIDTH,
-                        policy="auto", buckets=(1, 2, 4), seed=7)
-    for k in ("images", "requests", "batches", "kips", "latency",
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+@pytest.mark.parametrize("model", sorted(SCHEDULES))
+def test_served_stream_answers_every_request(model, precision):
+    """A deterministic mixed-size stream, half of it under a generous
+    deadline, through ``VisionEngine``: every request answered ok,
+    nothing shed, expired, degraded or lost, every deadline met."""
+    from repro.models.zoo import get_conv_model
+    from repro.serve.vision import VisionEngine
+    spec = get_conv_model(model)
+    params = spec.init_params(jax.random.PRNGKey(0), width_mult=WIDTH,
+                              img=IMG, classes=CLASSES)
+    eng = VisionEngine(params, spec.to_graph(), img=IMG, policy="auto",
+                       buckets=(1, 2, 4), precision=precision)
+    eng.warmup()
+    rng = np.random.default_rng(7)
+    sizes = rng.integers(1, 5, 6)
+    reqs = [eng.submit(im, deadline_s=600.0 if i % 2 else None)
+            for i, im in enumerate(_requests(rng, sizes))]
+    eng.run()
+    d = eng.metrics_dict()
+    for k in ("images", "requests", "batches", "images_per_s", "latency",
               "slot_occupancy", "per_bucket_batches", "compile",
-              "workload", "robustness"):
+              "robustness"):
         assert k in d, k
-    assert d["requests"] == 6 and d["images"] >= 6
-    assert d["workload"]["model"] == "vgg16"
-    assert d["compile"]["distinct_schedules"] == 8
+    assert all(r.done and r.logits.shape == (r.n, CLASSES) for r in reqs)
+    assert d["requests"] == 6 and d["images"] == int(sizes.sum())
+    assert d["compile"]["distinct_schedules"] == SCHEDULES[model]
     assert set(d["latency"]) == {"p50_s", "p95_s", "p99_s", "mean_s"}
-    # a healthy deadline-free run: every request ok, nothing shed or
-    # degraded, nothing lost, and a deterministic 1.0 deadline hit rate
     rb = d["robustness"]
     assert rb["outcomes"] == {"ok": 6} and rb["submitted"] == 6
     assert rb["shed"] == rb["expired"] == rb["failed"] == 0
     assert rb["degraded_batches"] == 0 and rb["lost_requests"] == 0
-    assert rb["deadline_hit_rate"] == 1.0
-
-
-def test_merge_bench_json_per_model_keys(tmp_path):
-    """Per-model serving metrics land under serving_by_model.<name> and a
-    non-vgg16 model never clobbers the legacy flat serving section."""
-    from repro.launch.serve import merge_bench_json
-    path = str(tmp_path / "BENCH_vgg.json")
-    json.dump({"latency": {"x": 1}}, open(path, "w"))
-    merge_bench_json({"kips": 1.0}, path, model="vgg16")
-    merge_bench_json({"kips": 2.0}, path, model="resnet18")
-    data = json.load(open(path))
-    assert data["latency"] == {"x": 1}                 # micro preserved
-    assert data["serving"] == {"kips": 1.0}            # vgg16 stays legacy
-    assert data["serving_by_model"] == {"vgg16": {"kips": 1.0},
-                                        "resnet18": {"kips": 2.0}}
-    # re-serving one model leaves the other model's snapshot intact
-    merge_bench_json({"kips": 3.0}, path, model="resnet18")
-    data = json.load(open(path))
-    assert data["serving"] == {"kips": 1.0}
-    assert data["serving_by_model"]["resnet18"] == {"kips": 3.0}
-    assert data["serving_by_model"]["vgg16"] == {"kips": 1.0}
+    assert rb["deadline_total"] == 3 and rb["deadline_hit_rate"] == 1.0
